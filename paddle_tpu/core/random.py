@@ -41,14 +41,9 @@ def _configure_fast_rng_once() -> None:
             return
         from .. import flags
 
-        if flags.GLOBAL_FLAGS.get("use_fast_rng"):
-            try:
-                backend = jax.default_backend()
-            except Exception:
-                return  # backend unavailable — retry on next key creation
-            from .place import ACCEL_PLATFORMS
-            if backend in ACCEL_PLATFORMS:
-                jax.config.update("jax_default_prng_impl", "rbg")
+        if flags.GLOBAL_FLAGS.get("use_fast_rng") \
+                and jax.default_backend() == "tpu":
+            jax.config.update("jax_default_prng_impl", "rbg")
         _fast_rng_configured = True
 
 

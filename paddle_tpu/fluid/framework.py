@@ -6,8 +6,7 @@ tracing; the names user code actually touches route here."""
 from __future__ import annotations
 
 from ..core.place import CPUPlace, CUDAPlace  # noqa: F401
-from ..core.place import \
-    accelerator_configured as is_compiled_with_cuda  # noqa: F401
+from ..core.place import is_compiled_with_cuda  # noqa: F401
 from ..nn.layer import Parameter  # noqa: F401
 from ..static import (Program, default_main_program,  # noqa: F401
                       global_scope)

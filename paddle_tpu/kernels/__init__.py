@@ -20,16 +20,67 @@ from ..flags import GLOBAL_FLAGS
 
 
 def _on_tpu() -> bool:
-    from ..core.place import ACCEL_PLATFORMS
-    try:
-        platform = jax.default_backend()
-    except Exception:
-        return False
-    return platform in ACCEL_PLATFORMS
+    return jax.default_backend() == "tpu"
 
 
 def pallas_enabled() -> bool:
     return GLOBAL_FLAGS.get("use_pallas_kernels") and _on_tpu()
+
+
+def _per_shard(kernel, operands, dims, out_dims):
+    """Call ``kernel(*operands, shard)`` once per shard of the mesh in
+    scope, or directly when there is none.
+
+    GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map"), so under a multi-device mesh — the one
+    ``ShardedTrainStep`` sets while it traces — each routed kernel runs
+    on the local block of its operands. ``dims`` gives, per operand, a
+    ``{dimension: mesh axis}`` dict (``out_dims`` the same for the
+    result, which has operand 0's rank) in the standard axis names of
+    parallel/mesh.py: batch over
+    ``dp``, heads over ``mp``. An axis is used only where the mesh has
+    it with size > 1 and it divides that dimension of every operand
+    that names it (else that dimension is whole on every device); any
+    other layout GSPMD holds is resharded to this one around the call.
+    ``shard`` is a traced int32 that differs between shards (0 without
+    a mesh), for kernels that draw random bits.
+    """
+    import jax.numpy as jnp
+    from jax.sharding import AxisType, PartitionSpec as P
+
+    mesh = jax.sharding.get_abstract_mesh()
+    sizes = {n: s for n, s, t in zip(mesh.axis_names, mesh.axis_sizes,
+                                     mesh.axis_types)
+             if t == AxisType.Auto and s > 1}
+    if not sizes:
+        return kernel(*operands, jnp.int32(0))
+    # absent optional operands (None) stay outside the shard_map
+    live = [i for i, x in enumerate(operands) if x is not None]
+    used = {ax for i in live for ax in dims[i].values() if ax in sizes}
+    used = {ax for ax in used
+            if all(operands[i].shape[dim] % sizes[ax] == 0
+                   for i in live for dim, a in dims[i].items()
+                   if a == ax)}
+
+    def spec(ndim, d):
+        return P(*(d.get(i) if d.get(i) in used else None
+                   for i in range(ndim)))
+
+    def local(*blocks):
+        shard = jnp.int32(0)
+        for ax in sorted(used):
+            shard = shard * sizes[ax] + jax.lax.axis_index(ax)
+        args = [None] * len(operands)
+        for i, blk in zip(live, blocks):
+            args[i] = blk
+        return kernel(*args, shard)
+
+    from ..parallel._shard_map import shard_map
+    return shard_map(
+        local, mesh,
+        in_specs=tuple(spec(operands[i].ndim, dims[i]) for i in live),
+        out_specs=spec(operands[0].ndim, out_dims),
+        check_vma=False)(*(operands[i] for i in live))
 
 
 # Memory bound for routing NARROW head dims (d%8, not d%128) to flash
@@ -45,8 +96,12 @@ def maybe_layer_norm(x, weight, bias, epsilon: float, begin_norm_axis: int):
     if pallas_enabled() and GLOBAL_FLAGS.get("use_pallas_layer_norm") \
             and begin_norm_axis == x.ndim - 1 and x.ndim >= 2:
         try:
+            from ..parallel.mesh import DP
             from .layer_norm import layer_norm_pallas
-            return layer_norm_pallas(x, weight, bias, epsilon)
+            return _per_shard(
+                lambda x, w, b, _shard: layer_norm_pallas(x, w, b,
+                                                          epsilon),
+                (x, weight, bias), ({0: DP}, {}, {}), {0: DP})
         # ptlint: disable=silent-failure -- NotImplementedError is the kernel's documented "shape unsupported" signal; the reference impl below is the answer
         except NotImplementedError:
             pass
@@ -89,14 +144,15 @@ def maybe_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
     """Ragged paged decode attention over the serving KV block pool
     (q [B, H, D], pools [N, block_size, H, D] — see
     kernels/paged_attention.py). Unlike the other maybe_* entries this
-    has no separate XLA composition: off-accelerator the SAME kernel
+    has no separate XLA composition and no flag: on a TPU the kernel
+    compiles or the call raises; on any other backend the SAME kernel
     runs under the Pallas interpreter, so tier-1 exercises the exact
     production code path (the dense gather reference exists for parity
     tests, not routing)."""
     from .paged_attention import paged_attention
     return paged_attention(q, k_pool, v_pool, block_tables,
                            context_lens, scale=scale,
-                           interpret=not pallas_enabled())
+                           interpret=not _on_tpu())
 
 
 def maybe_paged_attention_multiquery(q, q_lens, k_pool, v_pool,
@@ -105,15 +161,14 @@ def maybe_paged_attention_multiquery(q, q_lens, k_pool, v_pool,
     """Ragged MULTI-QUERY paged attention — the speculative-decode
     verify step (q [B, Qmax, H, D] plus per-sequence q_lens; see
     kernels/paged_attention.py). Same routing story as
-    maybe_paged_attention: no separate XLA composition — off-
-    accelerator the kernel runs under the Pallas interpreter, and a
-    Qmax == 1 batch reduces to the single-query kernel path
-    bit-for-bit."""
+    maybe_paged_attention: no separate XLA composition — interpreted
+    only off-TPU — and a Qmax == 1 batch reduces to the single-query
+    kernel path bit-for-bit."""
     from .paged_attention import paged_attention_multiquery
     return paged_attention_multiquery(q, q_lens, k_pool, v_pool,
                                       block_tables, context_lens,
                                       scale=scale,
-                                      interpret=not pallas_enabled())
+                                      interpret=not _on_tpu())
 
 
 def _is_key_padding_mask(mask, batch: int, tk: int) -> bool:
@@ -190,27 +245,37 @@ def maybe_flash_attention(q, k, v, mask=None, scale: Optional[float] = None,
             or min_seq
     if (pallas_enabled() and mask_ok and q.ndim == 4 and d_ok
             and tk >= min_seq):
+        from ..parallel.mesh import DP, MP
         from .flash_attention import bthd_supported, flash_attention
-        if bthd and not bthd_supported(d, q.shape[2]):
-            # geometry the BTHD block tiling can't express (e.g. d=32,
-            # odd head count): still flash, via the transpose layout
-            out = maybe_flash_attention(
-                jnp.moveaxis(q, 2, 1), jnp.moveaxis(k, 2, 1),
-                jnp.moveaxis(v, 2, 1), mask=mask, scale=scale,
-                causal=causal, dropout_p=dropout_p, training=training)
-            return jnp.moveaxis(out, 1, 2)
         kv_bias = None if mask is None else _mask_to_kv_bias(mask)
-        if dropout_p > 0.0 and training:
+        seed = None
+        drop = float(dropout_p) if training else 0.0
+        if drop > 0.0:
             from ..core import random as _random
             seed = jax.random.randint(
                 _random.next_key("dropout"), (1, 1), 0, 2 ** 31 - 1,
                 dtype=jnp.int32)
-            return flash_attention(q, k, v, seed=seed, causal=causal,
-                                   scale=scale,
-                                   dropout_p=float(dropout_p),
-                                   kv_bias=kv_bias, bthd=bthd)
-        return flash_attention(q, k, v, causal=causal, scale=scale,
-                               kv_bias=kv_bias, bthd=bthd)
+
+        def kernel(q, k, v, seed, kv_bias, shard):
+            if seed is not None:
+                # the keep mask hashes (seed, LOCAL head, position):
+                # give each shard its own stream (int32 wrap is fine)
+                seed = seed + shard * jnp.int32(0x3C6EF35F)
+            kw = dict(seed=seed, causal=causal, scale=scale,
+                      dropout_p=drop, kv_bias=kv_bias)
+            if bthd and not bthd_supported(d, q.shape[2]):
+                # geometry the BTHD block tiling can't express (e.g.
+                # d=32, odd local head count): still flash, via the
+                # transpose layout
+                out = flash_attention(
+                    jnp.moveaxis(q, 2, 1), jnp.moveaxis(k, 2, 1),
+                    jnp.moveaxis(v, 2, 1), **kw)
+                return jnp.moveaxis(out, 1, 2)
+            return flash_attention(q, k, v, bthd=bthd, **kw)
+
+        qkv = {0: DP, 2 if bthd else 1: MP}   # batch, heads
+        return _per_shard(kernel, (q, k, v, seed, kv_bias),
+                          (qkv, qkv, qkv, {}, {0: DP}), qkv)
     if bthd:
         # XLA fallback wants [B, H, T, D]; the transpose pair here
         # costs what the caller-side head split used to cost

@@ -83,8 +83,7 @@ def bthd_supported(d: int, h: int) -> bool:
 # Mosaic so lets it pipeline/parallelize grid iterations instead of the
 # conservative sequential default. Pure scheduling hint: numerics are
 # identical (interpret-mode tests + the compiled verify stage cover it).
-_GRID_PARALLEL = getattr(pltpu, "CompilerParams",
-                         getattr(pltpu, "TPUCompilerParams", None))(
+_GRID_PARALLEL = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel"))
 
 

@@ -38,11 +38,7 @@ def _adam_kernel(p_ref, g_ref, m_ref, v_ref, sc_ref,
     p = p_ref[:].astype(jnp.float32)
     m = beta1 * m_ref[:] + (1.0 - beta1) * g
     v = beta2 * v_ref[:] + (1.0 - beta2) * g * g
-    # pl.reciprocal is missing from older pallas; exact 1/x either way
-    if hasattr(pl, "reciprocal"):
-        update = m * pl.reciprocal(jnp.sqrt(v) + eps, approx=False)
-    else:
-        update = m / (jnp.sqrt(v) + eps)
+    update = m * pl.reciprocal(jnp.sqrt(v) + eps, approx=False)
     if weight_decay:
         update = update + (weight_decay / 1.0) * p  # decoupled decay term
     p_new = p - lr_c * update

@@ -50,8 +50,7 @@ _NEG = -1e30
 
 # the inner grid dimension accumulates into the resident output block,
 # so it must be sequential ("arbitrary"); rows/vocab-outer can go wide
-_GRID_SEQ = getattr(pltpu, "CompilerParams",
-                    getattr(pltpu, "TPUCompilerParams", None))(
+_GRID_SEQ = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"))
 
 

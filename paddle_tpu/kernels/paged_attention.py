@@ -46,9 +46,8 @@ _NEG_INF = -1e30
 
 # Grid dims: (sequence, kv-block scan). The scan dim carries the
 # online-softmax state in scratch, so it MUST run sequentially;
-# sequences are independent. Same compat shim as flash_attention.
-_GRID_SEMANTICS = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))(
+# sequences are independent.
+_GRID_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"))
 
 
@@ -73,10 +72,12 @@ def _paged_attn_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         q = q_ref[0].astype(jnp.float32) * scale     # [H, D]
         k = k_ref[0].astype(jnp.float32)             # [BS, H, D]
         v = v_ref[0].astype(jnp.float32)
-        # head-batched q·k^T: batch H, contract D -> [H, BS]
+        # head-batched q·k^T: batch H, contract D -> [H, BS]. The left
+        # operand carries a unit row dim: Mosaic cannot express a
+        # batched dot whose lhs has no free dimension.
         s = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
+            q[:, None, :], k, (((2,), (2,)), ((0,), (1,))),
+            preferred_element_type=jnp.float32)[:, 0, :]
         pos = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         s = jnp.where(pos < ctx, s, _NEG_INF)        # ragged tail mask
@@ -89,8 +90,8 @@ def _paged_attn_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         # head-batched p·v: batch H, contract BS -> [H, D]
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
+            p[:, None, :], v, (((2,), (0,)), ((0,), (1,))),
+            preferred_element_type=jnp.float32)[:, 0, :]
         # m/l replicate across the 128-lane minor dim (scratch keeps
         # the vector tiling happy; column 0 is the value)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)

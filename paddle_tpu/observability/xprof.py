@@ -59,12 +59,10 @@ def enabled() -> bool:
 
 
 def _cost_dict(compiled) -> Dict[str, float]:
-    """Normalize cost_analysis() across jax versions: dict, list of
-    dicts (one per computation), or None."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return {str(k): float(v) for k, v in dict(cost or {}).items()
+    """cost_analysis() as {name: float}; a backend without the analysis
+    returns None."""
+    cost = compiled.cost_analysis() or {}
+    return {str(k): float(v) for k, v in cost.items()
             if isinstance(v, (int, float))}
 
 

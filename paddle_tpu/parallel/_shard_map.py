@@ -1,11 +1,7 @@
-"""Version-portable ``shard_map``.
+"""The one ``shard_map`` spelling of the framework.
 
-``jax.shard_map`` only exists as a top-level API in newer jax; on the
-pinned 0.4.x line it lives at ``jax.experimental.shard_map.shard_map``
-and spells the replication-check kwarg ``check_rep`` instead of
-``check_vma``. Every shard_map call in the framework routes through
-here so the version split lives in one place (the same pattern as the
-``lax.axis_size`` fallback in collective.py).
+Every shard_map call routes through here, so the mesh/spec keyword
+form and the ``check_vma`` default live in one place.
 """
 
 from __future__ import annotations
@@ -19,14 +15,6 @@ __all__ = ["shard_map"]
 
 def shard_map(f, mesh, in_specs, out_specs,
               check_vma: Optional[bool] = None) -> Any:
-    kwargs = {}
-    if hasattr(jax, "shard_map"):
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-    if check_vma is not None:
-        kwargs["check_rep"] = check_vma  # old spelling, same meaning
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               **kwargs)
+    kwargs = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

@@ -84,11 +84,7 @@ def _axis(group: Optional[Union[CommGroup, AxisName]]) -> AxisName:
 
 
 def _axis_size(axis: AxisName) -> int:
-    # lax.axis_size is recent; on older jax the psum-of-static-1 idiom
-    # gives the same bound-axis size (and raises NameError unbound)
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
+    return lax.axis_size(axis)
 
 
 def _in_traced_collective(axis: AxisName) -> bool:

@@ -469,16 +469,22 @@ class ShardedTrainStep:
         if self.lr_scale != 1.0:
             batch["lr_scale"] = jnp.float32(self.lr_scale)
         batch = self._place_batch(batch)
+        import contextlib
+
         from ..observability import metrics as _obs_metrics
-        if _obs_metrics.enabled():
-            from ..observability import span as _obs_span
-            with _obs_span(self._span_name), self.mesh:
-                self.state, metrics = self._jitted(self.state, batch)
+        from ..observability import span as _obs_span
+        metrics_on = _obs_metrics.enabled()
+        # set_mesh (not the legacy ``with mesh:``): the trace can then
+        # ask jax.sharding.get_abstract_mesh() which mesh it runs under
+        # — kernels/_per_shard wraps each Mosaic kernel in a shard_map
+        # over it, since GSPMD cannot partition one
+        span = _obs_span(self._span_name) if metrics_on \
+            else contextlib.nullcontext()
+        with span, jax.sharding.set_mesh(self.mesh):
+            self.state, metrics = self._jitted(self.state, batch)
+        if metrics_on:
             _obs_metrics.counter("optimizer_steps_total",
                                  "optimizer update steps applied").inc()
-        else:
-            with self.mesh:
-                self.state, metrics = self._jitted(self.state, batch)
         return metrics
 
     @property

@@ -42,32 +42,35 @@ def get_lib() -> str:
                         "native")
 
 
+_REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
 def enable_compile_cache(cache_dir: str = None,
                          min_compile_secs: float = 0.5) -> None:
-    """Enable JAX's persistent compilation cache (repo-root
-    ``.jax_cache/`` by default). The ONE implementation — bench.py,
-    verify, conftest and perf_lab all call this, so the path and the
-    min-compile threshold can't drift between entry points. Safe to
-    call repeatedly; failures are swallowed (the cache is an
-    optimization, never a correctness dependency)."""
+    """Enable JAX's persistent compilation cache. The ONE
+    implementation — bench.py, verify, conftest, chip_smoke and the
+    tools all call this, so the path and the min-compile threshold
+    can't drift between entry points. Safe to call repeatedly.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the directory is the
+    environment's (jax reads it itself) and no directory is set in
+    code, whatever ``cache_dir`` says: whoever runs the process decides
+    where its cache survives. Otherwise it is ``cache_dir``, by default
+    the fixed ``<checkout>/.jax_cache`` — the path is part of the
+    cache key, so it never comes from tempfile, a pid or the clock."""
     import jax
 
-    if cache_dir is None:
-        cache_dir = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            ".jax_cache")
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          min_compile_secs)
-        # tiny CPU executables (tests, the self-test drill) are below
-        # the default entry-size floor — persist everything; dedup is
-        # the cache key's job
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          -1)
-    # ptlint: disable=silent-failure -- these config keys vary across jax versions; a missing one means that knob does not exist to set
-    except Exception:  # noqa: BLE001
-        pass
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          cache_dir or _REPO_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      min_compile_secs)
+    # tiny CPU executables (tests, the self-test drill) are below
+    # the default entry-size floor — persist everything; dedup is
+    # the cache key's job
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _install_cache_listener()
 
 
@@ -94,13 +97,9 @@ def _install_cache_listener() -> None:
     with _LISTENER_LOCK:
         if _LISTENER_INSTALLED:
             return
-        try:
-            from jax import monitoring
-            monitoring.register_event_listener(_on_cache_event)
-            _LISTENER_INSTALLED = True
-        # ptlint: disable=silent-failure -- jax.monitoring is an optional surface; without it cache hit/miss counters simply stay absent
-        except Exception:  # noqa: BLE001
-            pass
+        from jax import monitoring
+        monitoring.register_event_listener(_on_cache_event)
+        _LISTENER_INSTALLED = True
 
 
 def compile_cache_stats() -> dict:
@@ -110,10 +109,12 @@ def compile_cache_stats() -> dict:
 
 def apply_compile_cache_flag() -> None:
     """Point jax's persistent compilation cache at
-    FLAGS_compile_cache_dir if set. Idempotent and cheap — the entry
-    points that trigger compiles (hapi.Model.fit, jit.to_static,
-    inference.Predictor/Server) all call it, because env-provided flag
-    values never fire on_change hooks. Threshold 0: when an operator
+    FLAGS_compile_cache_dir if set (enable_compile_cache's rule holds:
+    a JAX_COMPILATION_CACHE_DIR from the environment keeps the
+    directory and only the threshold is applied). Idempotent and cheap
+    — the entry points that trigger compiles (hapi.Model.fit,
+    jit.to_static, inference.Predictor/Server) all call it, because
+    env-provided flag values never fire on_change hooks. Threshold 0: when an operator
     asks for a persistent cache they mean every executable, including
     the sub-second CPU ones the proof drill measures."""
     global _FLAG_APPLIED_DIR

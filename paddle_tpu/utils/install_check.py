@@ -5,8 +5,9 @@ under the parallel executor, report success/diagnostics).
 TPU adaptation: verifies (1) the backend initializes and reports its
 platform/devices, (2) a jitted train step runs and the loss decreases,
 (3) when >1 device is visible, the same step runs sharded over a dp
-mesh — the three failure classes operators actually hit (wedged PJRT
-tunnel, broken compile cache, bad mesh/sharding install).
+mesh — the three failure classes operators actually hit (a backend
+that fails to initialise, broken compile cache, bad mesh/sharding
+install).
 """
 
 from __future__ import annotations

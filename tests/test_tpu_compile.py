@@ -1,0 +1,130 @@
+"""The main path's Pallas kernels must compile for the chip they ship to.
+
+Interpret mode cannot see what Mosaic refuses (the single-query decode
+kernel passed every parity test and had never compiled for a TPU), so
+each kernel of the train and serve paths is compiled here, at the width
+the chip smoke runs it, for a DESCRIBED v5e — no chip attached, nothing
+executed (/opt/skills/guides/on-chip-measurement/SKILL.md §2). A pass
+says the compiler accepts the kernel; it says nothing about numbers or
+time.
+"""
+
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One device of a described v5e 2x2; skip where the TPU compiler
+    cannot describe it."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without the chip: the next run
+    # would warn and recompile anyway
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    # conftest pins "highest" for op-correctness tests; the chip runs
+    # these kernels at its default precision, and Mosaic refuses an
+    # fp32-precision matmul on bf16 operands
+    with jax.default_matmul_precision("default"):
+        yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, chip, *shapes):
+    """Compile ``fn`` for the described chip; return the program text."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# GPT-2 small serving widths (chip_smoke serve phase): 12 heads of 64,
+# block_size 16; BERT-base training widths: b16 x seq 512, hidden 768.
+_H, _D, _BS, _POOL = 12, 64, 16, 512
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_paged_attention_decode_compiles(chip, dtype):
+    from paddle_tpu.kernels.paged_attention import _paged_attention_impl
+    text = _compile(
+        functools.partial(_paged_attention_impl, scale=_D ** -0.5), chip,
+        ((8, _H, _D), dtype), ((_POOL, _BS, _H, _D), dtype),
+        ((_POOL, _BS, _H, _D), dtype), ((8, 8), jnp.int32),
+        ((8,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_paged_attention_multiquery_compiles(chip):
+    from paddle_tpu.kernels.paged_attention import (
+        _paged_attention_mq_impl)
+    text = _compile(
+        functools.partial(_paged_attention_mq_impl, scale=_D ** -0.5),
+        chip, ((8, 4, _H, _D), jnp.float32), ((8,), jnp.int32),
+        ((_POOL, _BS, _H, _D), jnp.float32),
+        ((_POOL, _BS, _H, _D), jnp.float32), ((8, 8), jnp.int32),
+        ((8,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_flash_attention_bthd_fwd_bwd_compiles(chip):
+    from paddle_tpu.kernels.flash_attention import flash_attention
+
+    def loss(q, k, v, seed, kv_bias):
+        out = flash_attention(q, k, v, seed=seed, dropout_p=0.1,
+                              kv_bias=kv_bias, bthd=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    qkv = ((16, 512, _H, _D), jnp.bfloat16)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), chip, qkv, qkv,
+                    qkv, ((1, 1), jnp.int32), ((16, 512), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+def test_layer_norm_fwd_bwd_compiles(chip):
+    from paddle_tpu.kernels.layer_norm import layer_norm_pallas
+
+    def loss(x, w, b):
+        return jnp.sum(layer_norm_pallas(x, w, b, 1e-12)
+                       .astype(jnp.float32))
+
+    # value_and_grad: the backward is XLA ops, so the kernel is in the
+    # program only while the forward value is an output
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), chip,
+                    ((8192, 768), jnp.bfloat16), ((768,), jnp.float32),
+                    ((768,), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+def test_fused_linear_softmax_xent_fwd_bwd_compiles(chip):
+    from paddle_tpu.kernels.fused_softmax_xent import (
+        fused_linear_softmax_xent)
+
+    def loss(hidden, weight, bias, labels):
+        return jnp.sum(fused_linear_softmax_xent(hidden, weight, bias,
+                                                 labels))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), chip,
+                    ((8192, 768), jnp.bfloat16),
+                    ((30522, 768), jnp.bfloat16),
+                    ((30522,), jnp.float32), ((8192,), jnp.int32))
+    assert "tpu_custom_call" in text
