@@ -83,6 +83,7 @@ def low_precision_policy(x, op_name: str = "matmul"):
     return x
 
 
+@jax.named_scope("pt.guard")
 def all_finite(tree) -> jax.Array:
     """Scalar bool: every floating leaf of ``tree`` is finite — the
     check half of the reference's amp_check_finite_and_scale op,
@@ -98,6 +99,7 @@ def all_finite(tree) -> jax.Array:
     return jnp.all(jnp.stack(checks))
 
 
+@jax.named_scope("pt.guard")
 def select_update(found_inf, updated, current):
     """Per-leaf ``where(found_inf, current, updated)`` over two
     same-structure pytrees: the skip-step half of the reference's AMP

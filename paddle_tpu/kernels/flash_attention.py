@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability.xprof import note_kernel
+
 # 512 tiles measured fastest on chip (r5 d64 train sweep, v5e:
 # 512-tile 1.18x/1.58x/2.08x vs XLA at seq 1k/2k/4k, dominating
 # 256-tile 1.08x/1.36x/1.65x; 128-tile loses to XLA beyond 512).
@@ -54,6 +56,37 @@ def _block_sizes(tq: int, tk: int):
         pass
     bq, bk = bq or BLOCK_Q, bk or BLOCK_K
     return min(bq, tq), min(bk, tk)
+
+
+def flash_fwd_work(b: int, h: int, tq: int, tk: int, d: int,
+                   itemsize: int, causal: bool = False):
+    """(FLOPs, HBM bytes) one forward call must do: the two matmuls
+    QK^T and PV (``4*B*H*Tq*Tk*D``, half of it under a causal mask);
+    q, k, v read and the output written once, plus the f32 logsumexp
+    row."""
+    flops = 4.0 * b * h * tq * tk * d
+    if causal:
+        flops /= 2
+    return flops, float(b * h * (2 * tq + 2 * tk) * d * itemsize
+                        + 4 * b * h * tq)
+
+
+def flash_bwd_work(b: int, h: int, tq: int, tk: int, d: int,
+                   itemsize: int, causal: bool = False,
+                   matmuls: int = 5):
+    """(FLOPs, HBM bytes) one backward call must do. The recompute
+    backward runs five matmuls — S = QK^T, dP = dO V^T, dV = P^T dO,
+    dQ = dS K, dK = dS^T Q: ``10*B*H*Tq*Tk*D`` — in the fused
+    single-block kernel; split in two, the dq kernel runs three of them
+    (S, dP, dQ) and the dk/dv kernel four (S, dP, dV, dK). Bytes: q, k,
+    v, dO read, the two f32 row vectors (lse, delta), and the
+    gradients this call writes."""
+    flops = 2.0 * matmuls * b * h * tq * tk * d
+    if causal:
+        flops /= 2
+    written = {5: tq + 2 * tk, 3: tq, 4: 2 * tk}[matmuls]
+    return flops, float(b * h * (2 * tq + 2 * tk + written) * d
+                        * itemsize + 8 * b * h * tq)
 
 
 def _heads_per_block(d: int, h: int) -> int:
@@ -324,7 +357,10 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
         ],
         interpret=interpret,
         compiler_params=_GRID_PARALLEL,
+        name="flash_fwd",
     )(qr, kr, vr, _seed_arr(seed), _bias_arr(kv_bias, b, tk, tk_p))
+    note_kernel("flash_fwd", *flash_fwd_work(
+        b, h, tq, tk, d, q.dtype.itemsize, causal))
     # lse -> [B, H, Tq]: head = group*hpb + half, so the trailing half
     # dim interleaves back via a (tiny, h*tq fp32) transpose
     lse_pub = lse[:, :tq, :].reshape(b, hg, tq, hpb)
@@ -677,6 +713,7 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
     bias_a = _bias_arr(kv_bias, b, tk, tk_p)
     bias_map = (lambda g_, i: (g_ // hg, 0, 0)) if has_bias else \
         (lambda g_, i: (0, 0, 0))
+    shape = (b, h, tq, tk, d, q.dtype.itemsize)     # for the work notes
     row_spec = pl.BlockSpec((1, bq, hpb), lambda g_, i: (g_, i, 0),
                             memory_space=pltpu.VMEM)
     rowfull_spec = pl.BlockSpec((1, tq_p, hpb),
@@ -712,7 +749,9 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
             out_shape=[dq_struct, dk_struct, dv_struct],
             interpret=interpret,
             compiler_params=_GRID_PARALLEL,
+            name="flash_bwd",
         )(qr, kr, vr, dor, lse_r, delta, seed_a, bias_a)
+        note_kernel("flash_bwd", *flash_bwd_work(*shape, causal))
         if bthd:
             return (dq[:, :tq].reshape(b, tq, h, d),
                     dk[:, :tk].reshape(b, tk, h, d),
@@ -742,7 +781,10 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
         out_shape=dq_struct,
         interpret=interpret,
         compiler_params=_GRID_PARALLEL,
+        name="flash_bwd_dq",
     )(qr, kr, vr, dor, lse_r, delta, seed_a, bias_a)
+    note_kernel("flash_bwd_dq", *flash_bwd_work(*shape, causal,
+                                                matmuls=3))
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -773,7 +815,10 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
         out_shape=[dk_struct, dv_struct],
         interpret=interpret,
         compiler_params=_GRID_PARALLEL,
+        name="flash_bwd_dkv",
     )(qr, kr, vr, dor, lse_r, delta, seed_a, bias_a)
+    note_kernel("flash_bwd_dkv", *flash_bwd_work(*shape, causal,
+                                                 matmuls=4))
 
     if bthd:
         return (dq[:, :tq].reshape(b, tq, h, d),

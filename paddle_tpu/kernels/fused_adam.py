@@ -28,7 +28,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability.xprof import note_kernel
+
 _BLOCK = 8 * 128 * 64  # elements per grid step (fits VMEM x4 buffers)
+
+
+def fused_adam_work(n: int, param_itemsize: int = 4):
+    """(FLOPs, HBM bytes) one fused Adam call must do over ``n``
+    elements. The bytes bound it: parameter, gradient and both f32
+    moments read, parameter and moments written; some twelve vector
+    operations an element."""
+    return 12.0 * n, float(n * (2 * param_itemsize + 20))
 
 
 def _adam_kernel(p_ref, g_ref, m_ref, v_ref, sc_ref,
@@ -103,7 +113,10 @@ def fused_adam_leaf(p, g, m, v, lr_corrected, beta1: float, beta2: float,
             jax.ShapeDtypeStruct(v2.shape, jnp.float32),
         ],
         interpret=interpret,
+        name="fused_adam_leaf",
     )(p2, g2, m2, v2, sc)
+    note_kernel("fused_adam_leaf", *fused_adam_work(
+        rows * cols, p.dtype.itemsize))
     return (p_new.reshape(shape), m_new.reshape(shape),
             v_new.reshape(shape))
 
@@ -119,6 +132,8 @@ def fused_adam_flat(p, g, m, v, lr_corrected, beta1: float, beta2: float,
     kernel = functools.partial(_adam_kernel, beta1=beta1, beta2=beta2,
                                eps=eps, weight_decay=weight_decay)
     sc = jnp.asarray(lr_corrected, jnp.float32).reshape(1)
+    note_kernel("fused_adam_flat", *fused_adam_work(
+        n, p.dtype.itemsize))
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -150,4 +165,5 @@ def fused_adam_flat(p, g, m, v, lr_corrected, beta1: float, beta2: float,
         # may reuse the old param after this call; XLA still schedules the
         # update in-place when the buffers are donated at the jit boundary
         interpret=interpret,
+        name="fused_adam_flat",
     )(p, g, m, v, sc)

@@ -42,6 +42,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability.xprof import note_kernel
+
 _ROW_BLOCK = 256     # row tile (second-to-minor: multiple of 8)
 _VOCAB_BLOCK = 512   # vocab tile (minor: multiple of 128)
 # finite -inf stand-in: exp(_NEG - m) underflows to exactly 0.0 and
@@ -127,6 +129,19 @@ def _bwd_dw_kernel(h_ref, w_ref, b_ref, lab_ref, lse_ref, g_ref, dw_ref,
     db_ref[:] = db_ref[:] + jnp.sum(dlog, axis=0, keepdims=True)
 
 
+def fused_xent_work(n: int, v: int, hd: int, matmuls: int = 1,
+                    written: int = 0):
+    """(FLOPs, HBM bytes) one call must do over ``n`` positions, a
+    vocabulary of ``v`` and hidden width ``hd``, operands as padded.
+    The forward runs the projection once (``2*N*V*H``); each backward
+    kernel recomputes it and runs one more matmul (dh = dlogits W, or
+    dW = dlogits^T h). Bytes: the f32 hidden rows, weight and bias read
+    once (the logits never exist in HBM) and ``written`` f32 elements
+    of result."""
+    return (2.0 * matmuls * n * v * hd,
+            float(4 * (n * hd + v * hd + v + written)))
+
+
 def _padded_operands(h2, w, b2, lab, bn, bv):
     """Pad to tile multiples. Vocab padding gets bias _NEG so padded
     columns vanish from both the LSE (exp underflows to 0) and the
@@ -165,7 +180,10 @@ def _forward(h2, w, b2, lab, ignore_index, bn, bv, interpret):
         out_shape=[jax.ShapeDtypeStruct((n_pad, 1), jnp.float32)] * 3,
         compiler_params=_GRID_SEQ,
         interpret=interpret,
+        name="fused_xent_fwd",
     )(hp, wp, bp, labp)
+    note_kernel("fused_xent_fwd", *fused_xent_work(
+        n_pad, v_pad, h_pad, written=3 * n_pad))
     lse = (m + jnp.log(s))[:n, 0]
     picked = picked[:n, 0]
     loss = jnp.where(lab != ignore_index, lse - picked, 0.0)
@@ -200,7 +218,10 @@ def _backward(res, g, ignore_index, bn, bv, interpret):
         out_shape=jax.ShapeDtypeStruct((n_pad, h_pad), jnp.float32),
         compiler_params=_GRID_SEQ,
         interpret=interpret,
+        name="fused_xent_bwd_dh",
     )(hp, wp, bp, labp, lsep, gp)
+    note_kernel("fused_xent_bwd_dh", *fused_xent_work(
+        n_pad, v_pad, h_pad, matmuls=2, written=n_pad * h_pad))
     col_spec = pl.BlockSpec((bn, 1), lambda jv, i: (i, 0), **ms)
     dw, db = pl.pallas_call(
         functools.partial(_bwd_dw_kernel, block_v=bv),
@@ -221,7 +242,10 @@ def _backward(res, g, ignore_index, bn, bv, interpret):
         ],
         compiler_params=_GRID_SEQ,
         interpret=interpret,
+        name="fused_xent_bwd_dw",
     )(hp, wp, bp, labp, lsep, gp)
+    note_kernel("fused_xent_bwd_dw", *fused_xent_work(
+        n_pad, v_pad, h_pad, matmuls=2, written=v_pad * h_pad + v_pad))
     return dh[:n, :hd], dw[:v, :hd], db[0, :v]
 
 

@@ -23,7 +23,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability.xprof import note_kernel
+
 _ROW_BLOCK = 256
+
+
+def layer_norm_fwd_work(rows: int, cols: int, itemsize: int):
+    """(FLOPs, HBM bytes) one forward call must do. The bytes bound it:
+    every row read and written once, the two f32 affine vectors read;
+    some eight vector operations an element, none of them a matmul."""
+    return 8.0 * rows * cols, float(2 * rows * cols * itemsize
+                                    + 8 * cols)
 
 
 def _ln_kernel(x_ref, w_ref, b_ref, o_ref, *, eps: float):
@@ -42,6 +52,8 @@ def _ln_forward(x, w, b, eps: float, interpret: bool):
     grid = (pl.cdiv(rows, block),)
     kernel = functools.partial(_ln_kernel, eps=eps)
     ms = {} if interpret else {"memory_space": pltpu.VMEM}
+    note_kernel("layer_norm_fwd", *layer_norm_fwd_work(
+        rows, cols, x.dtype.itemsize))
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -53,6 +65,7 @@ def _ln_forward(x, w, b, eps: float, interpret: bool):
         out_specs=pl.BlockSpec((block, cols), lambda i: (i, 0), **ms),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
+        name="layer_norm_fwd",
     )(x, w, b)
 
 

@@ -42,7 +42,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability.xprof import note_kernel
+
 _NEG_INF = -1e30
+
+
+def paged_attention_work(b: int, q_rows: int, h: int, d: int,
+                         table_tokens: int, itemsize: int):
+    """(FLOPs, HBM bytes) of one call, as an UPPER bound from shapes:
+    every sequence attending its whole block table (``table_tokens`` =
+    max_blocks * block_size). The live context lengths are runtime
+    values; whole blocks past them are skipped, so the work done is
+    this times the table's fill. QK^T and PV over ``q_rows`` query
+    rows a sequence; K and V blocks, q and the output moved once."""
+    return (4.0 * b * q_rows * h * table_tokens * d,
+            float(b * h * d * itemsize * (2 * table_tokens
+                                          + 2 * q_rows)))
 
 # Grid dims: (sequence, kv-block scan). The scan dim carries the
 # online-softmax state in scratch, so it MUST run sequentially;
@@ -174,12 +189,15 @@ def _paged_attention_impl(q, k_pool, v_pool, block_tables, context_lens,
     )
     kernel = functools.partial(_paged_attn_kernel,
                                block_size=block_size, scale=scale)
+    note_kernel("paged_decode", *paged_attention_work(
+        b, 1, h, d, max_blocks * block_size, q.dtype.itemsize))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
         compiler_params=_GRID_SEMANTICS,
+        name="paged_decode",
     )(tables, lens, q, k_pool, v_pool)
 
 
@@ -319,12 +337,15 @@ def _paged_attention_mq_impl(q, q_lens, k_pool, v_pool, block_tables,
     )
     kernel = functools.partial(_paged_attn_mq_kernel,
                                block_size=block_size, scale=scale)
+    note_kernel("paged_ragged", *paged_attention_work(
+        b, qmax, h, d, max_blocks * block_size, q.dtype.itemsize))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, qmax, h, d), q.dtype),
         interpret=interpret,
         compiler_params=_GRID_SEMANTICS,
+        name="paged_ragged",
     )(tables, lens, qlens, q, k_pool, v_pool)
 
 

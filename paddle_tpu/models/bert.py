@@ -13,6 +13,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 
 from .. import nn
@@ -68,6 +69,9 @@ class BertEmbeddings(nn.Layer):
         self.layer_norm = nn.LayerNorm(config.hidden_size, epsilon=1e-12)
         self.dropout = nn.Dropout(config.hidden_dropout_prob)
 
+    # device scope (docs/observability.md): a profile reads the
+    # look-ups, the embedding norm and their backward as one block
+    @jax.named_scope("pt.embed")
     def forward(self, input_ids, token_type_ids=None):
         seq = input_ids.shape[1]
         pos_ids = jnp.arange(seq, dtype=jnp.int32)[None, :]
@@ -108,7 +112,8 @@ class BertModel(nn.Layer):
             mask = (1.0 - attention_mask[:, None, None, :].astype(
                 emb.dtype)) * jnp.finfo(jnp.float32).min
         seq_out = self.encoder(emb, src_mask=mask)
-        pooled = self.pooler_act(self.pooler(seq_out[:, 0]))
+        with jax.named_scope("pt.head_loss"):
+            pooled = self.pooler_act(self.pooler(seq_out[:, 0]))
         return seq_out, pooled
 
 
@@ -123,6 +128,7 @@ class BertPretrainingHeads(nn.Layer):
             jnp.zeros((config.vocab_size,), jnp.float32))
         self.seq_relationship = nn.Linear(config.hidden_size, 2)
 
+    @jax.named_scope("pt.head_loss")
     def forward(self, sequence_output, pooled_output, word_embedding_weight):
         from ..kernels import fused_softmax_xent_enabled
         h = self.transform_norm(self.transform_act(
@@ -159,13 +165,16 @@ class BertForPretraining(nn.Layer):
         seq_out, pooled = self.bert(input_ids, token_type_ids,
                                     attention_mask)
         if masked_positions is not None:
-            seq_out = jnp.take_along_axis(
-                seq_out,
-                masked_positions[:, :, None].astype(jnp.int32), axis=1)
+            with jax.named_scope("pt.head_loss"):
+                seq_out = jnp.take_along_axis(
+                    seq_out,
+                    masked_positions[:, :, None].astype(jnp.int32),
+                    axis=1)
         return self.cls(seq_out, pooled,
                         self.bert.embeddings.word_embeddings.weight)
 
 
+@jax.named_scope("pt.head_loss")
 def pretraining_loss(outputs, mlm_labels, nsp_labels,
                      ignore_index: int = -100):
     """Masked-LM + next-sentence loss."""

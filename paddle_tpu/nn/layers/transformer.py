@@ -152,21 +152,26 @@ class TransformerEncoderLayer(Layer):
         self.normalize_before = normalize_before
 
     def forward(self, src, src_mask=None):
-        residual = src
-        if self.normalize_before:
-            src = self.norm1(src)
-        src = self.self_attn(src, attn_mask=src_mask)
-        src = residual + self.dropout1(src)
-        if not self.normalize_before:
-            src = self.norm1(src)
-        residual = src
-        if self.normalize_before:
-            src = self.norm2(src)
-        src = self.linear2(self.act_dropout(self.activation(
-            self.linear1(src))))
-        src = residual + self.dropout2(src)
-        if not self.normalize_before:
-            src = self.norm2(src)
+        # the two device scopes a profile reads an encoder stack by
+        # (docs/observability.md): each sub-block with its residual
+        # add and norm, forward and backward alike
+        with jax.named_scope("pt.attn"):
+            residual = src
+            if self.normalize_before:
+                src = self.norm1(src)
+            src = self.self_attn(src, attn_mask=src_mask)
+            src = residual + self.dropout1(src)
+            if not self.normalize_before:
+                src = self.norm1(src)
+        with jax.named_scope("pt.ffn"):
+            residual = src
+            if self.normalize_before:
+                src = self.norm2(src)
+            src = self.linear2(self.act_dropout(self.activation(
+                self.linear1(src))))
+            src = residual + self.dropout2(src)
+            if not self.normalize_before:
+                src = self.norm2(src)
         return src
 
 

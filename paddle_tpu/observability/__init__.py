@@ -186,6 +186,7 @@ def reset_all() -> None:
     get_tracer().reset()
     recompile_tracker().reset()
     program_cards().reset()
+    xprof.reset_kernel_notes()
     anomaly_sentinel().reset()
     goodput_ledger().reset()
     flight_recorder().reset()

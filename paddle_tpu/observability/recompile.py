@@ -24,6 +24,7 @@ hit/latency accounting is gated on FLAGS_enable_metrics.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 import warnings
@@ -65,6 +66,14 @@ class FunctionRecord:
         self.signatures: List[str] = []
         self.compile_times_s: List[float] = []
         self._warned = False
+        # what the newest trace under metrics left to lower from again
+        # (xprof.op_scopes): abstract (args, kwargs) and — once the
+        # dispatch that traced has returned — the mesh in scope, its
+        # arguments' shardings and the jitted callable. The callable
+        # keeps its owner (a TrainStep and its state) alive until the
+        # next trace under this name, metrics going off at a trace, or
+        # reset(): whoever asks may come after the owner was dropped.
+        self._kept: Optional[Dict[str, Any]] = None
 
     # -- trace side --------------------------------------------------------
 
@@ -74,12 +83,12 @@ class FunctionRecord:
             # bookkeeping, not user-visible recompilation
             return
         sig = _abstract_signature(args, kwargs)
-        if _xprof.enabled():
+        if _metrics.enabled():
             # Capture the abstract signature as ShapeDtypeStructs while
             # the tracers are live: after a donated-argnum dispatch the
             # concrete args are deleted, so this is the only safe point
-            # to keep a lowerable description for the program-card
-            # harvest.
+            # to keep a lowerable description (for the program-card
+            # harvest and for xprof.op_scopes).
             import jax
 
             def to_sds(x):
@@ -87,13 +96,21 @@ class FunctionRecord:
                 dtype = getattr(x, "dtype", None)
                 if shape is None or dtype is None:
                     return x
-                return jax.ShapeDtypeStruct(tuple(shape), dtype)
+                weak = bool(getattr(getattr(x, "aval", None),
+                                    "weak_type", False))
+                return jax.ShapeDtypeStruct(tuple(shape), dtype,
+                                            weak_type=weak)
 
             try:
-                self._tls.pending_avals = (
-                    jax.tree.map(to_sds, (args, kwargs)), sig)
+                kept = {"avals": jax.tree.map(to_sds, (args, kwargs)),
+                        "signature": sig}
             except Exception:  # noqa: BLE001 — analytics never break a trace
-                self._tls.pending_avals = None
+                kept = None
+            with self._lock:
+                self._kept = kept
+        else:
+            with self._lock:
+                self._kept = None
         threshold = None
         with self._lock:
             self.traces += 1
@@ -136,7 +153,9 @@ class FunctionRecord:
         """Wrap ``fn`` (pre-jit) so tracing it is observed."""
         def traced(*args, **kwargs):
             self.note_trace(args, kwargs)
-            return fn(*args, **kwargs)
+            # kernels traced inside note their work under this name
+            with _xprof.tracing(self.name):
+                return fn(*args, **kwargs)
         traced.__name__ = getattr(fn, "__name__", "fn")
         traced.__qualname__ = getattr(fn, "__qualname__", traced.__name__)
         traced.__wrapped__ = fn
@@ -144,12 +163,75 @@ class FunctionRecord:
 
     # -- call side ---------------------------------------------------------
 
-    def take_pending_avals(self):
-        """Pop the (avals, signature) captured by the latest trace on
-        this thread (None when analytics were off at trace time)."""
-        pending = getattr(self._tls, "pending_avals", None)
-        self._tls.pending_avals = None
-        return pending
+    def keep_call(self, jitted: "_InstrumentedJit", args, kwargs) -> None:
+        """After a dispatch that traced: remember who to lower again
+        and where its arguments lived (a batch committed to a mesh
+        carries its sharding into jit; an abstract value must be told
+        it, or the program lowered again is not the one that ran)."""
+        import jax
+        with self._lock:
+            kept = self._kept
+        if kept is None:
+            return
+        kept["jitted"] = jitted
+        # the caller's set_mesh is still in scope here (get_mesh cannot
+        # be asked inside the trace)
+        kept["mesh"] = jax.sharding.get_mesh()
+
+        def placed(aval, x):
+            sharding = getattr(x, "sharding", None) \
+                if isinstance(x, jax.Array) else None
+            # on one device there is nothing to tell, and telling it
+            # would change the persistent cache's key: the program
+            # lowered again must load, not compile
+            if sharding is None or len(sharding.device_set) == 1 or \
+                    not isinstance(aval, jax.ShapeDtypeStruct):
+                return aval
+            return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                                        sharding=sharding,
+                                        weak_type=aval.weak_type)
+
+        try:
+            kept["avals"] = jax.tree.map(placed, kept["avals"],
+                                         (args, kwargs))
+        # ptlint: disable=silent-failure -- a pytree that cannot be walked beside its own abstract copy keeps the unplaced signature; analytics never break a step
+        except ValueError:
+            pass
+
+    @contextlib.contextmanager
+    def lowering_again(self):
+        """What the newest trace kept — ``None`` when metrics were off
+        at it, or the dispatch that traced failed — with the mesh that
+        was in scope then set again and the trace that a second
+        lowering causes not counted."""
+        import jax
+        with self._lock:
+            kept = self._kept
+        if kept is None or "jitted" not in kept:
+            yield None
+            return
+        scope = contextlib.nullcontext() if kept["mesh"].empty \
+            else jax.sharding.set_mesh(kept["mesh"])
+        self._tls.suppress = True
+        try:
+            with scope:
+                yield kept
+        finally:
+            self._tls.suppress = False
+
+    def compile_again(self):
+        """Lower and compile the entry point from what its newest trace
+        kept. ``None`` when nothing was kept or metrics are off now: a
+        step's body reads that flag while it is traced, so lowering it
+        again with the flag off could give another program than the
+        one that ran."""
+        if not _metrics.enabled():
+            return None
+        with self.lowering_again() as kept:
+            if kept is None:
+                return None
+            args, kwargs = kept["avals"]
+            return kept["jitted"].lower(*args, **kwargs).compile()
 
     def on_call(self, dt_s: float) -> bool:
         """Classify the finished dispatch; returns True when it traced."""
@@ -198,30 +280,25 @@ class _InstrumentedJit:
             # still consume a pending trace marker so a later enabled
             # call is not misclassified as a compile
             rec._tls.traced = False
-            rec._tls.pending_avals = None
             return self._jitted(*args, **kwargs)
         t0 = time.perf_counter()
         out = self._jitted(*args, **kwargs)
         traced = rec.on_call(time.perf_counter() - t0)
         if traced:
+            rec.keep_call(self, args, kwargs)
             self._maybe_harvest(rec)
         return out
 
     def _maybe_harvest(self, rec: "FunctionRecord") -> None:
         """Program-card harvest for the trace that just completed. Runs
-        lower().compile() over the captured ShapeDtypeStructs (no data,
-        donation-safe); the re-trace it causes is suppressed from the
-        recompile stats."""
-        pending = rec.take_pending_avals()
-        if pending is None or not _xprof.enabled():
+        lower().compile() over the kept ShapeDtypeStructs (no data,
+        donation-safe)."""
+        if not _xprof.enabled():
             return
-        (avals_args, avals_kwargs), sig = pending
-        rec._tls.suppress = True
-        try:
-            _xprof.harvest(rec.name, self._jitted, avals_args,
-                           avals_kwargs, sig)
-        finally:
-            rec._tls.suppress = False
+        with rec.lowering_again() as kept:
+            if kept is not None:
+                _xprof.harvest(rec.name, self._jitted, *kept["avals"],
+                               kept["signature"])
 
     def __getattr__(self, item):
         return getattr(self._jitted, item)

@@ -25,18 +25,34 @@ cost only, never a steady-state one. Backends whose analyses are empty
 or unsupported produce a card with an explicit ``unavailable`` marker
 instead of an error (the CPU fallback contract tested in
 tests/test_observability.py).
+
+Two more things only the traced program knows are kept here while
+metrics are on, both at trace time and nothing per step:
+
+- ``note_kernel``: each Pallas call site notes ``(name, flops,
+  bytes)`` — the work one call must do, from its shapes — under the jit
+  entry point being traced (``kernel_notes(fn)``), so a kernel's device
+  time from a profile can be set against a peak;
+- ``op_scopes(fn)``: on demand, the compiled program's
+  ``{instruction name: op_name}``. A device profile names an operation
+  by its HLO instruction; the ``jax.named_scope`` it was traced under
+  (``pt.attn``, ``pt.optimizer``, ... — docs/observability.md) is in
+  that instruction's ``op_name`` metadata, which the profile may lack.
 """
 
 from __future__ import annotations
 
+import contextlib
+import re
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 from . import metrics as _metrics
 
 __all__ = ["ProgramCardRegistry", "cards", "enabled", "harvest",
-           "flops_of"]
+           "flops_of", "note_kernel", "kernel_notes", "op_scopes",
+           "parse_op_names"]
 
 # Cost-analysis keys promoted onto the card top level when present.
 _COST_KEYS = ("flops", "transcendentals", "bytes accessed")
@@ -189,3 +205,113 @@ def flops_of(name: str) -> Optional[float]:
         return None
     v = card.get("flops")
     return float(v) if v else None
+
+
+# -- kernel work noted at trace time -------------------------------------------
+
+KernelNote = Tuple[str, float, float]      # (name, flops, bytes) of one call
+
+_TLS = threading.local()
+_NOTES_LOCK = threading.Lock()
+_KERNEL_NOTES: Dict[str, List[KernelNote]] = {}
+
+
+@contextlib.contextmanager
+def tracing(fn_name: str) -> Iterator[None]:
+    """Entered by the recompile tracker round the body of a jit entry
+    point, which runs only while jax traces it: kernels traced inside
+    note their work under ``fn_name``. The newest trace replaces the
+    notes of the one before (a retrace, or ``op_scopes`` lowering the
+    entry point again, must not count a call site twice)."""
+    outer = getattr(_TLS, "notes", None)
+    _TLS.notes = notes = []
+    try:
+        yield
+    finally:
+        _TLS.notes = outer
+        if outer is not None:       # an entry point traced inside another
+            outer.extend(notes)
+        if _metrics.enabled():
+            with _NOTES_LOCK:
+                _KERNEL_NOTES[fn_name] = notes
+
+
+def note_kernel(name: str, flops: float, bytes_: float) -> None:
+    """Called by a kernel wrapper beside its ``pallas_call``, once per
+    traced call site: the FLOPs and HBM bytes one call must do. A no-op
+    unless metrics are on and a tracked entry point is being traced."""
+    notes = getattr(_TLS, "notes", None)
+    if notes is not None and _metrics.enabled():
+        notes.append((name, float(flops), float(bytes_)))
+
+
+def kernel_notes(fn_name: str) -> List[KernelNote]:
+    """The call sites noted by the newest trace of ``fn_name``."""
+    with _NOTES_LOCK:
+        return list(_KERNEL_NOTES.get(fn_name, ()))
+
+
+def reset_kernel_notes() -> None:
+    with _NOTES_LOCK:
+        _KERNEL_NOTES.clear()
+
+
+# -- instruction -> op_name, from the compiled text -----------------------------
+
+_INSTRUCTION_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+_OP_NAME_RE = re.compile(r"\bmetadata=\{[^}]*?op_name=\"([^\"]*)\"")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+
+
+def parse_op_names(hlo_text: str) -> Dict[str, str]:
+    """``{instruction name: op_name}`` of every instruction in an HLO
+    module's text (names are unique in a module).
+
+    What the compiler adds carries no metadata — on a TPU mostly the
+    moves between memory spaces (``copy-start``/``copy-done``,
+    ``slice-start``/``slice-done`` and the bitcast that joins them),
+    several per cent of a train step. Such an instruction takes the
+    ``op_name`` of the first instruction that uses its result, through
+    other such instructions: the move is charged to the work that
+    needed the data. ``""`` where no user has one either."""
+    own: Dict[str, str] = {}
+    users: Dict[str, List[str]] = {}
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION_RE.match(line)
+        if not m:
+            continue
+        name = m.group(1)
+        found = _OP_NAME_RE.search(line)
+        own[name] = found.group(1) if found else ""
+        for operand in _OPERAND_RE.findall(line[m.end():]):
+            users.setdefault(operand, []).append(name)
+
+    def inherited(name: str, depth: int = 8) -> str:
+        for user in users.get(name, ()) if depth else ():
+            op_name = own.get(user) or inherited(user, depth - 1)
+            if op_name:
+                return op_name
+        return ""
+
+    return {name: op_name or inherited(name)
+            for name, op_name in own.items()}
+
+
+def op_scopes(fn_name: str) -> Optional[Dict[str, str]]:
+    """Lower and compile the jit entry point ``fn_name`` again from the
+    abstract signature its newest trace left with the recompile tracker
+    (kept only while metrics are on), and return the compiled program's
+    ``{instruction name: op_name}``. Costs a compile, or a load from
+    the persistent cache: for whoever asks (a profile's reader, an
+    operator), never on a step. The trace it causes is not counted as
+    a recompilation. ``None`` when there is nothing to lower from, or
+    metrics are off (ask while they are on, as they were at the
+    trace: the program lowered must be the one that ran)."""
+    from . import recompile as _recompile
+    rec = _recompile.tracker().get(fn_name)
+    if rec is None:
+        return None
+    compiled = rec.compile_again()
+    if compiled is None:
+        return None
+    return parse_op_names(compiled.as_text())

@@ -200,6 +200,9 @@ class Optimizer:
     def init_slots(self, p) -> Dict[str, jax.Array]:
         return {}
 
+    # device scope (docs/observability.md): clip, regularisation, the
+    # update and the master-to-parameter cast read as one block
+    @jax.named_scope("pt.optimizer")
     def apply_gradients(self, params, grads, state,
                         lr_override=None) -> Tuple[Any, Dict[str, Any]]:
         step = state["step"] + 1

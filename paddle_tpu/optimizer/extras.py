@@ -47,6 +47,7 @@ class _WrappedOptimizer(Optimizer):
     def wrap_init(self, params):
         return {}
 
+    @jax.named_scope("pt.optimizer")
     def apply_gradients(self, params, grads, state, lr_override=None):
         inner_state = {k: v for k, v in state.items() if k != "wrap"}
         new_params, new_inner = self.inner.apply_gradients(
@@ -189,6 +190,7 @@ class GradientMerge(_WrappedOptimizer):
         }
         return state
 
+    @jax.named_scope("pt.optimizer")
     def apply_gradients(self, params, grads, state, lr_override=None):
         wrap = state["wrap"]
         acc = jax.tree.map(jnp.add, wrap["acc"], grads)

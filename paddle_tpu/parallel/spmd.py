@@ -460,29 +460,30 @@ class ShardedTrainStep:
         # model-forward kwargs ride the batch like args (same contract
         # as TrainStep — e.g. BERT's masked_positions); their leaves
         # shard per batch_spec when shardable, else replicate
-        batch = inject_host_lr(
-            {"args": args, "labels": as_label_tuple(labels),
-             "kwargs": kwargs},
-            self.optimizer)
-        from ..static import inject_fault_mults
-        inject_fault_mults(batch)
-        if self.lr_scale != 1.0:
-            batch["lr_scale"] = jnp.float32(self.lr_scale)
-        batch = self._place_batch(batch)
-        import contextlib
-
         from ..observability import metrics as _obs_metrics
         from ..observability import span as _obs_span
-        metrics_on = _obs_metrics.enabled()
+        from ..static import inject_fault_mults
+
+        # the entry point's host spans, as TrainStep's
+        # (docs/observability.md); nothing is drained here: the probes
+        # of this step stream through host callbacks
+        with _obs_span("pt/train_step/make_batch"):
+            batch = inject_host_lr(
+                {"args": args, "labels": as_label_tuple(labels),
+                 "kwargs": kwargs},
+                self.optimizer)
+            inject_fault_mults(batch)
+            if self.lr_scale != 1.0:
+                batch["lr_scale"] = jnp.float32(self.lr_scale)
+            batch = self._place_batch(batch)
         # set_mesh (not the legacy ``with mesh:``): the trace can then
         # ask jax.sharding.get_abstract_mesh() which mesh it runs under
         # — kernels/_per_shard wraps each Mosaic kernel in a shard_map
         # over it, since GSPMD cannot partition one
-        span = _obs_span(self._span_name) if metrics_on \
-            else contextlib.nullcontext()
-        with span, jax.sharding.set_mesh(self.mesh):
+        with _obs_span("pt/train_step/dispatch", fn=self._span_name), \
+                jax.sharding.set_mesh(self.mesh):
             self.state, metrics = self._jitted(self.state, batch)
-        if metrics_on:
+        if _obs_metrics.enabled():
             _obs_metrics.counter("optimizer_steps_total",
                                  "optimizer update steps applied").inc()
         return metrics

@@ -326,6 +326,7 @@ def _note_nonfinite_host(fired: bool) -> None:
         pass
 
 
+@jax.named_scope("pt.probe")
 def probe_nonfinite(found_inf) -> None:
     """Stream the skip-step guard's verdict to the host (traced
     context): async jax.debug.callback like anomaly.probe — baked in
@@ -505,16 +506,18 @@ class TrainStep:
             # per-step sync). In persistent-cache mode the scalars ride
             # the step outputs instead and are drained host-side — a
             # callback in the HLO would make the executable uncacheable.
-            gnorm = jnp.sqrt(sum(
-                jnp.sum(jnp.square(g.astype(jnp.float32)))
-                for g in jax.tree.leaves(grads)
-                if jnp.issubdtype(getattr(g, "dtype", jnp.int32),
-                                  jnp.inexact)) + 0.0)
-            if self._defer_probes:
-                deferred["_pt_gnorm"] = gnorm
-            else:
-                _obs.anomaly.probe("loss", loss)
-                _obs.anomaly.probe("grad_norm", gnorm)
+            # pt.probe: what metrics being on costs on the device
+            with jax.named_scope("pt.probe"):
+                gnorm = jnp.sqrt(sum(
+                    jnp.sum(jnp.square(g.astype(jnp.float32)))
+                    for g in jax.tree.leaves(grads)
+                    if jnp.issubdtype(getattr(g, "dtype", jnp.int32),
+                                      jnp.inexact)) + 0.0)
+                if self._defer_probes:
+                    deferred["_pt_gnorm"] = gnorm
+                else:
+                    _obs.anomaly.probe("loss", loss)
+                    _obs.anomaly.probe("grad_norm", gnorm)
         lr = batch.get("lr")
         if "lr_scale" in batch:
             # rollback LR rescale: reproduce the LR apply_gradients
@@ -577,16 +580,22 @@ class TrainStep:
             batch["lr_scale"] = jnp.float32(self.lr_scale)
         return batch
 
+    # Host spans of the whole entry point (docs/observability.md): on a
+    # profile's clock through TraceAnnotation, so a device-idle gap is
+    # charged to batch building, to the dispatch (host batch transfer
+    # included) or to the drain, which reads device buffers. One early
+    # return each while metrics are off.
+
     def __call__(self, *args, labels=(), **kwargs):
-        batch = self._make_batch(args, labels, kwargs)
+        with _obs.span("pt/train_step/make_batch"):
+            batch = self._make_batch(args, labels, kwargs)
+        with _obs.span("pt/train_step/dispatch", fn=self._span_name):
+            self.state, metrics = self._jitted(self.state, batch)
         if _obs.enabled():
-            with _obs.span(self._span_name):
-                self.state, metrics = self._jitted(self.state, batch)
             _obs.counter("optimizer_steps_total",
                          "optimizer update steps applied").inc()
-        else:
-            self.state, metrics = self._jitted(self.state, batch)
-        return self._drain_signals(metrics)
+        with _obs.span("pt/train_step/drain"):
+            return self._drain_signals(metrics)
 
     def run_steps(self, *args, labels=(), **kwargs):
         """Run K fused optimizer steps in one dispatch: every leaf of
@@ -596,22 +605,22 @@ class TrainStep:
         scheduler's live value is held constant across the K steps of
         one dispatch (scheduler granularity becomes K steps)."""
         from ..parallel.spmd import host_lr_of
-        batch = {"args": args, "labels": as_label_tuple(labels),
-                 "kwargs": kwargs}
-        lr = host_lr_of(self.optimizer)
-        lr = None if lr is None else jnp.float32(lr)
+        with _obs.span("pt/train_step/make_batch"):
+            batch = {"args": args, "labels": as_label_tuple(labels),
+                     "kwargs": kwargs}
+            lr = host_lr_of(self.optimizer)
+            lr = None if lr is None else jnp.float32(lr)
+        with _obs.span("pt/train_step/dispatch",
+                       fn=self._span_name + ".multi"):
+            self.state, metrics = self._jitted_multi(self.state, batch,
+                                                     lr)
         if _obs.enabled():
-            with _obs.span(self._span_name + ".multi"):
-                self.state, metrics = self._jitted_multi(self.state,
-                                                         batch, lr)
             k = next((int(a.shape[0]) for a in jax.tree.leaves(batch)
                       if getattr(a, "ndim", 0)), 1)
             _obs.counter("optimizer_steps_total",
                          "optimizer update steps applied").inc(k)
-        else:
-            self.state, metrics = self._jitted_multi(self.state, batch,
-                                                     lr)
-        return self._drain_signals(metrics)
+        with _obs.span("pt/train_step/drain"):
+            return self._drain_signals(metrics)
 
     # -- persistent-cache probe drain ------------------------------------
     # With FLAGS_compile_cache_dir set the step's anomaly/skip-guard

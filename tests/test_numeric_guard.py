@@ -35,6 +35,17 @@ def _clean_faults():
     faults.configure(None)
 
 
+@pytest.fixture(autouse=True)
+def _restore_metrics_port():
+    """Three tests here set ``metrics_port=-1`` (no exporter). Left
+    behind, it made ``tests/test_fleet_federation.py::
+    test_maybe_start_reporter_from_env`` fail whenever xdist put both
+    files on one worker."""
+    was = pt.get_flags(["metrics_port"])["metrics_port"]
+    yield
+    pt.set_flags({"metrics_port": was})
+
+
 def _data(n=16, poison=False):
     rng = np.random.default_rng(0)
     x = rng.normal(0, 1, (n, 4)).astype(np.float32)

@@ -436,8 +436,12 @@ def test_trace_report_on_fit_output(metrics_on, tmp_path, capsys):
         sys.path.pop(0)
     out = capsys.readouterr().out
     assert rc == 0
-    assert "TrainStep(_MLP)" in out
+    assert "TrainStep(_MLP)" in out           # the recompile report
     assert "merged span summary" in out
+    # the entry point's three host spans (they replaced the one span
+    # named after the step)
+    for phase in ("make_batch", "dispatch", "drain"):
+        assert f"pt/train_step/{phase}" in out
     assert "hapi_step_time_seconds" in out
 
 
