@@ -21,6 +21,7 @@ import threading
 from typing import Dict, Iterator, Optional
 
 import jax
+import jax.numpy as jnp
 
 _fast_rng_configured = False
 _fast_rng_lock = threading.Lock()
@@ -30,10 +31,14 @@ def _configure_fast_rng_once() -> None:
     """Switch to the hardware RngBitGenerator PRNG on TPU (FLAGS_use_fast_rng).
 
     Must run before the FIRST jax.random key is created anywhere in the
-    package — threefry dropout-mask generation costs ~35% of a BERT-base
-    train step on v5e. Called lazily from Generator key creation so that
-    ``import paddle_tpu`` never initializes the PJRT backend (a slow or
-    contended accelerator plugin would hang the import otherwise).
+    package: mixing PRNG impls in one process breaks stream
+    reproducibility. The generator serves initialisation and small
+    draws; dropout masks draw no words from it (they hash a seed folded
+    from the key: ``ops.nn_functional.dropout_keep_mask``), so what it
+    is worth to a train step has not been measured in this tree. Called
+    lazily from Generator key creation so that ``import paddle_tpu``
+    never initializes the PJRT backend (a slow or contended accelerator
+    plugin would hang the import otherwise).
     """
     global _fast_rng_configured
     with _fast_rng_lock:
@@ -45,6 +50,27 @@ def _configure_fast_rng_once() -> None:
                 and jax.default_backend() == "tpu":
             jax.config.update("jax_default_prng_impl", "rbg")
         _fast_rng_configured = True
+
+
+def mix32(x):
+    """The two multiply-xorshift rounds of the murmur3 finalizer
+    (uint32 in/out): every input bit reaches the high bits of the
+    result, which is what a threshold reads. Plain jnp, so it runs
+    inside a Pallas kernel too."""
+    x = x ^ (x >> jnp.uint32(16))
+    x = x * jnp.uint32(0x85EBCA6B)
+    x = x ^ (x >> jnp.uint32(13))
+    x = x * jnp.uint32(0xC2B2AE35)
+    return x
+
+
+def fmix32(x):
+    """murmur3 finalizer: ``mix32`` and a last fold of the high half
+    into the low one, a full-avalanche 32-bit mix. The one mixing
+    function of every counter-hash keep-mask: the flash kernels'
+    attention dropout and ``ops.nn_functional``'s element-wise one."""
+    x = mix32(x)
+    return x ^ (x >> jnp.uint32(16))
 
 
 def make_key(seed) -> jax.Array:

@@ -288,10 +288,12 @@ define_flag("batch_norm_single_pass", True,
             "cancellation; BN inputs are ~unit-scale.")
 define_flag("use_fast_rng", True,
             "On TPU, use the hardware RngBitGenerator PRNG ('rbg') for "
-            "jax.random keys instead of threefry. [assumed] The ~1.5x "
-            "dropout-heavy speedup is the public TPU-known result, not "
-            "a measurement from this repo; streams are still "
-            "splittable/foldable but not bit-identical to threefry.")
+            "jax.random keys instead of threefry: initialisation and "
+            "small draws. Dropout masks draw nothing from it (they hash "
+            "a seed folded from the key where the mask is used), so its "
+            "worth to a train step is [assumed], not measured in this "
+            "tree; streams are still splittable/foldable but not "
+            "bit-identical to threefry.")
 define_flag("profile_dir", "",
             "If set, write xplane profiler traces under this directory.")
 define_flag("log_level", 0, "Framework VLOG level (0 = off).")

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.random import fmix32 as _fmix32
 from ..observability.xprof import note_kernel
 
 # 512 tiles measured fastest on chip (r5 d64 train sweep, v5e:
@@ -118,16 +119,6 @@ def bthd_supported(d: int, h: int) -> bool:
 # identical (interpret-mode tests + the compiled verify stage cover it).
 _GRID_PARALLEL = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel"))
-
-
-def _fmix32(x):
-    """murmur3 finalizer: full-avalanche 32-bit mix (uint32 in/out)."""
-    x = x ^ (x >> jnp.uint32(16))
-    x = x * jnp.uint32(0x85EBCA6B)
-    x = x ^ (x >> jnp.uint32(13))
-    x = x * jnp.uint32(0xC2B2AE35)
-    x = x ^ (x >> jnp.uint32(16))
-    return x
 
 
 def _dropout_keep(seed, g, q_pos, k_pos, dropout_p: float):

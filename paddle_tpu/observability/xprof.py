@@ -26,13 +26,16 @@ or unsupported produce a card with an explicit ``unavailable`` marker
 instead of an error (the CPU fallback contract tested in
 tests/test_observability.py).
 
-Two more things only the traced program knows are kept here while
-metrics are on, both at trace time and nothing per step:
+Three more things only the traced program knows are kept here while
+metrics are on, all at trace time and nothing per step:
 
 - ``note_kernel``: each Pallas call site notes ``(name, flops,
   bytes)`` — the work one call must do, from its shapes — under the jit
   entry point being traced (``kernel_notes(fn)``), so a kernel's device
   time from a profile can be set against a peak;
+- ``note_dropout_mask``: each dropout call site notes the elements its
+  hashed keep-mask covers, published per entry point as the gauges
+  ``pt_dropout_mask_sites`` / ``pt_dropout_mask_elements``;
 - ``op_scopes(fn)``: on demand, the compiled program's
   ``{instruction name: op_name}``. A device profile names an operation
   by its HLO instruction; the ``jax.named_scope`` it was traced under
@@ -51,8 +54,8 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 from . import metrics as _metrics
 
 __all__ = ["ProgramCardRegistry", "cards", "enabled", "harvest",
-           "flops_of", "note_kernel", "kernel_notes", "op_scopes",
-           "parse_op_names"]
+           "flops_of", "note_kernel", "note_dropout_mask", "kernel_notes",
+           "op_scopes", "parse_op_names"]
 
 # Cost-analysis keys promoted onto the card top level when present.
 _COST_KEYS = ("flops", "transcendentals", "bytes accessed")
@@ -219,21 +222,33 @@ _KERNEL_NOTES: Dict[str, List[KernelNote]] = {}
 @contextlib.contextmanager
 def tracing(fn_name: str) -> Iterator[None]:
     """Entered by the recompile tracker round the body of a jit entry
-    point, which runs only while jax traces it: kernels traced inside
-    note their work under ``fn_name``. The newest trace replaces the
-    notes of the one before (a retrace, or ``op_scopes`` lowering the
-    entry point again, must not count a call site twice)."""
-    outer = getattr(_TLS, "notes", None)
+    point, which runs only while jax traces it: kernels and dropout
+    sites traced inside note their work under ``fn_name``. The newest
+    trace replaces what the one before noted (a retrace, or
+    ``op_scopes`` lowering the entry point again, must not count a call
+    site twice)."""
+    outer = getattr(_TLS, "notes", None), getattr(_TLS, "masked", None)
     _TLS.notes = notes = []
+    _TLS.masked = masked = []
     try:
         yield
     finally:
-        _TLS.notes = outer
-        if outer is not None:       # an entry point traced inside another
-            outer.extend(notes)
+        _TLS.notes, _TLS.masked = outer
+        if outer[0] is not None:    # an entry point traced inside another
+            outer[0].extend(notes)
+            outer[1].extend(masked)
         if _metrics.enabled():
             with _NOTES_LOCK:
                 _KERNEL_NOTES[fn_name] = notes
+            _metrics.gauge(
+                "pt_dropout_mask_sites",
+                "dropout call sites whose keep-mask is a counter hash, "
+                "in the newest trace of the entry point").set(
+                    len(masked), fn=fn_name)
+            _metrics.gauge(
+                "pt_dropout_mask_elements",
+                "elements those sites mask in one call of the entry "
+                "point").set(sum(masked), fn=fn_name)
 
 
 def note_kernel(name: str, flops: float, bytes_: float) -> None:
@@ -243,6 +258,15 @@ def note_kernel(name: str, flops: float, bytes_: float) -> None:
     notes = getattr(_TLS, "notes", None)
     if notes is not None and _metrics.enabled():
         notes.append((name, float(flops), float(bytes_)))
+
+
+def note_dropout_mask(elements: int) -> None:
+    """Called once per traced dropout call site with the elements it
+    masks. A no-op unless metrics are on and a tracked entry point is
+    being traced."""
+    masked = getattr(_TLS, "masked", None)
+    if masked is not None and _metrics.enabled():
+        masked.append(int(elements))
 
 
 def kernel_notes(fn_name: str) -> List[KernelNote]:

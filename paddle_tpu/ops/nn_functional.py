@@ -16,6 +16,8 @@ mutating buffers — the Layer wrappers thread stats through step state.
 from __future__ import annotations
 
 import builtins
+import functools
+from math import prod as _prod
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
@@ -23,7 +25,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core import random as _random
+from ..core.random import fmix32 as _fmix32, mix32 as _mix32
 from ..flags import GLOBAL_FLAGS
+from ..observability import xprof as _xprof
 
 IntOrPair = Union[int, Sequence[int]]
 
@@ -541,14 +545,103 @@ def spectral_norm(weight, u, v, power_iters: int = 1, epsilon: float = 1e-12,
 # dropout & friends (ref: dropout_op.cc)
 # ---------------------------------------------------------------------------
 
-def dropout_keep_mask(key, keep_prob: float, shape):
-    """Bernoulli(keep_prob) mask via an integer threshold on raw PRNG
-    bits — skips the bits→float-uniform conversion jax.random.bernoulli
-    does, which on big masks (attention probs are [B,H,T,T]) is pure
-    memory traffic."""
-    bits = jax.random.bits(key, shape, dtype=jnp.uint32)
+def _mask_seed(key):
+    """One ``uint32`` from a PRNG key: a fold of the key's own words
+    (two under threefry, four under rbg), so no random word is drawn.
+    Keys from ``rng_scope`` streams are already hashes of (step key,
+    call-site counter); an explicit ``jax.random.key(n)`` differs from
+    ``key(m)`` in one word, which the rounds spread over all 32 bits."""
+    words = jax.random.key_data(key).astype(jnp.uint32).reshape(-1)
+    h = jnp.uint32(0x9E3779B9)
+    for i in range(words.shape[0]):
+        h = _fmix32(h ^ words[i])
+    return h
+
+
+def _position(shape, lo: int, hi: int, start=None):
+    """``start`` + the row-major position within dims ``lo:hi``, as
+    ``uint32`` of the whole ``shape``: iotas over the GLOBAL shape, so a
+    mesh that splits the array splits the iotas with it. ``start`` rides
+    the slowest iota's term, which XLA keeps as a short vector: adding
+    it there costs nothing an element."""
+    terms, stride = [], 1
+    for d in range(hi - 1, lo - 1, -1):
+        if shape[d] > 1:
+            term = lax.broadcasted_iota(jnp.uint32, shape, d)
+            terms.append(term * jnp.uint32(stride) if stride > 1 else term)
+        stride *= shape[d]
+    if start is not None:       # on the slowest term, or alone
+        terms.append(terms.pop() + start if terms
+                     else jnp.full(shape, start, jnp.uint32))
+    if not terms:
+        return jnp.zeros(shape, jnp.uint32)
+    return functools.reduce(jnp.add, reversed(terms))
+
+
+def _hash_keep(seed, keep_prob: float, shape):
+    """Bernoulli(keep_prob) over ``shape`` as a pure function of
+    (``seed``, global position): a threshold on 32 hashed bits, by
+    ``mix32`` (a threshold reads the high bits, which the two rounds
+    have mixed: ``fmix32``'s last fold would buy nothing)."""
+    shape = tuple(int(d) for d in shape)
     thresh = jnp.uint32(min(int(keep_prob * (2.0 ** 32)), 2 ** 32 - 1))
-    return bits < thresh
+    # the scope's name must not start with "pt.": a block metric charges
+    # an operation to the last pt. token of its op_name
+    with jax.named_scope("dropout_mask"):
+        split, tail = len(shape), 1
+        while split and tail * shape[split - 1] <= 2 ** 32:
+            split -= 1
+            tail *= shape[split]
+        if split == 0:
+            # under 2^32 elements every position is its own counter:
+            # one full mix each
+            bits = _mix32(_position(shape, 0, len(shape), seed))
+        else:
+            # two coordinates, each through rounds of its own, as
+            # kernels/flash_attention._dropout_keep: one linear counter
+            # would alias positions 2^32 apart
+            if _prod(shape[:split]) > 2 ** 32:
+                raise ValueError(f"dropout mask of shape {shape}: no "
+                                 "split into two 32-bit coordinates")
+            bits = _mix32(_fmix32(_position(shape, 0, split, seed))
+                          ^ (_position(shape, split, len(shape))
+                             * jnp.uint32(0x9E3779B9)))
+        return bits < thresh
+
+
+def dropout_keep_mask(key, keep_prob: float, shape):
+    """Bernoulli(keep_prob) mask of ``shape``: a counter hash of (a
+    32-bit seed folded from ``key``, the element's global position)
+    against the threshold ``floor(keep_prob * 2^32)``; every element
+    has 32 bits of its own. It is integer arithmetic on iotas, which
+    XLA fuses into whatever applies the mask: no random word is written
+    to memory (a ``jax.random.bits`` draw of ``shape`` is a word per
+    element written and read back). The same key gives the same mask,
+    eagerly, under ``jit``, and under any mesh: positions are global,
+    so GSPMD partitions the iotas and ``ShardedTrainStep`` draws the
+    mask ``TrainStep`` draws."""
+    _xprof.note_dropout_mask(_prod(shape))
+    return _hash_keep(_mask_seed(key), keep_prob, shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _dropout_apply(x, seed, keep_prob: float, upscale: bool):
+    keep = _hash_keep(seed, keep_prob, x.shape)
+    return jnp.where(keep, x / keep_prob if upscale else x,
+                     0.0).astype(x.dtype)
+
+
+def _dropout_apply_fwd(x, seed, keep_prob, upscale):
+    # the seed is the only residual: the backward hashes the mask
+    # again, as the flash kernels do, instead of keeping it
+    return _dropout_apply(x, seed, keep_prob, upscale), seed
+
+
+def _dropout_apply_bwd(keep_prob, upscale, seed, g):
+    return _dropout_apply(g, seed, keep_prob, upscale), None
+
+
+_dropout_apply.defvjp(_dropout_apply_fwd, _dropout_apply_bwd)
 
 
 def dropout(x, p: float = 0.5, training: bool = True,
@@ -559,10 +652,9 @@ def dropout(x, p: float = 0.5, training: bool = True,
         return x
     if key is None:
         key = _random.next_key("dropout")
-    keep = dropout_keep_mask(key, 1.0 - p, x.shape)
-    if mode == "upscale_in_train":
-        return jnp.where(keep, x / (1.0 - p), 0.0).astype(x.dtype)
-    return jnp.where(keep, x, 0.0).astype(x.dtype)
+    _xprof.note_dropout_mask(_prod(x.shape))
+    return _dropout_apply(x, _mask_seed(key), 1.0 - p,
+                          mode == "upscale_in_train")
 
 
 def dropout2d(x, p: float = 0.5, training: bool = True, key=None):

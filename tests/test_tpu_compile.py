@@ -128,3 +128,59 @@ def test_fused_linear_softmax_xent_fwd_bwd_compiles(chip):
                     ((30522, 768), jnp.bfloat16),
                     ((30522,), jnp.float32), ((8192,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def test_encoder_layer_step_holds_no_random_words(chip, monkeypatch):
+    """One BertEncoderLayer forward and backward at bert_base_s512's
+    shape (b32 x s512, three dropouts at 0.1), under the generator the
+    chip runs (``rbg``, core/random.py): the keep-masks are hashed
+    inside the fusions that use them, so the only random bits the
+    compiled program draws are the flash kernel's one seed, and it
+    writes no word per element."""
+    import math
+    import re
+
+    from paddle_tpu import kernels
+    from paddle_tpu.core import random as _random
+    from paddle_tpu.models.bert import BertConfig, BertEncoderLayer
+    from paddle_tpu.nn.layer import functional_call
+    monkeypatch.setattr(kernels, "_on_tpu", lambda: True)
+    layer = BertEncoderLayer(BertConfig())
+    layer.to(dtype="bfloat16")
+    layer.train()
+    params, buffers = layer.param_dict(), layer.buffer_dict()
+
+    def loss(p, x, key):
+        with _random.rng_scope(default=key, dropout=key):
+            y = functional_call(layer, p, buffers, x)
+        return jnp.sum(y.astype(jnp.float32))
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+
+    was = jax.config.jax_default_prng_impl
+    jax.config.update("jax_default_prng_impl", "rbg")
+    try:
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            jax.tree.map(on_chip, params),
+            on_chip(jax.ShapeDtypeStruct((32, 512, 768), jnp.bfloat16)),
+            on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+        ).compile().as_text()
+    finally:
+        jax.config.update("jax_default_prng_impl", was)
+    assert "flash_fwd" in text and "dropout_mask" in text
+    drawn = re.findall(r"= (.*?) rng-bit-generator\(", text)
+    assert len(drawn) == 1, drawn       # attention dropout's seed
+    for dims in re.findall(r"\[([\d,]*)\]", drawn[0]):
+        assert math.prod(int(d) for d in dims.split(",") if d) <= 4, drawn
+    # what the program writes to memory are the results of the entry
+    # computation's instructions (a fusion's inner values live in
+    # registers): none is a word per activation element
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    results = re.findall(r"^\s*(?:ROOT )?\S+ = (.*?) [\w\-]+\(", entry,
+                         flags=re.M)
+    assert len(results) > 50
+    wide = [r for r in results
+            if re.search(r"[us]32\[32,512,(3072|768)\]", r)]
+    assert not wide, wide
