@@ -121,6 +121,29 @@ _GRID_PARALLEL = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel"))
 
 
+def _grid_params(*resident_bytes: int):
+    """Every program keeps whole sequences resident in VMEM (the key
+    and value, or the query and dO, of its head; double-buffered), and
+    the compiler's scoped limit is 16 MiB of the chip's 128: at 8192
+    positions of a 128-wide head the dk/dv kernel needs 25. Past a
+    quarter of the limit the call asks for what its resident operands
+    take plus room for a block's working set; below it (every shape
+    the kernels ran at before) the parameters are the shared default
+    and the compiled program is what it was."""
+    resident = 2 * sum(resident_bytes)
+    if resident <= 4 * 2 ** 20:
+        return _GRID_PARALLEL
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel"),
+        vmem_limit_bytes=min(resident + 24 * 2 ** 20, 100 * 2 ** 20))
+
+
+def _row_bytes(rows: int) -> int:
+    """A [rows, heads-per-block] float32 column in VMEM: the minor
+    dimension is padded to 128 lanes."""
+    return rows * 128 * 4
+
+
 def _dropout_keep(seed, g, q_pos, k_pos, dropout_p: float):
     """Counter-based keep mask: bits are a pure hash of (seed, head,
     global q/k position), so the SAME mask regenerates bitwise in the
@@ -347,7 +370,8 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
             jax.ShapeDtypeStruct((b * hg, tq_p, hpb), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_GRID_PARALLEL,
+        compiler_params=_grid_params(
+            *[tk_p * hpb * d * k.dtype.itemsize] * 2),
         name="flash_fwd",
     )(qr, kr, vr, _seed_arr(seed), _bias_arr(kv_bias, b, tk, tk_p))
     note_kernel("flash_fwd", *flash_fwd_work(
@@ -750,6 +774,9 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
         return (dq[:, :tq].reshape(b, h, tq, d),
                 dk[:, :tk].reshape(b, h, tk, d),
                 dv[:, :tk].reshape(b, h, tk, d))
+    # one program's resident whole-sequence operands, for _grid_params
+    kv_bytes = tk_p * hpb * d * k.dtype.itemsize
+    q_bytes = tq_p * hpb * d * q.dtype.itemsize
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_k=bk, seq_k=tk, seq_q=tq,
@@ -771,7 +798,7 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
         out_specs=seq_spec(bq, q_map),
         out_shape=dq_struct,
         interpret=interpret,
-        compiler_params=_GRID_PARALLEL,
+        compiler_params=_grid_params(kv_bytes, kv_bytes),
         name="flash_bwd_dq",
     )(qr, kr, vr, dor, lse_r, delta, seed_a, bias_a)
     note_kernel("flash_bwd_dq", *flash_bwd_work(*shape, causal,
@@ -805,7 +832,8 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
         ],
         out_shape=[dk_struct, dv_struct],
         interpret=interpret,
-        compiler_params=_GRID_PARALLEL,
+        compiler_params=_grid_params(q_bytes, q_bytes, _row_bytes(tq_p),
+                                     _row_bytes(tq_p)),
         name="flash_bwd_dkv",
     )(qr, kr, vr, dor, lse_r, delta, seed_a, bias_a)
     note_kernel("flash_bwd_dkv", *flash_bwd_work(*shape, causal,

@@ -21,3 +21,6 @@ from .transformer_xl import (TransformerXL, TransformerXLConfig,  # noqa
                              TransformerXLTrainStep)
 from .ernie import (ErnieConfig, ErnieForPretraining, ErnieModel,  # noqa
                     knowledge_mask)
+from .nemotron_h import (CausalLMOutput, NemotronHConfig,  # noqa: F401
+                         NemotronHForCausalLM, balance_router_bias,
+                         next_token_loss, routing_metrics)

@@ -22,13 +22,15 @@ from .layers.conv import (AdaptiveAvgPool2D, AdaptiveMaxPool2D, AvgPool2D,
 from .layers.norm import (BatchNorm, BatchNorm1D, BatchNorm2D, BatchNorm3D,
                           GroupNorm, InstanceNorm1D, InstanceNorm2D,
                           InstanceNorm3D, LayerNorm, LocalResponseNorm,
-                          SpectralNorm, SyncBatchNorm)
+                          RMSNorm, SpectralNorm, SyncBatchNorm)
 from .layers.loss import (BCELoss, BCEWithLogitsLoss, CTCLoss,
                           CosineEmbeddingLoss, CrossEntropyLoss,
                           FusedLinearCrossEntropy, KLDivLoss,
                           L1Loss, MSELoss, MarginRankingLoss, NLLLoss,
                           SmoothL1Loss, TripletMarginLoss)
-from .layers.moe import MoELayer, moe_param_rule  # noqa: F401
+from .layers.moe import (DroplessMoE, MoELayer,  # noqa: F401
+                         moe_param_rule)
+from .layers.ssm import Mamba2Mixer  # noqa: F401
 from .decode import (BasicDecoder, BeamSearchDecoder,  # noqa: F401
                      DecodeHelper, Decoder, dynamic_decode,
                      GreedyEmbeddingHelper, SampleEmbeddingHelper,
@@ -36,7 +38,8 @@ from .decode import (BasicDecoder, BeamSearchDecoder,  # noqa: F401
 from .layers.rnn import (GRU, GRUCell, LSTM, LSTMCell, RNN, RNNCell,  # noqa
                          SimpleRNN,
                          SimpleRNNCell)
-from .layers.transformer import (MultiHeadAttention, Transformer,
+from .layers.transformer import (GroupedQueryAttention,
+                                 MultiHeadAttention, Transformer,
                                  TransformerDecoder,
                                  TransformerDecoderLayer,
                                  TransformerEncoder,

@@ -9,6 +9,16 @@ the mesh's "ep" axis, tokens are dispatched densely with a capacity
 limit (one-hot einsum — static shapes, MXU-friendly), and XLA inserts
 the all-to-alls from the sharding annotations (the same mechanism the
 reference's NCCL graph passes hand-build).
+
+Two layers live here. :class:`MoELayer` is that GShard layer: softmax
+gate, ``capacity_factor``, overflow tokens dropped, every expert held;
+it stays for what it alone does, a layer whose experts GSPMD spreads
+over an ``ep`` mesh axis from sharding annotations
+(:func:`moe_param_rule`), where static capacity is what lets XLA insert
+the exchange. :class:`DroplessMoE` is the layer of today's sparse
+language models and of one expert-parallel rank: a sigmoid router with a
+selection-only bias, no capacity and no dropped pair, a shared expert,
+and a sort by expert with a grouped matmul over the experts held here.
 """
 
 from __future__ import annotations
@@ -23,7 +33,7 @@ from ...core.dtype import get_default_dtype
 from .. import initializer as I
 from ..layer import Layer, Parameter
 
-__all__ = ["MoELayer", "moe_param_rule"]
+__all__ = ["MoELayer", "DroplessMoE", "moe_param_rule"]
 
 
 class MoELayer(Layer):
@@ -111,6 +121,207 @@ class MoELayer(Layer):
         mean_prob = probs.mean(axis=0)
         self.aux_loss = e * jnp.sum(frac_tokens * mean_prob)
         return y.reshape(b, t, d)
+
+
+# The routed part runs on (token, choice) pairs sorted by expert. Every
+# pair may fall on the experts held here, so the sorted list is tokens x
+# top_k long; a rank that holds held/total of the experts sees that
+# share of it when the router is balanced. The list is therefore walked
+# in windows of WINDOW_FACTOR times the balanced load. A window that
+# holds a held pair is computed whole (the rows past the last pair are
+# zeros in the last group), and one that starts past the last held pair
+# is skipped (a lax.cond on the step's own count): a step whose held
+# pairs fit one window takes the same time whatever the routing, the
+# worst routing runs every window, no pair is dropped either way, and
+# the buffers are one window's.
+WINDOW_FACTOR = 2
+_ROW_TILE = 512
+
+
+class DroplessMoE(Layer):
+    """Sigmoid-routed experts with a shared expert, for a layer that
+    holds ``experts_held`` of ``num_experts`` experts starting at
+    ``expert_offset`` (all of them by default).
+
+    ``s = sigmoid(W_r x)`` in float32; the ``top_k`` largest of
+    ``s + e_score_correction_bias`` choose (the bias is a buffer and
+    only selects); ``w = scale * s[chosen] / (sum s[chosen] + 1e-20)``
+    (the division only with ``norm_topk_prob``); ``out = sum_e w_e W2_e
+    relu(W1_e x)^2 + Ws2 relu(Ws1 x)^2``. The router scores every
+    expert; pairs that fall on experts held elsewhere add nothing here
+    (on an expert-parallel rank their part arrives by the exchange,
+    which this layer does not contain). The held pairs are sorted by
+    expert and run, a window of the sorted list at a time, through one
+    pair of grouped matmuls (``jax.lax.ragged_dot`` with the true group
+    sizes, a window's empty rows zeros in its last group): no capacity,
+    no dropped pair, whatever the routing. A window is twice the
+    balanced load and is computed whole, so a balanced layer multiplies
+    as many rows of zeros as rows of pairs: a step's time follows the
+    number of windows that hold a pair, not the pairs (PERF.md section
+    6, PR 29, has the price and why it is paid).
+
+    Returns ``(out, stats)``; ``stats`` holds the scalars
+    ``pairs_held`` (pairs on held experts in this call),
+    ``load_max_over_mean`` (the fullest held expert over their mean)
+    and ``pairs_dropped`` (held pairs no window covers: 0), and
+    ``expert_load`` [num_experts], the pairs this call's tokens sent to
+    each expert the router scores: what the aux-loss-free balancing
+    rule reads to move ``e_score_correction_bias`` (the layer itself
+    never writes the buffer)."""
+
+    def __init__(self, d_model: int, d_expert: int, num_experts: int,
+                 top_k: int, d_shared: int = 0,
+                 experts_held: Optional[int] = None,
+                 expert_offset: int = 0,
+                 routed_scaling_factor: float = 1.0,
+                 norm_topk_prob: bool = True, weight_attr=None,
+                 out_weight_attr=None) -> None:
+        super().__init__()
+        held = num_experts if experts_held is None else experts_held
+        if not (0 <= expert_offset and expert_offset + held <= num_experts
+                and 0 < top_k <= num_experts):
+            raise ValueError(
+                f"experts [{expert_offset}, {expert_offset + held}) of "
+                f"{num_experts}, top_k {top_k}")
+        self.num_experts, self.top_k = num_experts, top_k
+        self.experts_held, self.expert_offset = held, expert_offset
+        self.routed_scaling_factor = routed_scaling_factor
+        self.norm_topk_prob = norm_topk_prob
+        dtype = get_default_dtype()
+        self.router_weight = I.make_param(
+            weight_attr, I.XavierUniform(), (d_model, num_experts), dtype)
+        self.register_buffer("e_score_correction_bias",
+                             jnp.zeros((num_experts,), jnp.float32))
+        self.w_in = I.make_param(weight_attr, I.XavierUniform(),
+                                 (held, d_model, d_expert), dtype)
+        self.w_out = I.make_param(out_weight_attr, I.XavierUniform(),
+                                  (held, d_expert, d_model), dtype)
+        self.has_shared = d_shared > 0
+        if self.has_shared:
+            from .common import Linear
+            self.shared_in = Linear(d_model, d_shared, weight_attr,
+                                    bias_attr=False)
+            self.shared_out = Linear(d_shared, d_model, out_weight_attr,
+                                     bias_attr=False)
+
+    def route(self, tokens):
+        """(chosen experts [N, k] int32, their weights [N, k] float32)."""
+        scores = jax.nn.sigmoid(jnp.matmul(
+            tokens.astype(jnp.float32),
+            self.router_weight.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, chosen = jax.lax.top_k(
+            scores + self.e_score_correction_bias, self.top_k)
+        w = jnp.take_along_axis(scores, chosen, axis=-1)
+        if self.norm_topk_prob:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return chosen, w * self.routed_scaling_factor
+
+    def _window(self, tokens, weights, w_in, w_out, order, ends, lo,
+                rows: int):
+        """What the held pairs ``order[lo:lo + rows]`` add, [N, D]
+        float32. ``ends`` are the cumulative group sizes."""
+        with jax.named_scope("pt.moe_route"):
+            pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
+            token_of = pair // self.top_k
+            inside = jnp.clip(ends, lo, lo + rows) - lo
+            live = jnp.arange(rows) < inside[-1]
+            # the rows past the last held pair are zeros and join the
+            # last group: a window is computed whole whatever share of
+            # it holds pairs, so its time does not follow the routing
+            sizes = jnp.diff(inside, prepend=0).at[-1].add(
+                rows - inside[-1])
+            rows_in = tokens[token_of]
+            w = jnp.where(live, weights.reshape(-1)[pair], 0.0)
+        # XLA:TPU renames the grouped kernels, and a profile's reader
+        # charges them to the scope of what uses (or feeds) them: the
+        # element-wise work next to each product is under this scope
+        with jax.named_scope("pt.moe_experts"):
+            hidden = jnp.square(jax.nn.relu(jax.lax.ragged_dot(
+                jnp.where(live[:, None], rows_in, 0), w_in, sizes)))
+            out = (jax.lax.ragged_dot(hidden, w_out, sizes)
+                   * w[:, None].astype(hidden.dtype)).astype(jnp.float32)
+        with jax.named_scope("pt.moe_route"):
+            return jnp.zeros(tokens.shape, jnp.float32).at[token_of].add(
+                out)
+
+    def _routed(self, tokens, weights, order, ends, rows: int,
+                windows: int):
+        """The held experts' part, [N, D] float32: the sum of the
+        windows that hold a held pair. Differentiated by hand, a window
+        at a time: left to reverse-mode AD, the scan over windows keeps
+        a [N, D] residual for every window, run or not."""
+        def scan_windows(run, init, order, ends):
+            starts = jnp.arange(windows, dtype=jnp.int32) * rows
+
+            @jax.named_scope("pt.moe_route")    # the body's own stack
+            def step(acc, lo):
+                add = jax.lax.cond(lo < ends[-1], run,
+                                   lambda lo: init, lo)
+                return jax.tree.map(jnp.add, acc, add), None
+            return jax.lax.scan(step, init, starts)[0]
+
+        @jax.custom_vjp
+        @jax.named_scope("pt.moe_route")    # the accumulators' zeros
+        def routed(diff, order, ends):
+            return scan_windows(
+                lambda lo: self._window(*diff, order, ends, lo, rows),
+                jnp.zeros(tokens.shape, jnp.float32), order, ends)
+
+        def forward(diff, order, ends):
+            return routed(diff, order, ends), (diff, order, ends)
+
+        @jax.named_scope("pt.moe_route")
+        def backward(saved, g):
+            diff, order, ends = saved
+
+            def run(lo):
+                _, pull = jax.vjp(lambda *d: self._window(
+                    *d, order, ends, lo, rows), *diff)
+                return pull(g)
+
+            grads = scan_windows(run, jax.tree.map(jnp.zeros_like, diff),
+                                 order, ends)
+            return grads, None, None
+
+        routed.defvjp(forward, backward)
+        return routed((tokens, weights, self.w_in, self.w_out), order,
+                      ends)
+
+    def forward(self, x):
+        tokens = x.reshape(-1, x.shape[-1])
+        n, held = tokens.shape[0], self.experts_held
+        total = n * self.top_k
+        rows = min(total, -(-WINDOW_FACTOR * total * held
+                            // (self.num_experts * _ROW_TILE)) * _ROW_TILE)
+        windows = -(-total // rows)
+        with jax.named_scope("pt.moe_route"):
+            chosen, weights = self.route(tokens)
+            local = chosen - self.expert_offset
+            # absent experts sort last, into a group no matmul visits
+            key = jnp.where((local >= 0) & (local < held), local,
+                            held).reshape(-1)
+            order = jnp.pad(jnp.argsort(key), (0, windows * rows - total))
+            load = jnp.bincount(chosen.reshape(-1),
+                                length=self.num_experts).astype(jnp.int32)
+            held_load = load[self.expert_offset:self.expert_offset + held]
+            ends = jnp.cumsum(held_load)
+            pairs_held = ends[-1]
+        routed = self._routed(tokens, weights, order, ends, rows, windows)
+        out = routed.astype(x.dtype)
+        if self.has_shared:
+            with jax.named_scope("pt.moe_shared"):
+                out = out + self.shared_out(jnp.square(jax.nn.relu(
+                    self.shared_in(tokens))))
+        stats = {
+            "pairs_held": pairs_held,
+            "load_max_over_mean": jnp.max(held_load) * held / jnp.maximum(
+                pairs_held, 1).astype(jnp.float32),
+            # every window that holds a held pair runs
+            "pairs_dropped": jnp.maximum(pairs_held - windows * rows, 0),
+            "expert_load": load,
+        }
+        return out.reshape(x.shape), stats
 
 
 def moe_param_rule(ep_axis: str = "ep"):

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from ...core.dtype import get_default_dtype
@@ -130,6 +131,35 @@ class LayerNorm(Layer):
         begin = x.ndim - len(self.normalized_shape)
         from ...kernels import maybe_layer_norm
         return maybe_layer_norm(x, w, b, self.epsilon, begin)
+
+
+class RMSNorm(Layer):
+    """``x / sqrt(mean(x^2) + epsilon) * weight`` over the last axis
+    (no mean subtraction, no bias), computed in float32 and returned in
+    the input's dtype. With ``num_groups`` the mean is taken inside
+    each of that many equal groups of the last axis (the gated norm of
+    a Mamba-2 mixer); the weight is one vector over the whole axis."""
+
+    def __init__(self, hidden_size: int, epsilon: float = 1e-5,
+                 num_groups: int = 1, weight_attr=None) -> None:
+        super().__init__()
+        if hidden_size % num_groups:
+            raise ValueError(f"{num_groups} groups do not divide "
+                             f"{hidden_size}")
+        self.hidden_size = hidden_size
+        self.epsilon = epsilon
+        self.num_groups = num_groups
+        self.weight = I.make_param(weight_attr, I.Constant(1.0),
+                                   (hidden_size,), get_default_dtype())
+
+    def forward(self, x):
+        h = x.astype(jnp.float32)
+        grouped = h.reshape(*h.shape[:-1], self.num_groups, -1)
+        grouped = grouped * jax.lax.rsqrt(
+            jnp.mean(jnp.square(grouped), axis=-1, keepdims=True)
+            + self.epsilon)
+        h = grouped.reshape(h.shape) * self.weight.astype(jnp.float32)
+        return h.astype(x.dtype)
 
 
 class InstanceNorm2D(Layer):

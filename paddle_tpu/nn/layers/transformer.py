@@ -130,6 +130,51 @@ class MultiHeadAttention(Layer):
         return self.out_proj(out.reshape(b, t, h * d))
 
 
+class GroupedQueryAttention(Layer):
+    """Self-attention with ``num_heads`` query heads on ``num_kv_heads``
+    key/value heads of ``head_dim`` (a KV head serves ``num_heads /
+    num_kv_heads`` query heads), no projection bias, no positional term
+    of its own, scale ``head_dim ** -0.5``. The KV heads are repeated
+    to the query heads before the attention call, so the routed flash
+    kernel sees plain multi-head operands in their native [B, T, H, D]
+    layout; the gradient of the repeat sums a group's heads."""
+
+    def __init__(self, hidden_size: int, num_heads: int,
+                 num_kv_heads: int, head_dim: int, causal: bool = True,
+                 weight_attr=None, out_weight_attr=None) -> None:
+        super().__init__()
+        if num_heads % num_kv_heads:
+            raise ValueError(f"{num_kv_heads} KV heads do not divide "
+                             f"{num_heads} query heads")
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim, self.causal = head_dim, causal
+        self.q_proj = Linear(hidden_size, num_heads * head_dim,
+                             weight_attr, bias_attr=False)
+        self.k_proj = Linear(hidden_size, num_kv_heads * head_dim,
+                             weight_attr, bias_attr=False)
+        self.v_proj = Linear(hidden_size, num_kv_heads * head_dim,
+                             weight_attr, bias_attr=False)
+        self.o_proj = Linear(num_heads * head_dim, hidden_size,
+                             out_weight_attr, bias_attr=False)
+
+    def forward(self, x):
+        from ...kernels import maybe_flash_attention
+        b, t, _ = x.shape
+        rep = self.num_heads // self.num_kv_heads
+        q = self.q_proj(x).reshape(b, t, self.num_heads, self.head_dim)
+
+        def kv(proj):
+            heads = proj(x).reshape(b, t, self.num_kv_heads,
+                                    self.head_dim)
+            return jnp.repeat(heads, rep, axis=2)
+
+        out = maybe_flash_attention(
+            q, kv(self.k_proj), kv(self.v_proj), causal=self.causal,
+            scale=self.head_dim ** -0.5, training=self.training,
+            layout="bthd")
+        return self.o_proj(out.reshape(b, t, -1))
+
+
 class TransformerEncoderLayer(Layer):
     def __init__(self, d_model: int, nhead: int, dim_feedforward: int,
                  dropout: float = 0.1, activation: str = "relu",

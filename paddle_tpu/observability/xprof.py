@@ -297,9 +297,19 @@ def parse_op_names(hlo_text: str) -> Dict[str, str]:
     several per cent of a train step. Such an instruction takes the
     ``op_name`` of the first instruction that uses its result, through
     other such instructions: the move is charged to the work that
-    needed the data. ``""`` where no user has one either."""
+    needed the data. ``""`` where no user has one either.
+
+    What the compiler rewrites into a kernel of its own carries a name
+    it made up (XLA:TPU turns ``lax.ragged_dot`` into Mosaic calls
+    named ``ragged-dot-none``): an ``op_name`` without a ``/`` is no
+    trace of the program's. Such an instruction is charged like a move,
+    and where nothing in its computation uses its result (a gradient
+    that leaves a loop's or a branch's body) to the work that made its
+    newest operand."""
     own: Dict[str, str] = {}
     users: Dict[str, List[str]] = {}
+    operands: Dict[str, List[str]] = {}
+    renamed = set()
     for line in hlo_text.splitlines():
         m = _INSTRUCTION_RE.match(line)
         if not m:
@@ -307,17 +317,25 @@ def parse_op_names(hlo_text: str) -> Dict[str, str]:
         name = m.group(1)
         found = _OP_NAME_RE.search(line)
         own[name] = found.group(1) if found else ""
-        for operand in _OPERAND_RE.findall(line[m.end():]):
+        if found and "/" not in own[name]:
+            own[name] = ""
+            renamed.add(name)
+        operands[name] = _OPERAND_RE.findall(line[m.end():])
+        for operand in operands[name]:
             users.setdefault(operand, []).append(name)
 
-    def inherited(name: str, depth: int = 8) -> str:
-        for user in users.get(name, ()) if depth else ():
-            op_name = own.get(user) or inherited(user, depth - 1)
+    def inherited(name: str, through: Dict[str, List[str]],
+                  depth: int = 8) -> str:
+        for other in through.get(name, ()) if depth else ():
+            op_name = own.get(other) or inherited(other, through,
+                                                  depth - 1)
             if op_name:
                 return op_name
         return ""
 
-    return {name: op_name or inherited(name)
+    newest_first = {name: ops[::-1] for name, ops in operands.items()}
+    return {name: op_name or inherited(name, users)
+            or (inherited(name, newest_first) if name in renamed else "")
             for name, op_name in own.items()}
 
 

@@ -228,6 +228,12 @@ class ShardedTrainStep:
         self.amp_dtype = amp_dtype if amp_dtype is not None \
             else getattr(self, "amp_dtype", None)
         self._skip_guard = _skip_guard_default()
+        # with FLAGS_compile_cache_dir set the guard's verdict rides the
+        # step's outputs, as in TrainStep: XLA persists no executable
+        # that holds a host callback
+        from ..static import _defer_probes_default
+        self._defer_probes = _defer_probes_default()
+        self._pending_signals = []
         self.lr_scale = 1.0
 
         params = model.param_dict()
@@ -390,6 +396,7 @@ class ShardedTrainStep:
         import contextlib
 
         from .. import amp as _amp
+        from ..observability import metrics as _obs_metrics
         from ..static import apply_fault_mults, probe_nonfinite
         params = state["params"]
         buffers = state["buffers"]
@@ -437,8 +444,12 @@ class ShardedTrainStep:
                                          state["opt"])
             new_buffers = _amp.select_update(found_inf, new_buffers,
                                              buffers)
-            probe_nonfinite(found_inf)
         metrics = {"loss": loss}
+        if found_inf is not None:
+            if self._defer_probes and _obs_metrics.enabled():
+                metrics["_pt_nonfinite"] = found_inf
+            else:
+                probe_nonfinite(found_inf)
         for name, fn in self.extra_metrics.items():
             metrics[name] = fn(out, *batch["labels"])
         new_state = {**state, "params": new_params,
@@ -465,8 +476,7 @@ class ShardedTrainStep:
         from ..static import inject_fault_mults
 
         # the entry point's host spans, as TrainStep's
-        # (docs/observability.md); nothing is drained here: the probes
-        # of this step stream through host callbacks
+        # (docs/observability.md)
         with _obs_span("pt/train_step/make_batch"):
             batch = inject_host_lr(
                 {"args": args, "labels": as_label_tuple(labels),
@@ -486,13 +496,33 @@ class ShardedTrainStep:
         if _obs_metrics.enabled():
             _obs_metrics.counter("optimizer_steps_total",
                                  "optimizer update steps applied").inc()
+        verdict = metrics.pop("_pt_nonfinite", None)
+        if verdict is not None:
+            with _obs_span("pt/train_step/drain"):
+                self._pending_signals.append(verdict)
+                self.flush_signals(block=False)
         return metrics
+
+    def flush_signals(self, block: bool = True) -> None:
+        """Hand the deferred skip-step verdicts to the host's counter
+        (``nonfinite_steps_total``); with ``block=False`` only those
+        whose buffers are ready, so a step never waits for the one
+        before it."""
+        from ..static import _note_nonfinite_host
+        keep = []
+        for verdict in self._pending_signals:
+            if not block and not verdict.is_ready():
+                keep.append(verdict)
+            elif bool(np.asarray(verdict)):
+                _note_nonfinite_host(True)
+        self._pending_signals = keep
 
     @property
     def params(self):
         return self.state["params"]
 
     def sync_to_model(self) -> None:
+        self.flush_signals()
         state = {**self.state["params"], **self.state["buffers"]}
         # A step that failed mid-execution may have consumed (deleted) the
         # donated buffers with no result to replace them; skip those rather

@@ -1,0 +1,451 @@
+"""Nemotron-H (Mamba-2 + dropless routed experts + GQA) against the
+plain float32 reference of ``benchmarks/references``, at tiny widths on
+the CPU. Both sides compute in float32 here, so the only difference is
+the order of summation (chunked scan against the recurrence, grouped
+matmul against a loop over experts): every tolerance is 1e-4 relative,
+a hundred times what that order moves a value and far under what any
+of the faults below does."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from benchmarks.references import nemotron3_nano_30b_a3b as ref
+from paddle_tpu.models import (NemotronHConfig, NemotronHForCausalLM,
+                               balance_router_bias, next_token_loss,
+                               routing_metrics)
+from paddle_tpu.nn.layer import functional_call
+from paddle_tpu.nn.layers import moe, ssm
+from paddle_tpu.static import TrainStep
+
+TOL = 1e-4
+CFG = dict(
+    vocab_size=96, hidden_size=32, hybrid_override_pattern="MEM*E",
+    mamba_num_heads=4, mamba_head_dim=8, ssm_state_size=16, n_groups=2,
+    conv_kernel=4, chunk_size=8, n_routed_experts=4,
+    n_routed_experts_total=16, expert_offset=4, num_experts_per_tok=3,
+    moe_intermediate_size=24, moe_shared_expert_intermediate_size=40,
+    routed_scaling_factor=2.5, norm_topk_prob=True,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+    layer_norm_epsilon=1e-5)
+WATCHED = ["layers.0.mixer.in_proj.weight", "layers.0.mixer.A_log",
+           "layers.1.mixer.w_in", "layers.1.mixer.router_weight",
+           "layers.3.mixer.q_proj.weight", "lm_head.weight"]
+SEQ = 21          # not a multiple of the chunk
+
+
+@pytest.fixture(autouse=True)
+def _small_windows(monkeypatch):
+    """Windows of a few rows, so every test walks more than one."""
+    monkeypatch.setattr(moe, "_ROW_TILE", 8)
+
+
+def build(seed=0, **over):
+    pt.seed(seed)
+    model = NemotronHForCausalLM(NemotronHConfig(**{**CFG, **over}))
+    # nothing at its initial value: a term multiplied by a 0 or a 1
+    # would hide its own absence
+    rng = np.random.default_rng(seed)
+    for name, p in model.named_parameters():
+        if name.endswith(("conv_bias", "mixer.D", "norm.weight",
+                          "norm_f.weight")):
+            p.value = p.value + jnp.asarray(
+                rng.normal(0, 0.3, p.shape), p.value.dtype)
+    return model
+
+
+def batch(seed=1, rows=2):
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, CFG["vocab_size"], (rows, SEQ + 1))
+    return ids[:, :-1].astype(np.int32), ids[:, 1:].astype(np.int32)
+
+
+def system(model, ids, labels):
+    buffers = model.buffer_dict()
+
+    @jax.jit
+    def run(params):
+        def loss_of(p):
+            out = functional_call(model, p, buffers, ids)
+            return next_token_loss(out, labels), out
+
+        (loss, out), grads = jax.value_and_grad(
+            loss_of, has_aux=True)(params)
+        return out, loss, grads
+
+    return run(model.param_dict())
+
+
+@jax.jit
+def _reference(params, buffers, ids, labels):
+    loss, grads = jax.value_and_grad(
+        lambda p: ref.loss(p, CFG, ids, labels, buffers))(params)
+    return ref.logits(params, CFG, ids, buffers), loss, grads
+
+
+def reference(model, ids, labels):
+    return _reference(model.param_dict(), model.buffer_dict(), ids, labels)
+
+
+def rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+
+def compare(model, told=None):
+    ids, labels = batch()
+    out, loss, grads = system(model, ids, labels)
+    ref_logits, ref_loss, ref_grads = reference(told or model, ids, labels)
+    assert rel(out.logits(), ref_logits) < TOL
+    assert abs(float(loss) - float(ref_loss)) < TOL * float(ref_loss)
+    for name in WATCHED:
+        assert rel(grads[name], ref_grads[name]) < TOL, name
+    assert int(out.moe_pairs_dropped) == 0
+    return out
+
+
+@pytest.mark.parametrize("recompute", ["none", "layer"])
+def test_logits_loss_and_gradients_match_the_reference(recompute):
+    model = build(recompute=recompute)
+    out = compare(model)
+    # two E layers, 42 tokens, 3 choices each, a quarter of the experts
+    assert 0 < int(out.moe_pairs_held) < 2 * 42 * 3
+
+
+def _bf16_scan(monkeypatch):
+    true_scan = ssm.ssd_chunked_scan
+
+    def scan(x, dt, b_mat, c_mat, a, chunk):
+        return true_scan(*(t.astype(jnp.bfloat16) for t in
+                           (x, dt, b_mat, c_mat)), a, chunk) \
+            .astype(x.dtype)
+
+    monkeypatch.setattr(ssm, "ssd_chunked_scan", scan)
+    return build()
+
+
+def _softmax_router(monkeypatch):
+    def route(self, tokens):
+        s = jax.nn.softmax(tokens @ self.router_weight, axis=-1)
+        _, chosen = jax.lax.top_k(s + self.e_score_correction_bias,
+                                  self.top_k)
+        w = jnp.take_along_axis(s, chosen, axis=-1)
+        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+        return chosen, w * self.routed_scaling_factor
+
+    monkeypatch.setattr(moe.DroplessMoE, "route", route)
+    return build()
+
+
+def _dropped_pairs(monkeypatch):
+    true_routed = moe.DroplessMoE._routed
+
+    def capped(self, tokens, weights, order, ends, rows, windows):
+        return true_routed(self, tokens, weights, order, ends,
+                           rows=8, windows=1)
+
+    monkeypatch.setattr(moe.DroplessMoE, "_routed", capped)
+    return build()
+
+
+def _no_skip(monkeypatch):
+    model = build()
+    for name, p in model.named_parameters():
+        if name.endswith("mixer.D"):
+            p.value = jnp.zeros_like(p.value)
+    return model
+
+
+def _no_scaling(monkeypatch):
+    return build(routed_scaling_factor=1.0)
+
+
+FAULTS = {"bf16_scan": _bf16_scan, "softmax_router": _softmax_router,
+          "dropped_pairs": _dropped_pairs, "no_D_x": _no_skip,
+          "no_scaling_factor": _no_scaling}
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_a_fault_fails_the_comparison(fault, monkeypatch):
+    model = FAULTS[fault](monkeypatch)
+    with pytest.raises(AssertionError):
+        compare(model, told=build())     # the reference is told no fault
+
+
+@pytest.mark.parametrize("length", [5, 8, 21, 37])
+def test_chunked_scan_matches_the_recurrence(length):
+    rng = np.random.default_rng(length)
+    h, p, g, n = 4, 8, 2, 16
+    x = jnp.asarray(rng.normal(size=(1, length, h, p)), jnp.float32)
+    dt = jnp.asarray(rng.uniform(0.01, 0.5, (1, length, h)), jnp.float32)
+    a = -jnp.asarray(rng.uniform(0.5, 4.0, (h,)), jnp.float32)
+    b_mat = jnp.asarray(rng.normal(size=(1, length, g, n)), jnp.float32)
+    c_mat = jnp.asarray(rng.normal(size=(1, length, g, n)), jnp.float32)
+    spread = lambda t: jnp.repeat(t[0], h // g, axis=1)
+    want = ref.recurrence(x[0], dt[0], a, spread(b_mat), spread(c_mat))
+    got = ssm.ssd_chunked_scan(x, dt, b_mat, c_mat, a, chunk=8)
+    assert rel(got[0], want) < TOL
+
+
+def _moe_layer(held, offset, seed=3):
+    pt.seed(seed)
+    return pt.nn.DroplessMoE(32, 24, 16, 3, d_shared=40,
+                             experts_held=held, expert_offset=offset,
+                             routed_scaling_factor=2.5)
+
+
+def _ref_layer(params, x, held, offset, bias=0.0):
+    with jax.default_matmul_precision("highest"):
+        return ref.routed_experts(params, "", CFG, x, bias, held, offset) \
+            + ref.shared_expert(params, "", x)
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """16 experts as 4 shares of 4: the four routed parts, with the
+    shared expert counted once, are the whole layer's result."""
+    whole = _moe_layer(16, 0)
+    params = whole.param_dict()
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(2, SEQ, 32)),
+                    jnp.float32)
+    tokens = x.reshape(-1, 32)
+    want = _ref_layer(params, tokens, 16, 0)
+    with jax.default_matmul_precision("highest"):
+        shared = ref.shared_expert(params, "", tokens)
+    total, pairs = -3 * shared, 0
+    for i in range(4):
+        share = _moe_layer(4, 4 * i)
+        mine = {**params, "w_in": params["w_in"][4 * i:4 * i + 4],
+                "w_out": params["w_out"][4 * i:4 * i + 4]}
+        out, stats = functional_call(share, mine, share.buffer_dict(), x)
+        # each share alone is what the reference gives for that share
+        assert rel(out.reshape(-1, 32),
+                   _ref_layer(mine, tokens, 4, 4 * i)) < TOL
+        total = total + out.reshape(-1, 32)
+        pairs += int(stats["pairs_held"])
+        assert int(stats["pairs_dropped"]) == 0
+    assert rel(total, want) < TOL
+    assert pairs == tokens.shape[0] * 3, "every pair is someone's"
+    whole_out, _ = whole(x)
+    assert rel(whole_out.reshape(-1, 32), want) < TOL
+
+
+@pytest.mark.parametrize("where", ["all_held", "none_held"])
+def test_adversarial_routing_drops_nothing(where):
+    """The selection bias pushes every token's choices onto the held
+    experts, or onto none of them: the windows cover the worst case."""
+    layer = _moe_layer(4, 8)
+    push = 10.0 if where == "all_held" else -10.0
+    bias = jnp.zeros((16,), jnp.float32).at[8:12].set(push)
+    x = jnp.asarray(np.random.default_rng(1).normal(size=(2, SEQ, 32)),
+                    jnp.float32)
+    params = layer.param_dict()
+
+    def run(p):
+        out, stats = functional_call(
+            layer, p, {"e_score_correction_bias": bias}, x)
+        return jnp.sum(out * out), (out, stats)
+
+    (_, (out, stats)), grads = jax.value_and_grad(run, has_aux=True)(params)
+    want_fn = lambda p: jnp.sum(jnp.square(
+        _ref_layer(p, x.reshape(-1, 32), 4, 8, bias)))
+    want_grads = jax.grad(want_fn)(params)
+    assert rel(out.reshape(-1, 32),
+               _ref_layer(params, x.reshape(-1, 32), 4, 8, bias)) < TOL
+    pairs = 2 * SEQ * 3
+    assert int(stats["pairs_held"]) == (pairs if where == "all_held"
+                                        else 0)
+    assert int(stats["pairs_dropped"]) == 0
+    for name in ("w_in", "w_out", "router_weight", "shared_in.weight"):
+        if where == "none_held" and name in ("w_in", "w_out"):
+            assert not np.any(np.asarray(grads[name]))
+        elif where == "none_held" and name == "router_weight":
+            continue                    # the reference's is zero too
+        else:
+            assert rel(grads[name], want_grads[name]) < TOL, name
+
+
+def test_rows_past_the_last_group_are_never_read(monkeypatch):
+    """XLA:TPU's grouped-matmul kernel leaves the rows past the last
+    group uninitialised (the CPU's zero-fills them). A window hands the
+    kernel groups that cover every row of it, and the layer's result
+    and gradients do not depend on what a kernel would leave past
+    them."""
+    true_dot = jax.lax.ragged_dot
+
+    def garbage_past_the_groups(x, w, sizes):
+        dead = jnp.arange(x.shape[0]) >= jnp.sum(sizes)
+        return jnp.where(dead[:, None], jnp.nan, true_dot(x, w, sizes))
+
+    layer = _moe_layer(4, 8)
+    x = jnp.asarray(np.random.default_rng(2).normal(size=(2, SEQ, 32)),
+                    jnp.float32)
+
+    def run(p):
+        out, _ = functional_call(layer, p, layer.buffer_dict(), x)
+        return jnp.sum(out * out), out
+
+    params = layer.param_dict()
+    (_, want), want_grads = jax.value_and_grad(run, has_aux=True)(params)
+    monkeypatch.setattr(jax.lax, "ragged_dot", garbage_past_the_groups)
+    (_, got), grads = jax.value_and_grad(run, has_aux=True)(params)
+    assert np.isfinite(np.asarray(got)).all()
+    assert rel(got, want) < 1e-6
+    for name in params:
+        assert rel(grads[name], want_grads[name]) < 1e-6, name
+
+
+@pytest.mark.parametrize("held_pairs", [0, 5, 16])
+def test_a_window_is_computed_whole(held_pairs, monkeypatch):
+    """Whatever share of a window holds pairs, the grouped matmuls get
+    groups that sum to the window's rows (the rows past the last pair
+    are zeros in the last group), so a window's time does not follow
+    the routing; the zeros add nothing."""
+    layer = _moe_layer(4, 0)
+    rows, n = 16, 12
+    rng = np.random.default_rng(held_pairs)
+    tokens = jnp.asarray(rng.normal(size=(n, 32)), jnp.float32)
+    weights = jnp.asarray(rng.uniform(0.1, 1, (n, 3)), jnp.float32)
+    # sorted (token, choice) pairs: `held_pairs` of them on held experts
+    sizes = np.bincount(rng.integers(0, 4, held_pairs), minlength=4)
+    order = jnp.asarray(np.pad(rng.permutation(n * 3), (0, rows)))
+    ends = jnp.asarray(np.cumsum(sizes), jnp.int32)
+    seen = []
+    true_dot = jax.lax.ragged_dot
+
+    def counting(x, w, group_sizes):
+        seen.append((x.shape[0], int(jnp.sum(group_sizes))))
+        return true_dot(x, w, group_sizes)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", counting)
+    got = layer._window(tokens, weights, layer.w_in, layer.w_out, order,
+                        ends, 0, rows)
+    assert seen == [(rows, rows)] * 2
+    want = np.zeros((n, 32), np.float32)
+    expert = np.repeat(np.arange(4), sizes)
+    for pair, e in zip(np.asarray(order)[:held_pairs], expert):
+        t = tokens[pair // 3]
+        want[pair // 3] += weights.reshape(-1)[pair] * (
+            jnp.square(jax.nn.relu(t @ layer.w_in[e])) @ layer.w_out[e])
+    assert np.allclose(got, want, atol=1e-5)
+
+
+def _zipf_ids(rows=4, seed=7):
+    rng = np.random.default_rng(seed)
+    p = 1.0 / np.arange(1, CFG["vocab_size"] + 1)
+    return rng.choice(CFG["vocab_size"], (rows, 64), p=p / p.sum()) \
+        .astype(np.int32)
+
+
+def test_fitting_the_selection_bias_balances_the_experts():
+    model = build()
+    ids = _zipf_ids()
+    before = model(ids).moe_expert_load
+    worst_before = float(jnp.max(before.max(1) / before.mean(1)))
+    worst = balance_router_bias(model, ids)
+    after = model(ids).moe_expert_load
+    assert int(after.sum()) == int(before.sum()), "no pair went missing"
+    assert worst < 0.6 * worst_before, (worst, worst_before)
+    assert any(float(jnp.abs(b).max()) > 0 for n, b in
+               model.buffer_dict().items() if "correction_bias" in n)
+    assert model.training
+    # the fitted bias is what selects, in the reference too
+    compare(model)
+
+
+def test_the_bias_moves_a_step_at_a_time_while_training():
+    model = build(router_bias_update_rate=0.01, recompute="layer")
+    step = TrainStep(model, pt.optimizer.AdamW(1e-3), next_token_loss)
+    ids = _zipf_ids()
+    step(ids, labels=(ids,))
+    name = "layers.1.mixer.e_score_correction_bias"
+    once = np.asarray(step.state["buffers"][name])
+    assert set(np.unique(np.abs(once))) <= {0.0, np.float32(0.01)}
+    assert np.any(once != 0)
+    step(ids, labels=(ids,))
+    assert np.abs(np.asarray(step.state["buffers"][name])).max() \
+        <= 0.02 + 1e-6
+    # evaluation leaves it alone
+    model.eval()
+    out, buffers = functional_call(model, step.state["params"],
+                                   step.state["buffers"], ids,
+                                   capture_buffers=True)
+    assert np.array_equal(buffers[name], step.state["buffers"][name])
+
+
+def test_rms_norm_groups():
+    norm = pt.nn.RMSNorm(16, epsilon=1e-5, num_groups=4)
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(3, 16)),
+                    jnp.float32)
+    grouped = np.asarray(x).reshape(3, 4, 4)
+    want = grouped / np.sqrt((grouped ** 2).mean(-1, keepdims=True) + 1e-5)
+    assert rel(norm(x), want.reshape(3, 16)) < 1e-6
+
+
+def test_train_step_returns_the_routing_counters_and_learns():
+    model = build(recompute="layer")
+    model.to(dtype="bfloat16")
+    step = TrainStep(model, pt.optimizer.AdamW(3e-3), next_token_loss,
+                     extra_metrics=routing_metrics())
+    ids, labels = batch(rows=4)
+    first = step(ids, labels=(labels,))
+    for _ in range(14):
+        last = step(ids, labels=(labels,))
+    assert float(last["loss"]) < float(first["loss"]) - 0.3
+    assert int(last["moe_pairs_dropped"]) == 0
+    assert 0 < int(last["moe_pairs_held"]) < 2 * 4 * SEQ * 3
+    assert float(last["moe_load_max_over_mean"]) >= 1.0
+
+
+def test_the_step_names_its_blocks():
+    """Every block of the model is a ``pt.`` scope in the compiled
+    step, forward and backward, loops included (a loop body is lowered
+    with its own name stack: the scope is entered inside it)."""
+    import re
+
+    from paddle_tpu import observability as obs
+    from paddle_tpu.observability import xprof
+    was = jax.config.jax_compilation_cache_include_metadata_in_key
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
+    obs.reset_all()
+    pt.set_flags({"enable_metrics": True})
+    try:
+        step = TrainStep(build(recompute="layer"),
+                         pt.optimizer.AdamW(1e-3), next_token_loss,
+                         extra_metrics=routing_metrics())
+        ids, labels = batch()
+        step(ids, labels=(labels,))
+        names = list(xprof.op_scopes(step._span_name).values())
+    finally:
+        pt.set_flags({"enable_metrics": False})
+        obs.reset_all()
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", was)
+    by_block = {}
+    for name in names:
+        found = re.findall(r"pt\.[a-z_]+", name)
+        if found:
+            by_block.setdefault(found[-1], []).append(name)
+    for scope in ("pt.embed", "pt.ssm_proj", "pt.ssm_conv", "pt.ssm_scan",
+                  "pt.moe_route", "pt.moe_experts", "pt.moe_shared",
+                  "pt.attn", "pt.head_loss"):
+        assert any("transpose(" in n for n in by_block[scope]), scope
+        assert any("transpose(" not in n for n in by_block[scope]), scope
+    # the scan's loop bodies carry the scope themselves
+    assert any("while/body" in n for n in by_block["pt.ssm_scan"])
+    assert any("while/body" in n for n in by_block["pt.head_loss"])
+
+
+def test_hapi_fit_trains_it_like_any_model():
+    from paddle_tpu import hapi
+    from paddle_tpu.data import DataLoader, TensorDataset
+    ids = np.random.default_rng(0).integers(
+        0, CFG["vocab_size"], (16, SEQ + 1)).astype(np.int32)
+    model = hapi.Model(build(recompute="layer"))
+    model.prepare(pt.optimizer.AdamW(3e-3), next_token_loss)
+    history = model.fit(
+        DataLoader(TensorDataset([ids[:, :-1], ids[:, 1:]]), batch_size=4),
+        epochs=4, verbose=0)
+    assert history["loss"][-1] < history["loss"][0] - 0.3

@@ -128,6 +128,46 @@ def test_skip_guard_counts_nonfinite_steps():
         pt.set_flags({"enable_metrics": False})
 
 
+@pytest.mark.parametrize("cache_dir", ["", "persist"])
+def test_sharded_step_counts_nonfinite_steps(cache_dir, tmp_path):
+    """The sharded step's verdict reaches the counter through a host
+    callback, or — with ``FLAGS_compile_cache_dir`` set, where XLA
+    persists no executable that holds one — rides the step's outputs
+    and is drained on the host, as in TrainStep."""
+    from jax.sharding import PartitionSpec as P
+    from paddle_tpu.parallel import ShardedTrainStep, create_mesh
+
+    was = pt.get_flags(["compile_cache_dir"])["compile_cache_dir"]
+    pt.set_flags({"enable_metrics": True, "metrics_port": -1,
+                  "compile_cache_dir":
+                      str(tmp_path) if cache_dir else ""})
+    try:
+        pt.seed(0)
+        step = ShardedTrainStep(
+            pt.nn.Linear(4, 2), pt.optimizer.SGD(learning_rate=0.1),
+            lambda o, y: pt.nn.functional.cross_entropy(o, y),
+            create_mesh({"dp": 2, "mp": 2}, allow_submesh=True),
+            batch_spec=P("dp"))
+        assert step._defer_probes == bool(cache_dir)
+        counter = obs.metrics.counter("nonfinite_steps_total",
+                                      always=True)
+        before = counter.value()
+        assert set(step(*_data()[:1], labels=_data()[1])) == {"loss"}
+        kept = np.asarray(step.state["params"]["weight"]).copy()
+        xp, yp = _data(poison=True)
+        assert set(step(xp, labels=yp)) == {"loss"}
+        step.flush_signals()
+        jax.effects_barrier()
+        assert counter.value() == before + 1
+        assert np.array_equal(step.state["params"]["weight"], kept)
+        lowered = step._jitted.lower(step.state, step._place_batch(
+            {"args": (xp,), "labels": (yp,), "kwargs": {}})).as_text()
+        assert ("callback" in lowered.lower()) == (not cache_dir)
+    finally:
+        pt.set_flags({"enable_metrics": False,
+                      "compile_cache_dir": was})
+
+
 def test_skip_guard_opt_out_flag():
     pt.set_flags({"skip_nonfinite_steps": False})
     try:

@@ -192,6 +192,34 @@ def test_what_the_compiler_added_takes_its_users_op_name():
     assert got["copy-start.2"] == got["copy-done.2"] == ""
 
 
+def test_a_kernel_the_compiler_renamed_is_charged_to_its_neighbours():
+    """XLA:TPU rewrites ``lax.ragged_dot`` into Mosaic calls whose
+    ``op_name`` is its own (``ragged-dot-none``, no ``/`` in it): the
+    call is charged to the work that uses its result, and a result that
+    leaves its computation to the work that made its newest operand."""
+    experts = "jit(_step)/jvp(pt.moe_experts)/square"
+    back = "jit(_step)/transpose(jvp(pt.moe_experts))/select_n"
+    text = "\n".join([
+        '  %fusion.1 = bf16[16,8]{1,0} fusion(%t), kind=kLoop, '
+        'calls=%f1, metadata={op_name="jit(_step)/jvp(pt.moe_route)/'
+        'gather"}',
+        '  %ragged-dot-none.1 = bf16[16,4]{1,0} custom-call(%m, '
+        '%fusion.1, %w), custom_call_target="tpu_custom_call", '
+        'metadata={op_name="ragged-dot-none"}',
+        '  %fusion.2 = bf16[16,4]{1,0} fusion(%ragged-dot-none.1), '
+        'kind=kLoop, calls=%f2, metadata={op_name="' + experts + '"}',
+        '  %fusion.3 = bf16[16,4]{1,0} fusion(%g), kind=kLoop, '
+        'calls=%f3, metadata={op_name="' + back + '"}',
+        '  %ragged-dot-none.2 = bf16[2,8,4]{2,1,0} custom-call(%m, '
+        '%fusion.1, %fusion.3), custom_call_target="tpu_custom_call", '
+        'metadata={op_name="ragged-dot-none"}',
+        '  ROOT %tuple.5 = (bf16[2,8,4]) tuple(%ragged-dot-none.2)',
+    ])
+    got = xprof.parse_op_names(text)
+    assert got["ragged-dot-none.1"] == experts
+    assert got["ragged-dot-none.2"] == back
+
+
 def test_host_spans_cover_the_whole_entry_point(metrics_on):
     step = _make_step("TrainStep")
     _run(step, 2)

@@ -100,6 +100,50 @@ def test_flash_attention_bthd_fwd_bwd_compiles(chip):
     assert "tpu_custom_call" in text
 
 
+def test_flash_attention_causal_8192_positions_head_128_compiles(chip):
+    """The hybrid language model's attention layer: 8192 positions of
+    128-wide heads, causal. Every program keeps whole sequences in
+    VMEM, and the dk/dv kernel's (q, dO and two float32 columns,
+    double-buffered) take 25 MiB, past the compiler's 16 MiB scoped
+    default: the call asks for what it needs (``_grid_params``)."""
+    from paddle_tpu.kernels.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, bthd=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    qkv = ((1, 8192, 4, 128), jnp.bfloat16)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), chip, qkv, qkv,
+                    qkv)
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text
+
+
+def test_grouped_matmul_of_held_pairs_compiles_to_one_pass(chip):
+    """``jax.lax.ragged_dot`` at the routed experts' widths, forward
+    and both gradients: XLA:TPU lowers each to its own grouped-matmul
+    kernel over the true group sizes (a custom call), not to a dense
+    product of every row with every expert."""
+    rows, d, f, experts = 12288, 2688, 1856, 8
+
+    def loss(x, w_in, w_out, sizes):
+        h = jnp.square(jax.nn.relu(jax.lax.ragged_dot(x, w_in, sizes)))
+        return jnp.sum(jax.lax.ragged_dot(h, w_out, sizes)
+                       .astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*[
+        jax.ShapeDtypeStruct(s, t, sharding=chip) for s, t in (
+            ((rows, d), jnp.bfloat16), ((experts, d, f), jnp.bfloat16),
+            ((experts, f, d), jnp.bfloat16), ((experts,), jnp.int32))
+    ]).compile()
+    assert "ragged-dot" in compiled.as_text()
+    one_pass = 2.0 * rows * d * f
+    # two forward products, their two input and two weight gradients
+    # and one recomputed forward product: 7 passes (8 allowed), where a
+    # dense lowering would take `experts` times that
+    assert compiled.cost_analysis()["flops"] < 8.5 * one_pass
+
+
 def test_layer_norm_fwd_bwd_compiles(chip):
     from paddle_tpu.kernels.layer_norm import layer_norm_pallas
 
