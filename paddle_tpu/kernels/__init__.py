@@ -44,14 +44,19 @@ def _per_shard(kernel, operands, dims, out_dims):
     other layout GSPMD holds is resharded to this one around the call.
     ``shard`` is a traced int32 that differs between shards (0 without
     a mesh), for kernels that draw random bits.
+
+    Never differentiate through this call when an operand is whole over
+    a mesh axis (layer norm's rows over ``mp``): transposing the
+    ``shard_map`` sums that operand's cotangent over the axis, an
+    all-reduce of identical copies. Such a kernel keeps its
+    ``custom_vjp`` outside (kernels/layer_norm.py); flash attention
+    names both axes on q/k/v and has nothing to sum.
     """
     import jax.numpy as jnp
-    from jax.sharding import AxisType, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
-    mesh = jax.sharding.get_abstract_mesh()
-    sizes = {n: s for n, s, t in zip(mesh.axis_names, mesh.axis_sizes,
-                                     mesh.axis_types)
-             if t == AxisType.Auto and s > 1}
+    from ..parallel.mesh import auto_axis_sizes
+    sizes = auto_axis_sizes()
     if not sizes:
         return kernel(*operands, jnp.int32(0))
     # absent optional operands (None) stay outside the shard_map
@@ -77,7 +82,7 @@ def _per_shard(kernel, operands, dims, out_dims):
 
     from ..parallel._shard_map import shard_map
     return shard_map(
-        local, mesh,
+        local, jax.sharding.get_abstract_mesh(),
         in_specs=tuple(spec(operands[i].ndim, dims[i]) for i in live),
         out_specs=spec(operands[0].ndim, out_dims),
         check_vma=False)(*(operands[i] for i in live))
@@ -96,12 +101,8 @@ def maybe_layer_norm(x, weight, bias, epsilon: float, begin_norm_axis: int):
     if pallas_enabled() and GLOBAL_FLAGS.get("use_pallas_layer_norm") \
             and begin_norm_axis == x.ndim - 1 and x.ndim >= 2:
         try:
-            from ..parallel.mesh import DP
             from .layer_norm import layer_norm_pallas
-            return _per_shard(
-                lambda x, w, b, _shard: layer_norm_pallas(x, w, b,
-                                                          epsilon),
-                (x, weight, bias), ({0: DP}, {}, {}), {0: DP})
+            return layer_norm_pallas(x, weight, bias, epsilon)
         # ptlint: disable=silent-failure -- NotImplementedError is the kernel's documented "shape unsupported" signal; the reference impl below is the answer
         except NotImplementedError:
             pass

@@ -12,6 +12,13 @@ the per-row mean/rstd from the saved input (avoids 1-D tiled kernel outputs,
 which Mosaic lays out incompatibly with XLA) and applies the standard fused
 three-term formula in fp32 XLA ops — the stat recompute fuses into the same
 HBM pass as the dx computation.
+
+Under a mesh only the forward kernel runs per shard (``_per_shard``: rows
+over ``dp``); the ``custom_vjp`` sits outside that ``shard_map``, so the
+backward is ordinary XLA that the partitioner leaves local to each chip
+(``dw``/``db`` summed over ``dp``). Inside the ``shard_map`` its transpose
+would sum ``dx`` over ``mp``, where the rows are whole: an all-reduce of
+two identical halves per norm site.
 """
 
 from __future__ import annotations
@@ -69,13 +76,21 @@ def _ln_forward(x, w, b, eps: float, interpret: bool):
     )(x, w, b)
 
 
+def _ln_forward_per_shard(x, w, b, eps: float, interpret: bool):
+    from . import _per_shard
+    from ..parallel.mesh import DP
+    return _per_shard(
+        lambda x, w, b, _shard: _ln_forward(x, w, b, eps, interpret),
+        (x, w, b), ({0: DP}, {}, {}), {0: DP})
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _ln_core(x, w, b, eps: float, interpret: bool):
-    return _ln_forward(x, w, b, eps, interpret)
+    return _ln_forward_per_shard(x, w, b, eps, interpret)
 
 
 def _ln_fwd(x, w, b, eps, interpret):
-    return _ln_forward(x, w, b, eps, interpret), (x, w, b)
+    return _ln_forward_per_shard(x, w, b, eps, interpret), (x, w, b)
 
 
 def _ln_bwd(eps, interpret, res, g):

@@ -24,6 +24,53 @@ from .common import Dropout, Linear
 from .norm import LayerNorm
 
 
+@jax.custom_vjp
+def _qkv_linear(x, wq, bq, wk, bk, wv, bv):
+    """Three ``F.linear`` projections of one input, values as three
+    ``Linear`` calls give them. Differs only backward: with the weights
+    split by columns over ``mp`` (megatron_param_rule) each of
+    ``dQ Wq^T``, ``dK Wk^T``, ``dV Wv^T`` is a partial sum a chip, and
+    autodiff adds them to the input's cotangent in an order that makes
+    the partitioner all-reduce each product where its dot ends: three
+    exchanges of ``[B, T, H]`` a layer. Written as one adjacent sum,
+    XLA adds the partial products on the chip and exchanges once."""
+    return F.linear(x, wq, bq), F.linear(x, wk, bk), F.linear(x, wv, bv)
+
+
+def _qkv_linear_fwd(x, *wb):
+    return _qkv_linear(x, *wb), (x, wb)
+
+
+def _qkv_linear_bwd(res, cts):
+    x, wb = res
+    grads, dx = [], None
+    for ct, w, b in zip(cts, wb[::2], wb[1::2]):
+        # weight and bias gradients are F.linear's own
+        _, pull = jax.vjp(lambda w, b: F.linear(x, w, b), w, b)
+        grads += pull(ct)
+        part = jnp.matmul(ct, w.T)
+        dx = part if dx is None else dx + part
+    return (dx.astype(x.dtype), *grads)
+
+
+_qkv_linear.defvjp(_qkv_linear_fwd, _qkv_linear_bwd)
+
+
+def _self_attention_projections(q_proj, k_proj, v_proj, x):
+    """``q_proj(x), k_proj(x), v_proj(x)``. Under a mesh that splits
+    the model over ``mp`` (the one in scope while a sharded step
+    traces) the three go through ``_qkv_linear``, which sums their
+    input gradients before the exchange; with no such axis these are
+    the three ``Linear`` calls, and the program is what it was."""
+    from ...parallel.mesh import MP, auto_axis_sizes
+    if MP not in auto_axis_sizes():
+        return q_proj(x), k_proj(x), v_proj(x)
+    from ...observability.xprof import note_qkv_grad_summed
+    note_qkv_grad_summed()
+    return _qkv_linear(x, *(p for proj in (q_proj, k_proj, v_proj)
+                            for p in (proj.weight, proj.bias)))
+
+
 class MultiHeadAttention(Layer):
     """(capability ref: multihead_matmul_op.cu fused attention)."""
 
@@ -78,8 +125,9 @@ class MultiHeadAttention(Layer):
         # targeted the XLA composition, where dot_general re-transposes
         # anyway — that objection does not apply to the Pallas path.
         from ...flags import GLOBAL_FLAGS
+        self_attention = key is None and value is None
         fusable = (GLOBAL_FLAGS.get("fused_qkv_projection")
-                   and key is None and value is None
+                   and self_attention
                    and self.q_proj.in_features == self.k_proj.in_features
                    == self.v_proj.in_features
                    and ((self.q_proj.bias is None)
@@ -89,6 +137,9 @@ class MultiHeadAttention(Layer):
         value = key if value is None else value
         if fusable:
             qp, kp, vp = self._qkv_self(query)
+        elif self_attention and not self.need_weights:
+            qp, kp, vp = _self_attention_projections(
+                self.q_proj, self.k_proj, self.v_proj, query)
         else:
             qp = self.q_proj(query)
             kp = self.k_proj(key)

@@ -36,6 +36,9 @@ metrics are on, all at trace time and nothing per step:
 - ``note_dropout_mask``: each dropout call site notes the elements its
   hashed keep-mask covers, published per entry point as the gauges
   ``pt_dropout_mask_sites`` / ``pt_dropout_mask_elements``;
+- ``note_qkv_grad_summed``: each self-attention site whose three
+  projection input gradients are summed before they cross the ``mp``
+  link notes itself, published as ``pt_qkv_grad_summed_sites``;
 - ``op_scopes(fn)``: on demand, the compiled program's
   ``{instruction name: op_name}``. A device profile names an operation
   by its HLO instruction; the ``jax.named_scope`` it was traced under
@@ -54,8 +57,9 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 from . import metrics as _metrics
 
 __all__ = ["ProgramCardRegistry", "cards", "enabled", "harvest",
-           "flops_of", "note_kernel", "note_dropout_mask", "kernel_notes",
-           "op_scopes", "parse_op_names"]
+           "flops_of", "note_kernel", "note_dropout_mask",
+           "note_qkv_grad_summed", "kernel_notes", "op_scopes",
+           "parse_op_names"]
 
 # Cost-analysis keys promoted onto the card top level when present.
 _COST_KEYS = ("flops", "transcendentals", "bytes accessed")
@@ -222,21 +226,24 @@ _KERNEL_NOTES: Dict[str, List[KernelNote]] = {}
 @contextlib.contextmanager
 def tracing(fn_name: str) -> Iterator[None]:
     """Entered by the recompile tracker round the body of a jit entry
-    point, which runs only while jax traces it: kernels and dropout
-    sites traced inside note their work under ``fn_name``. The newest
-    trace replaces what the one before noted (a retrace, or
-    ``op_scopes`` lowering the entry point again, must not count a call
-    site twice)."""
-    outer = getattr(_TLS, "notes", None), getattr(_TLS, "masked", None)
+    point, which runs only while jax traces it: kernels, dropout sites
+    and summed q/k/v gradients traced inside note themselves under
+    ``fn_name``. The newest trace replaces what the one before noted (a
+    retrace, or ``op_scopes`` lowering the entry point again, must not
+    count a call site twice)."""
+    outer = (getattr(_TLS, "notes", None), getattr(_TLS, "masked", None),
+             getattr(_TLS, "summed", None))
     _TLS.notes = notes = []
     _TLS.masked = masked = []
+    _TLS.summed = summed = []
     try:
         yield
     finally:
-        _TLS.notes, _TLS.masked = outer
+        _TLS.notes, _TLS.masked, _TLS.summed = outer
         if outer[0] is not None:    # an entry point traced inside another
             outer[0].extend(notes)
             outer[1].extend(masked)
+            outer[2].extend(summed)
         if _metrics.enabled():
             with _NOTES_LOCK:
                 _KERNEL_NOTES[fn_name] = notes
@@ -249,6 +256,11 @@ def tracing(fn_name: str) -> Iterator[None]:
                 "pt_dropout_mask_elements",
                 "elements those sites mask in one call of the entry "
                 "point").set(sum(masked), fn=fn_name)
+            _metrics.gauge(
+                "pt_qkv_grad_summed_sites",
+                "self-attention sites whose q/k/v input gradients are "
+                "summed before the mp all-reduce, in the newest trace "
+                "of the entry point").set(len(summed), fn=fn_name)
 
 
 def note_kernel(name: str, flops: float, bytes_: float) -> None:
@@ -267,6 +279,16 @@ def note_dropout_mask(elements: int) -> None:
     masked = getattr(_TLS, "masked", None)
     if masked is not None and _metrics.enabled():
         masked.append(int(elements))
+
+
+def note_qkv_grad_summed() -> None:
+    """Called once per traced self-attention site that takes the
+    projections whose input gradients are summed before the exchange
+    over ``mp``. A no-op unless metrics are on and a tracked entry
+    point is being traced."""
+    summed = getattr(_TLS, "summed", None)
+    if summed is not None and _metrics.enabled():
+        summed.append(1)
 
 
 def kernel_notes(fn_name: str) -> List[KernelNote]:

@@ -57,6 +57,18 @@ def create_mesh(axes: Optional[Dict[str, int]] = None,
     return Mesh(arr, tuple(axes))
 
 
+def auto_axis_sizes() -> Dict[str, int]:
+    """``{axis: size}`` of the axes larger than 1 that GSPMD partitions
+    (``AxisType.Auto``) in the mesh in scope — the one
+    ``jax.sharding.set_mesh`` holds while a sharded step traces; empty
+    when there is none. What a trace may adapt to: no flag says it."""
+    from jax.sharding import AxisType
+    mesh = jax.sharding.get_abstract_mesh()
+    return {n: s for n, s, t in zip(mesh.axis_names, mesh.axis_sizes,
+                                    mesh.axis_types)
+            if t == AxisType.Auto and s > 1}
+
+
 def num_slices(devices: Optional[Sequence] = None) -> int:
     """Number of distinct TPU slices among ``devices`` (1 on CPU/GPU or a
     single slice). Multi-slice topologies expose ``slice_index`` on each

@@ -302,9 +302,12 @@ def test_counter_reads_the_cells_sites_and_elements(monkeypatch):
         sites = obs.gauge("pt_dropout_mask_sites").value(fn=step._span_name)
         elements = obs.gauge("pt_dropout_mask_elements").value(
             fn=step._span_name)
+        summed = obs.gauge("pt_qkv_grad_summed_sites").value(
+            fn=step._span_name)
     finally:
         pt.set_flags({"enable_metrics": was})
     assert sites == 37
+    assert summed == 0      # one chip: no mp axis, three Linear calls
     assert elements == 918_552_576 == \
         b * s * (768 + 12 * (768 + 3072 + 768))
     # outside a tracked entry point nothing is counted

@@ -174,21 +174,29 @@ def test_fused_linear_softmax_xent_fwd_bwd_compiles(chip):
     assert "tpu_custom_call" in text
 
 
-def test_encoder_layer_step_holds_no_random_words(chip, monkeypatch):
-    """One BertEncoderLayer forward and backward at bert_base_s512's
-    shape (b32 x s512, three dropouts at 0.1), under the generator the
-    chip runs (``rbg``, core/random.py): the keep-masks are hashed
-    inside the fusions that use them, so the only random bits the
-    compiled program draws are the flash kernel's one seed, and it
-    writes no word per element."""
-    import math
-    import re
+@functools.lru_cache(maxsize=None)
+def _encoder_layer_step_text(on_mesh: bool) -> str:
+    """Compiled text of one BertEncoderLayer, forward and backward with
+    its three dropouts at 0.1 under the generator the chip runs
+    (``rbg``, core/random.py), for the described v5e: on one device at
+    bert_base_s512's shape (b32 x s512, no mesh in scope), or over all
+    four as dp2 x mp2 at bert_base_s512_dp2mp2's (b128: 64 sequences a
+    chip, parameters placed by megatron_param_rule, the mesh in scope
+    as ``ShardedTrainStep`` sets it)."""
+    import contextlib
+    from unittest import mock
+
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from paddle_tpu import kernels
     from paddle_tpu.core import random as _random
     from paddle_tpu.models.bert import BertConfig, BertEncoderLayer
     from paddle_tpu.nn.layer import functional_call
-    monkeypatch.setattr(kernels, "_on_tpu", lambda: True)
+    from paddle_tpu.parallel.spmd import megatron_param_rule
+    devices = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices
     layer = BertEncoderLayer(BertConfig())
     layer.to(dtype="bfloat16")
     layer.train()
@@ -199,19 +207,60 @@ def test_encoder_layer_step_holds_no_random_words(chip, monkeypatch):
             y = functional_call(layer, p, buffers, x)
         return jnp.sum(y.astype(jnp.float32))
 
-    def on_chip(a):
-        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+    if on_mesh:
+        mesh = Mesh(np.array(devices).reshape(2, 2), ("dp", "mp"))
+        rule = megatron_param_rule()
+        scope, batch = jax.sharding.set_mesh(mesh), 128
+
+        def placed(name, a):
+            spec = P("dp") if name == "x" else \
+                rule(name, a) if name else P()
+            return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                        sharding=NamedSharding(mesh, spec))
+    else:
+        scope, batch = contextlib.nullcontext(), 32
+
+        def placed(name, a):
+            return jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=SingleDeviceSharding(devices[0]))
 
     was = jax.config.jax_default_prng_impl
     jax.config.update("jax_default_prng_impl", "rbg")
     try:
-        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
-            jax.tree.map(on_chip, params),
-            on_chip(jax.ShapeDtypeStruct((32, 512, 768), jnp.bfloat16)),
-            on_chip(jax.eval_shape(lambda: jax.random.key(0)))
-        ).compile().as_text()
+        with mock.patch.object(kernels, "_on_tpu", lambda: True), scope:
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+                {k: placed(k, v) for k, v in params.items()},
+                placed("x", jax.ShapeDtypeStruct((batch, 512, 768),
+                                                 jnp.bfloat16)),
+                placed(None, jax.eval_shape(lambda: jax.random.key(0)))
+            ).compile().as_text()
     finally:
         jax.config.update("jax_default_prng_impl", was)
+
+
+def _all_reduced(text):
+    """(result type, op_name) of every all-reduce in a compiled text."""
+    import re
+    return [(m.group(1), m.group(2)) for m in re.finditer(
+        r"^\s*(?:ROOT )?\S+ = (.*?) all-reduce(?:-start)?\(.*?"
+        r"op_name=\"([^\"]*)\"", text, flags=re.M)]
+
+
+def _assert_four_kernels(text):
+    # flash forward and backward and the two norms' forward
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    for kernel in ("flash_fwd", "flash_bwd", "layer_norm_fwd"):
+        assert kernel in text
+
+
+def test_encoder_layer_step_holds_no_random_words(chip):
+    """One BertEncoderLayer forward and backward at bert_base_s512's
+    shape: the keep-masks are hashed inside the fusions that use them,
+    so the only random bits the compiled program draws are the flash
+    kernel's one seed, and it writes no word per element."""
+    import math
+    import re
+    text = _encoder_layer_step_text(on_mesh=False)
     assert "flash_fwd" in text and "dropout_mask" in text
     drawn = re.findall(r"= (.*?) rng-bit-generator\(", text)
     assert len(drawn) == 1, drawn       # attention dropout's seed
@@ -228,3 +277,31 @@ def test_encoder_layer_step_holds_no_random_words(chip, monkeypatch):
     wide = [r for r in results
             if re.search(r"[us]32\[32,512,(3072|768)\]", r)]
     assert not wide, wide
+
+
+def test_encoder_layer_on_dp2mp2_exchanges_four_activations(chip):
+    """Megatron's count: an activation crosses the mp link after the
+    attention output projection and after FFN-out going forward, and
+    for the input gradient of FFN-in and of q/k/v (once, for the sum of
+    the three) going back. The parent read 8 here: a ``psum`` from
+    transposing the norm kernel's shard_map at each norm, and the three
+    q/k/v input gradients reduced one by one."""
+    text = _encoder_layer_step_text(on_mesh=True)
+    reduced = _all_reduced(text)
+    # a tuple all-reduce counts once for each 50 MB element
+    stream = [(kind, op) for kind, op in reduced
+              for _ in range(kind.count("bf16[64,512,768]"))]
+    assert len(stream) == 4, reduced
+    assert not [op for _, op in reduced if "shard_map/psum" in op], reduced
+    assert sorted(op.split("/")[1] for _, op in stream) == [
+        "jvp(pt.attn)", "jvp(pt.ffn)", "transpose(jvp(pt.attn))",
+        "transpose(jvp(pt.ffn))"], stream
+    _assert_four_kernels(text)      # each on its shard still
+
+
+def test_encoder_layer_on_one_chip_has_its_kernels_and_no_exchange(chip):
+    """With no mesh in scope nothing of the mesh path shows: the
+    kernels are called directly and nothing is reduced."""
+    text = _encoder_layer_step_text(on_mesh=False)
+    assert "all-reduce" not in text
+    _assert_four_kernels(text)
