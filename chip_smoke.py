@@ -39,7 +39,7 @@ from dataclasses import dataclass, field
 
 NEW_TOKENS = 16
 PROMPT_LENS = (8, 48, 8, 48)
-# bench.py's BERT recipe is AdamW(1e-4) with no warm-up. At full width
+# The usual BERT recipe is AdamW(1e-4). With no warm-up and at full width
 # that recipe's first steps DIVERGE on one fixed batch (PR 22: 11.2 ->
 # 12.8 -> 16.8 on the chip, 11.2 -> 14.9 on the CPU's XLA path, so it is
 # the optimizer and not a kernel): every weight moves 1e-4 along its
@@ -87,7 +87,7 @@ def require(cond: bool, what: str) -> None:
 
 
 def _bert_and_data(sizes: Sizes, seed: int):
-    """(build, data): ``build()`` makes the flagship as bench.py does —
+    """(build, data): ``build()`` makes the flagship —
     bf16 BERT-base for pretraining + AdamW, but see LEARNING_RATE — from
     the seed, so two calls give identical weights; data is one fixed
     batch."""

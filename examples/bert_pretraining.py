@@ -9,8 +9,9 @@ two ways to run the step:
 - a mesh: ``parallel.ShardedTrainStep`` (same call, batch sharded over
   dp, megatron rules optional for mp)
 
-On a v5e this is the exact configuration ``bench.py`` times; on CPU it
-runs a tiny config for the smoke test. bf16 parameters with fp32
+On a v5e this is the model the ``bert_base_s512`` cells of
+``benchmarks/run.py`` time; on CPU it runs a tiny config for the smoke
+test. bf16 parameters with fp32
 LN/softmax/loss reductions, per-leaf AdamW.
 """
 

@@ -137,53 +137,22 @@ define_flag("matmul_precision", "default",
             "jax matmul precision: default | float32 | tensorfloat32 | "
             "highest. bf16 MXU passes use 'default'.")
 define_flag("use_pallas_kernels", True,
-            "Route hot ops (attention, layer_norm, adam) through Pallas "
+            "Route hot ops (attention, layer_norm) through Pallas "
             "kernels when on TPU (master switch; per-kernel flags "
             "below). [structural] The switch itself only enables "
             "routing; each routed kernel carries its own evidence "
             "class on its own flag.")
-define_flag("optimizer_fused_state", False,
-            "Pack optimizer state (m/v/master) into flat fp32 vectors: "
-            "one elementwise update over 3 buffers instead of 3 buffers "
-            "PER parameter (~600 for BERT-base). [measured] A REGRESSION "
-            "on real v5e (round 3): BERT-base b32xs512 97.1k tok/s "
-            "per-leaf vs 77.1k fused (per-leaf +26%) — the in-graph pack/unpack "
-            "slices cost more than the dispatch copies they save, and "
-            "steps-per-loop measured per-dispatch overhead at ~0 anyway. "
-            "Stays available for runtimes where per-buffer dispatch IS "
-            "the bottleneck; Lamb/Lars and RowSlices-sparse paths always "
-            "stay per-leaf. (ref capability: merged/multi-tensor "
-            "optimizers, incubate multi_tensor_apply.)")
 define_flag("optimizer_moment_dtype", "float32",
             "Storage dtype for Adam-family first/second moments "
             "(float32 | bfloat16). [assumed — conservative] fp32 is "
-            "the safe default; the bf16 win is a hypothesis whose "
-            "bert_b8_bf16mv capture stage is queued. bfloat16 halves "
+            "the safe default; the bf16 win is a hypothesis no chip "
+            "run has tested. bfloat16 halves "
             "optimizer-state HBM "
             "traffic (~1.3 GB/step on BERT-base); update math still "
             "runs in fp32 and the fp32 master weights are unaffected, "
             "so the only loss is ~0.4% relative rounding on stored "
             "m/v. Read at optimizer init. (ref capability: "
             "multi_precision / master-weight family.)")
-define_flag("use_pallas_adam", False,
-            "Use the Pallas fused-adam kernel. [measured] Off: on "
-            "v5e the flatten/unflatten layout copies it forces on 2-D "
-            "params cost more than the fusion saves (XLA fuses the "
-            "elementwise adam chain itself; 34.4 vs 39.6 ms/step on "
-            "BERT-base b8xs512). Useful again only if params are kept in "
-            "a 1-D flat buffer.")
-define_flag("fused_adam", False,
-            "Route Adam/AdamW moment+param updates through the "
-            "layout-preserving Pallas fused-adam kernel "
-            "(kernels.fused_adam.fused_adam_leaf): one VMEM-resident "
-            "elementwise pass over p/g/m/v per leaf, bitwise-identical "
-            "to the unfused update (same op order, no reciprocal "
-            "rewrite) including under the skip-step guard and "
-            "GradScaler. Unlike FLAGS_use_pallas_adam it keeps each "
-            "leaf's native 2-D layout (no ravel copies — the measured "
-            "regression that keeps use_pallas_adam off). [assumed — "
-            "conservative] Off until the bert_b16_fusedloss_fusedadam "
-            "capture stage lands chip evidence.")
 define_flag("fused_softmax_xent", False,
             "Fuse BERT's masked-LM head (hidden->vocab projection) "
             "with its softmax cross-entropy into one Pallas loss-"
@@ -191,23 +160,14 @@ define_flag("fused_softmax_xent", False,
             "log-sum-exp over vocab chunks, so the [B, T, V] logits "
             "tensor never exists in HBM in either direction "
             "(custom_vjp backward recomputes chunks and fuses dlogits "
-            "into dh/dW/db). [assumed — conservative] Off until the "
-            "bert_b16_fusedloss capture stage lands chip evidence.")
+            "into dh/dW/db). [assumed — conservative] Off: it has "
+            "never run on a chip.")
 define_flag("use_pallas_layer_norm", True,
             "Use the Pallas layer_norm kernel (subject to the master "
             "switch). [measured] r5 chip A/B at the best BERT config "
             "(bert_b8_spl8_xlaln pair): Pallas LN 129.3k vs XLA LN "
             "128.9k tok/s (+0.3%, within noise) — kept on; the XLA "
             "fallback is one flag away.")
-define_flag("fused_qkv_projection", False,
-            "Compute self-attention q/k/v as one [d, 3d] matmul via "
-            "trace-time weight concat (checkpoint layout unchanged). "
-            "[measured] The only chip measurement (round 2) said -3%; "
-            "default follows it. The round-3 HLO count (fewer dots/"
-            "transposes) argued for on, but HLO structure has "
-            "mispredicted the chip twice (docs/performance.md), so the "
-            "default stays with the last measurement until the "
-            "bert_b8_perleaf_{qkv,noqkv} capture pair remeasures it.")
 define_flag("flash_attention_min_seq", 8192,
             "Key-sequence length at or above which EVAL attention "
             "routes to the Pallas flash kernel. [measured+structural] "
@@ -230,16 +190,6 @@ define_flag("flash_attention_min_seq_train", 512,
             "gate sits at the lowest measured win. The memory argument "
             "(XLA backward re-materializes [B, H, T, T] fp32 probs, "
             "~6.4 GB at B8 T4096) independently caps the XLA path.")
-define_flag("attention_bthd_layout", True,
-            "MultiHeadAttention hands q/k/v to the flash kernel in "
-            "their native [B, T, H, D] projection layout (the kernel "
-            "gathers heads inside its block DMA) instead of physically "
-            "transposing to [B, H, T, D]. [measured] r5 chip A/B "
-            "(bert_b8_flash_bthd 127.5k vs bert_b8_flash512 127.2k "
-            "tok/s): throughput-neutral — the default is on for the "
-            "simpler graph (data-formatting ops 1.72 -> 0.19 ms/step "
-            "in the profile). Off restores the transpose layout (the "
-            "A/B partner and the fallback if a geometry misbehaves).")
 define_flag("flash_block_q", 0,
             "Flash kernel query-tile size (rows of the online-softmax "
             "block). 0 = the kernel module's built-in BLOCK_Q (512, "
@@ -623,8 +573,8 @@ define_flag("kv_prefix_sharing", False,
             "(kv_cow_copies_total), and free() only returns "
             "refcount-0 blocks. The admission watermark projects "
             "post-sharing demand, so shared-prefix floods admit ~N "
-            "times more streams. Off [assumed] pending chip capture "
-            "(bench.py llm_prefix_reuse).")
+            "times more streams. Off [assumed]: serving has not been "
+            "measured on a chip.")
 define_flag("prefill_chunk_tokens", 0,
             "LLM serving (serving_llm): chunked prefill. When > 0, "
             "prefill runs in chunks of this many tokens (floored to "
@@ -634,8 +584,8 @@ define_flag("prefill_chunk_tokens", 0,
             "joins the decode batch only when its last chunk lands; "
             "preempting it mid-prefill resets to its last shared or "
             "cached block. 0 (default) prefills whole prompts in one "
-            "step — 0 [assumed] pending chip capture (bench.py "
-            "llm_mixed_prefill; ~256 is the expected setting). Read "
+            "step — 0 [assumed]: serving has not been measured on a "
+            "chip (~256 is the expected setting). Read "
             "every step, so it can be retuned on a live server.")
 define_flag("llm_stall_factor", 10.0,
             "LLM engine stall watchdog: an engine step (or the gap "
@@ -658,8 +608,8 @@ define_flag("speculative_k", 0,
             "written past the accepted point is rolled back via the "
             "allocator's truncate_to (llm_spec_*_tokens_total, "
             "llm_spec_accept_rate, llm_spec_verify_ms). 0 (default) "
-            "disables — 0 [assumed] pending chip capture (bench.py "
-            "llm_spec_decode). Read every step, so it can be retuned "
+            "disables — 0 [assumed]: serving has not been measured on "
+            "a chip. Read every step, so it can be retuned "
             "on a live server.")
 define_flag("speculative_draft_layers", 1,
             "LLM serving (serving_llm): transformer layers of the "

@@ -2,12 +2,12 @@
 
 TPU-native replacement for the reference's hand-written CUDA kernels
 (/root/reference/paddle/fluid/operators/fused/: multihead_matmul_op.cu,
-fused_fc_elementwise_layernorm_op.cu; operators/math/bert_encoder_functor.cu;
-operators/optimizers/adam_op.h). Routing policy: each ``maybe_*`` entry point
+fused_fc_elementwise_layernorm_op.cu; operators/math/bert_encoder_functor.cu).
+Routing policy: each ``maybe_*`` entry point
 checks the ``use_pallas_kernels`` flag and the backend, and falls back to the
 pure-XLA composition in ops/ — so CPU tests and TPU production share one
 call site. Kernels themselves live in sibling modules (flash_attention,
-layer_norm, fused_adam).
+layer_norm, fused_softmax_xent, paged_attention).
 """
 
 from __future__ import annotations

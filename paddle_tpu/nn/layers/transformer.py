@@ -96,48 +96,17 @@ class MultiHeadAttention(Layer):
         return jnp.moveaxis(
             x.reshape(b, t, self.num_heads, self.head_dim), 2, 1)
 
-    def _qkv_self(self, x):
-        """Self-attention projections as ONE [d, 3d] matmul: the q/k/v
-        weights are concatenated at trace time (XLA folds the concat of
-        constants-at-step-scope into the dot operand), so the MXU sees a
-        single large GEMM instead of three d×d ones — the same shape the
-        reference's fused multihead_matmul_op.cu feeds cuBLAS. Parameter
-        structure (q_proj/k_proj/v_proj) and checkpoints are unchanged;
-        per-column math is identical (test_fused_qkv)."""
-        w = jnp.concatenate([self.q_proj.weight, self.k_proj.weight,
-                             self.v_proj.weight], axis=1)
-        biases = [self.q_proj.bias, self.k_proj.bias, self.v_proj.bias]
-        b = jnp.concatenate(biases) if all(
-            bb is not None for bb in biases) else None
-        qkv = F.linear(x, w, b)
-        return jnp.split(qkv, 3, axis=-1)
-
     def forward(self, query, key=None, value=None, attn_mask=None,
                 causal: bool = False):
-        # Layout note: the main path hands the projections to attention
-        # in their NATIVE [B, T, H, D] layout (layout="bthd") — the
-        # flash kernel gathers heads inside its block DMA, so the
-        # routed path runs zero physical head transposes (the r5 BERT
-        # b8 profile measured ~2.2 ms/step of transpose_jvp around
-        # attention). The XLA fallback transposes to BHTD internally,
-        # costing exactly what the old caller-side split did. An
-        # earlier transpose-free attempt (ops.attention.attention_bthd)
-        # targeted the XLA composition, where dot_general re-transposes
-        # anyway — that objection does not apply to the Pallas path.
-        from ...flags import GLOBAL_FLAGS
+        # Layout: the projections go to attention in their NATIVE
+        # [B, T, H, D] layout (layout="bthd") — the flash kernel gathers
+        # heads inside its block DMA, so the routed path runs no
+        # physical head transpose. The XLA fallback transposes to BHTD
+        # internally.
         self_attention = key is None and value is None
-        fusable = (GLOBAL_FLAGS.get("fused_qkv_projection")
-                   and self_attention
-                   and self.q_proj.in_features == self.k_proj.in_features
-                   == self.v_proj.in_features
-                   and ((self.q_proj.bias is None)
-                        == (self.k_proj.bias is None)
-                        == (self.v_proj.bias is None)))
         key = query if key is None else key
         value = key if value is None else value
-        if fusable:
-            qp, kp, vp = self._qkv_self(query)
-        elif self_attention and not self.need_weights:
+        if self_attention and not self.need_weights:
             qp, kp, vp = _self_attention_projections(
                 self.q_proj, self.k_proj, self.v_proj, query)
         else:
@@ -159,15 +128,6 @@ class MultiHeadAttention(Layer):
             out = self.out_proj(out)
             return out, weights
         from ...kernels import maybe_flash_attention
-        if not GLOBAL_FLAGS.get("attention_bthd_layout"):
-            # transpose layout (the measured A/B partner + escape hatch)
-            out = maybe_flash_attention(
-                self._split(qp), self._split(kp), self._split(vp),
-                mask=attn_mask, causal=causal, dropout_p=self.dropout,
-                training=self.training)
-            b, h, t, d = out.shape
-            return self.out_proj(
-                jnp.moveaxis(out, 1, 2).reshape(b, t, h * d))
 
         def heads(x):
             b_, t_, _ = x.shape

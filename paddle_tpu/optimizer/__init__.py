@@ -66,15 +66,6 @@ def _as_f32(x):
     return x
 
 
-def _fused_eligible(p) -> bool:
-    """Leaves that can join the flat fused-state pack: dense floating
-    arrays (RowSlices params/ints stay on the per-leaf path)."""
-    if isinstance(p, RowSlices):
-        return False
-    dt = getattr(p, "dtype", None)
-    return dt is not None and jnp.issubdtype(dt, jnp.floating)
-
-
 class Optimizer:
     """Base optimizer.
 
@@ -87,17 +78,9 @@ class Optimizer:
       opt.step(grads)  # or attach via set_grads then step()
     """
 
-    # Optimizers whose update() is purely elementwise can run the fused
-    # flat-state path (flags.optimizer_fused_state): m/v/master packed
-    # into ONE fp32 vector each, collapsing ~3 runtime buffers per
-    # parameter into 3 total. Lamb/Lars need per-parameter norms and
-    # stay per-leaf.
-    _elementwise_update = False
-
     def __init__(self, learning_rate=0.001, parameters=None,
                  weight_decay: Optional[float] = None, grad_clip=None,
                  name: Optional[str] = None,
-                 fused_state: Optional[bool] = None,
                  regularization=None) -> None:
         self.learning_rate = learning_rate
         self._parameter_list = list(parameters) if parameters else None
@@ -108,7 +91,6 @@ class Optimizer:
             weight_decay = regularization
         self.weight_decay = weight_decay
         self.grad_clip = grad_clip
-        self._fused_state = fused_state
         self._eager_state = None
         # per-parameter ParamAttr metadata (set_param_meta): {name:
         # (need_clip, regularizer)}; consumed when grads/params are
@@ -146,14 +128,6 @@ class Optimizer:
             return wd(p32, g)
         return g + wd * p32
 
-    def _use_fused(self) -> bool:
-        if not self._elementwise_update:
-            return False
-        if self._fused_state is not None:
-            return bool(self._fused_state)
-        from ..flags import GLOBAL_FLAGS
-        return bool(GLOBAL_FLAGS.get("optimizer_fused_state"))
-
     # ------------------------------------------------------------------
     # functional API
     # ------------------------------------------------------------------
@@ -176,23 +150,6 @@ class Optimizer:
             if getattr(p, "dtype", None) in (jnp.bfloat16, jnp.float16):
                 slots["master"] = jnp.asarray(p, jnp.float32)
             return slots
-
-        if self._use_fused():
-            # Fused flat state: ONE fp32 master + one buffer per slot
-            # kind for ALL eligible leaves (offsets are recomputed from
-            # the params structure at apply time — pure trace-time
-            # Python). Non-eligible leaves keep per-leaf slots.
-            flat_p = jax.tree.flatten(
-                params, is_leaf=lambda x: isinstance(x, RowSlices))[0]
-            elig = [p for p in flat_p if _fused_eligible(p)]
-            master = jnp.concatenate(
-                [jnp.asarray(p, jnp.float32).reshape(-1) for p in elig]) \
-                if elig else jnp.zeros((0,), jnp.float32)
-            fused = dict(self.init_slots(master), master=master)
-            slots = _tree_map(
-                lambda p: {} if _fused_eligible(p) else mk(p), params)
-            return {"step": jnp.zeros((), jnp.int32), "slots": slots,
-                    "fused": fused}
 
         slots = _tree_map(mk, params)
         return {"step": jnp.zeros((), jnp.int32), "slots": slots}
@@ -268,18 +225,6 @@ class Optimizer:
             else:
                 flat_g = treedef.flatten_up_to(self.grad_clip(grads))
 
-        if "fused" in state:
-            if any(r is not None for r in regs):
-                raise ValueError(
-                    "per-parameter regularizers are not supported with "
-                    "optimizer_fused_state; set fused_state=False")
-            if getattr(self, "apply_decay_param_fun", None) is not None:
-                raise ValueError(
-                    "apply_decay_param_fun needs per-parameter updates; "
-                    "set fused_state=False")
-            return self._apply_fused(flat_p, flat_g, flat_s, treedef,
-                                     state, lr_t, step)
-
         new_p, new_s = [], []
         for p, g, s, r, n in zip(flat_p, flat_g, flat_s, regs, names):
             np_, ns_ = self._update_leaf(p, g, s, lr_t, step, reg=r,
@@ -290,8 +235,7 @@ class Optimizer:
                 {"step": step, "slots": jax.tree.unflatten(treedef, new_s)})
 
     def _update_leaf(self, p, g, s, lr_t, step, reg=None, name=None):
-        """One per-leaf update (shared by the per-leaf and fused paths'
-        non-eligible branch): fp32 master handling, RowSlices dispatch,
+        """One leaf's update: fp32 master handling, RowSlices dispatch,
         decay, cast back to the param dtype."""
         if g is None:
             return p, s
@@ -312,89 +256,6 @@ class Optimizer:
         if out_dtype is not None and np_.dtype != out_dtype:
             np_ = np_.astype(out_dtype)
         return np_, ns_
-
-    def _apply_fused(self, flat_p, flat_g, flat_s, treedef, state,
-                     lr_t, step):
-        """Flat fused-state update: eligible leaves update as slices of
-        ONE fp32 master vector (concat grads -> one elementwise update
-        -> split/cast back). Trades two large contiguous copies for the
-        per-leaf buffer traffic of ~3 runtime buffers per parameter —
-        the reference's fused multi-tensor optimizer capability
-        (ref: incubate multi_tensor_apply / merged_adam direction).
-        None-grad (frozen) leaves are masked to exact no-ops; RowSlices
-        grads densify on this path (the per-leaf path keeps them
-        sparse — pick per leaf structure, not per batch)."""
-        elig = [_fused_eligible(p) for p in flat_p]
-        master = state["fused"]["master"]
-
-        g_parts, mask_parts, any_none = [], [], False
-        decay_parts, any_sparse = [], False
-        for p, g, e in zip(flat_p, flat_g, elig):
-            if not e:
-                continue
-            n = int(jnp.size(p))
-            if g is None:
-                any_none = True
-                g_parts.append(jnp.zeros((n,), jnp.float32))
-                mask_parts.append(jnp.zeros((n,), jnp.float32))
-                decay_parts.append(jnp.zeros((n,), jnp.float32))
-            elif isinstance(g, RowSlices):
-                # densified for the flat update, but the per-leaf path's
-                # update_sparse never applies weight decay to sparse
-                # grads — keep that contract here too
-                any_sparse = True
-                g_parts.append(to_dense(g).reshape(-1)
-                               .astype(jnp.float32))
-                mask_parts.append(jnp.ones((n,), jnp.float32))
-                decay_parts.append(jnp.zeros((n,), jnp.float32))
-            else:
-                g_parts.append(g.reshape(-1).astype(jnp.float32))
-                mask_parts.append(jnp.ones((n,), jnp.float32))
-                decay_parts.append(jnp.ones((n,), jnp.float32))
-        gflat = jnp.concatenate(g_parts) if g_parts else \
-            jnp.zeros((0,), jnp.float32)
-        mask_flat = jnp.concatenate(mask_parts) if any_none else None
-        if self.weight_decay:
-            decay = master if not any_sparse else \
-                master * jnp.concatenate(decay_parts)
-            gflat = self._decay_grad(gflat, decay)
-        if mask_flat is not None:
-            # after decay: a frozen leaf must be an exact no-op, decay
-            # included
-            gflat = gflat * mask_flat
-
-        s_upd = {k: v for k, v in state["fused"].items() if k != "master"}
-        new_master, ns_fused = self.update(master, gflat, s_upd, lr_t,
-                                           step)
-        if mask_flat is not None:
-            # a zeroed grad is NOT enough for a frozen leaf: decoupled
-            # decay (AdamW) moves the param with g=0, and moment slots
-            # decay by beta — pin BOTH so fused == per-leaf (which skips
-            # frozen leaves entirely)
-            frozen = mask_flat <= 0
-            new_master = jnp.where(frozen, master, new_master)
-            ns_fused = {
-                k: jnp.where(frozen, state["fused"][k], v)
-                if hasattr(v, "shape") and v.shape == master.shape else v
-                for k, v in ns_fused.items()}
-        ns_fused = dict(ns_fused, master=new_master)
-
-        new_p, new_s = [], []
-        off = 0
-        for p, g, s, e in zip(flat_p, flat_g, flat_s, elig):
-            if e:
-                n = int(jnp.size(p))
-                sl = new_master[off:off + n]  # static offsets: plain slice
-                new_p.append(sl.reshape(jnp.shape(p)).astype(p.dtype))
-                new_s.append(s)
-                off += n
-            else:
-                np_, ns_ = self._update_leaf(p, g, s, lr_t, step)
-                new_p.append(np_)
-                new_s.append(ns_)
-        return (jax.tree.unflatten(treedef, new_p),
-                {"step": step, "slots": jax.tree.unflatten(treedef, new_s),
-                 "fused": ns_fused})
 
     def update(self, p, g, slots, lr_t, step):
         raise NotImplementedError
@@ -477,7 +338,6 @@ class Optimizer:
 
 class SGD(Optimizer):
     """(ref: sgd_op.cc)."""
-    _elementwise_update = True
 
     def update(self, p, g, slots, lr_t, step):
         return p - lr_t * g.astype(p.dtype), slots
@@ -489,7 +349,6 @@ class SGD(Optimizer):
 
 class Momentum(Optimizer):
     """(ref: momentum_op.cc; use_nesterov attr)."""
-    _elementwise_update = True
 
     def __init__(self, learning_rate=0.001, momentum: float = 0.9,
                  use_nesterov: bool = False, **kw) -> None:
@@ -539,7 +398,6 @@ class LarsMomentum(Optimizer):
 
 class Adam(Optimizer):
     """(ref: adam_op.h AdamFunctor)."""
-    _elementwise_update = True
 
     def __init__(self, learning_rate=0.001, beta1: float = 0.9,
                  beta2: float = 0.999, epsilon: float = 1e-8,
@@ -562,32 +420,6 @@ class Adam(Optimizer):
 
     def update(self, p, g, slots, lr_t, step):
         g = g.astype(p.dtype)
-        from ..flags import GLOBAL_FLAGS
-        from ..kernels import pallas_enabled
-        if (pallas_enabled() and GLOBAL_FLAGS.get("fused_adam")
-                and p.dtype == jnp.float32
-                and slots["m"].dtype == jnp.float32
-                and slots["v"].dtype == jnp.float32):
-            # layout-preserving fused kernel; bitwise-identical to the
-            # unfused expression below (takes precedence over the
-            # ravel-based use_pallas_adam path)
-            from ..kernels.fused_adam import fused_adam_leaf
-            lr_c = self._bias_correct_lr(lr_t, step)
-            p_new, m, v = fused_adam_leaf(
-                p, g, slots["m"], slots["v"], lr_c, self.beta1,
-                self.beta2, self.epsilon)
-            return p_new, {"m": m, "v": v}
-        if (pallas_enabled() and GLOBAL_FLAGS.get("use_pallas_adam")
-                and p.dtype == jnp.float32
-                and slots["m"].dtype == jnp.float32 and p.size >= 1024):
-            from ..kernels.fused_adam import fused_adam_flat
-            lr_c = self._bias_correct_lr(lr_t, step)
-            p_new, m, v = fused_adam_flat(
-                p.ravel(), g.ravel(), slots["m"].ravel(),
-                slots["v"].ravel(), lr_c, self.beta1, self.beta2,
-                self.epsilon)
-            return (p_new.reshape(p.shape),
-                    {"m": m.reshape(p.shape), "v": v.reshape(p.shape)})
         # moments may be STORED low-precision (FLAGS_optimizer_moment_
         # dtype): math always runs fp32, storage casts back
         m_dt, v_dt = slots["m"].dtype, slots["v"].dtype
@@ -661,7 +493,6 @@ class AdamW(Adam):
 
 class Adamax(Optimizer):
     """(ref: adamax_op.cc)."""
-    _elementwise_update = True
 
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999,
                  epsilon=1e-8, **kw) -> None:
@@ -674,15 +505,16 @@ class Adamax(Optimizer):
     def update(self, p, g, slots, lr_t, step):
         g = g.astype(p.dtype)
         m = self.beta1 * slots["m"] + (1 - self.beta1) * g
-        u = jnp.maximum(self.beta2 * slots["u"], jnp.abs(g))
+        # the operator keeps epsilon inside the running maximum, so the
+        # quotient needs none
+        u = jnp.maximum(self.beta2 * slots["u"] + self.epsilon, jnp.abs(g))
         step_f = step.astype(jnp.float32)
         lr_c = lr_t / (1.0 - jnp.power(self.beta1, step_f))
-        return p - lr_c * m / (u + self.epsilon), {"m": m, "u": u}
+        return p - lr_c * m / u, {"m": m, "u": u}
 
 
 class Adagrad(Optimizer):
     """(ref: adagrad_op.cc)."""
-    _elementwise_update = True
 
     def __init__(self, learning_rate=0.001, epsilon: float = 1e-6,
                  initial_accumulator_value: float = 0.0, **kw) -> None:
@@ -702,7 +534,6 @@ class Adagrad(Optimizer):
 
 class Adadelta(Optimizer):
     """(ref: adadelta_op.cc)."""
-    _elementwise_update = True
 
     def __init__(self, learning_rate=1.0, rho: float = 0.95,
                  epsilon: float = 1e-6, **kw) -> None:
@@ -725,7 +556,6 @@ class Adadelta(Optimizer):
 
 class RMSProp(Optimizer):
     """(ref: rmsprop_op.cc; centered variant supported)."""
-    _elementwise_update = True
 
     def __init__(self, learning_rate=0.001, rho: float = 0.95,
                  epsilon: float = 1e-6, momentum: float = 0.0,
@@ -913,9 +743,9 @@ class ProximalAdagrad(Optimizer):
     def update(self, p, g, slots, lr_t, step):
         g = g.astype(p.dtype)
         moment = slots["moment"] + jnp.square(g)
-        adapted_lr = lr_t / (jnp.sqrt(moment) + self.epsilon)
-        prox = p - adapted_lr * g
+        # the gradient step takes the adapted rate, the shrinkage the
+        # plain one (the operator's rule)
+        prox = p - lr_t * g / (jnp.sqrt(moment) + self.epsilon)
         new_p = jnp.sign(prox) * jnp.maximum(
-            jnp.abs(prox) - adapted_lr * self.l1, 0.0) \
-            / (1.0 + adapted_lr * self.l2)
+            jnp.abs(prox) - lr_t * self.l1, 0.0) / (1.0 + lr_t * self.l2)
         return new_p, {"moment": moment}

@@ -51,11 +51,6 @@ class LocalSGDStep:
         params = model.param_dict()
         buffers = model.buffer_dict()
         opt_state = optimizer.init(params)
-        if "fused" in opt_state:
-            raise ValueError(
-                "optimizer_fused_state is incompatible with LocalSGD's "
-                "replica-stacked optimizer state; construct the "
-                "optimizer with fused_state=False")
 
         def stack(tree):
             return jax.tree.map(

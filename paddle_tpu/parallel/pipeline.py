@@ -133,11 +133,6 @@ class GPipeTrainStep:
             "head": head.param_dict(),
         }
         opt_state = optimizer.init(params)
-        if "fused" in opt_state:
-            raise ValueError(
-                "optimizer_fused_state is incompatible with pipeline "
-                "stage-stacked optimizer state; construct the optimizer "
-                "with fused_state=False")
         stage_spec = jax.tree.map(lambda _: P(axis), params["stages"])
         self.param_specs = {
             "embed": jax.tree.map(lambda _: P(), params["embed"]),

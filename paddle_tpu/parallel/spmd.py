@@ -258,23 +258,6 @@ class ShardedTrainStep:
                 if hasattr(x, "ndim") and x.ndim > 0 else P(), s)
                       for n, s in opt_state["slots"].items()},
         }
-        if "fused" in opt_state:
-            # flat fused optimizer state is replicated; it only makes
-            # sense when the params themselves are replicated — with
-            # ZeRO/TP the flat vector would force all-gathers of every
-            # grad and un-shard the slot memory
-            sharded_params = [n for n, s in param_specs.items()
-                              if s != P()]
-            if zero_stage >= 1 or sharded_params:
-                raise ValueError(
-                    "optimizer_fused_state is incompatible with ZeRO "
-                    f"sharding / sharded params ({sharded_params[:3]}...)"
-                    if sharded_params else
-                    "optimizer_fused_state is incompatible with ZeRO "
-                    "slot sharding; construct the optimizer with "
-                    "fused_state=False for this strategy")
-            opt_specs["fused"] = jax.tree.map(lambda _: P(),
-                                              opt_state["fused"])
         self.state_specs = {
             "params": param_specs,
             "buffers": jax.tree.map(lambda _: P(), buffers),

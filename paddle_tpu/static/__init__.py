@@ -670,8 +670,8 @@ class TrainStep:
     def compiled_hlo(self, *args, labels=(), **kwargs) -> str:
         """Optimized-HLO text of the whole train step for these inputs
         (no execution; state is NOT consumed). Backs structural perf
-        analysis — tools/perf_lab.py hlostats counts copy/transpose
-        ops here before spending chip time."""
+        analysis: counting copy/transpose ops or collectives before
+        spending chip time."""
         batch = self._make_batch(args, labels, kwargs)
         return self._jitted.lower(self.state, batch).compile().as_text()
 

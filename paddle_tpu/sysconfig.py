@@ -50,8 +50,8 @@ _REPO_CACHE_DIR = os.path.join(
 def enable_compile_cache(cache_dir: str = None,
                          min_compile_secs: float = 0.5) -> None:
     """Enable JAX's persistent compilation cache. The ONE
-    implementation — bench.py, verify, conftest, chip_smoke and the
-    tools all call this, so the path and the min-compile threshold
+    implementation — verify, conftest, chip_smoke, the benchmark and
+    the tools all call this, so the path and the min-compile threshold
     can't drift between entry points. Safe to call repeatedly.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set the directory is the

@@ -1,14 +1,12 @@
-"""Standalone hardware verification, decoupled from bench timing.
+"""Standalone hardware verification, decoupled from any timing run.
 
-VERDICT r2 weak 6: the compiled-mode Pallas kernel checks used to live
-only inside ``bench.py``, so a bench-timing outage also lost the
-correctness evidence. This module is the single source for hardware
-verification — ``bench.py`` imports it, ``__graft_entry__.verify()``
-calls it, and ``run_verification`` writes its own JSON artifact
-(``VERIFY_TPU.json``) so a timing-less round still leaves a record.
+This module is the single source for hardware verification:
+``__graft_entry__.verify()`` calls it, and ``run_verification`` writes
+its own JSON artifact (``VERIFY_TPU.json``) so a run that measures
+nothing still leaves a record.
 
 Checks:
-- Pallas kernels (layer_norm, flash attention, fused adam) in compiled
+- Pallas kernels (layer_norm, flash attention) in compiled
   (non-interpret) mode against their XLA reference compositions —
   Mosaic layout bugs surface here mechanically instead of mid-training.
 - A 10-step training parity: the framework's ``TrainStep`` on the
@@ -191,31 +189,6 @@ def validate_kernels_on_tpu() -> list:
     except Exception as e:  # noqa: BLE001
         failures.append(f"flash_multiblock_bwd: {e}")
 
-    # fused adam vs elementwise composition
-    try:
-        from paddle_tpu.kernels.fused_adam import fused_adam_flat
-        n = 8192
-        p = jnp.asarray(rng.normal(0, 1, (n,)), jnp.float32)
-        g = jnp.asarray(rng.normal(0, 0.1, (n,)), jnp.float32)
-        m = jnp.asarray(rng.normal(0, 0.01, (n,)), jnp.float32)
-        v = jnp.abs(jnp.asarray(rng.normal(0, 0.01, (n,)), jnp.float32))
-        lr, b1, b2, eps = 1e-3, 0.9, 0.999, 1e-8
-        p2, m2, v2 = jax.jit(
-            lambda p, g, m, v: fused_adam_flat(p, g, m, v, lr, b1, b2, eps)
-        )(p, g, m, v)
-        m_ref = b1 * m + (1 - b1) * g
-        v_ref = b2 * v + (1 - b2) * g * g
-        p_ref = p - lr * m_ref / (jnp.sqrt(v_ref) + eps)
-        np.testing.assert_allclose(np.asarray(p2), np.asarray(p_ref),
-                                   rtol=1e-5, atol=1e-6)
-        np.testing.assert_allclose(np.asarray(m2), np.asarray(m_ref),
-                                   rtol=1e-5, atol=1e-6)
-        np.testing.assert_allclose(np.asarray(v2), np.asarray(v_ref),
-                                   rtol=1e-5, atol=1e-6)
-        _log("kernel-validate fused_adam: OK")
-    except Exception as e:  # noqa: BLE001
-        failures.append(f"fused_adam: {e}")
-
     for f in failures:
         _log(f"KERNEL VALIDATION FAILED: {f}")
     return failures
@@ -290,9 +263,9 @@ def train_parity_10steps() -> dict:
 
 def kernels_source_hash() -> str:
     """Stable hash of the Pallas kernel sources. Stamped into the
-    verification artifact so bench.py only trusts a cached "kernels ok"
-    verdict while the kernel code is byte-identical to what was
-    validated — any kernel edit invalidates the skip."""
+    verification artifact so a reader can tell whether a "kernels ok"
+    verdict was given for the kernel code it is looking at — any
+    kernel edit changes the hash."""
     import hashlib
     import os
 
@@ -309,8 +282,7 @@ def kernels_source_hash() -> str:
 
 def default_artifact_path() -> str:
     """Repo-root VERIFY_TPU.json — one canonical location regardless of
-    cwd, so a verify run from anywhere refreshes the same artifact
-    bench.py reads."""
+    cwd, so a verify run from anywhere refreshes the same artifact."""
     import os
 
     return os.path.join(os.path.dirname(os.path.dirname(
@@ -325,8 +297,7 @@ def run_verification(artifact_path: str | None = None) -> dict:
 
     import jax
 
-    # warm kernels cut the cost of a verify stage (the driver calls
-    # __graft_entry__.verify() directly, not via bench)
+    # warm kernels cut the cost of a verify stage
     from .sysconfig import enable_compile_cache
     enable_compile_cache()
 

@@ -171,18 +171,36 @@ def test_routed_kernels_run_per_shard_under_a_mesh(monkeypatch):
                                        rtol=2e-5, atol=2e-5)
 
 
+def _shard_map_bodies(jaxpr):
+    """Text of the body of every ``shard_map`` equation in a jaxpr,
+    those inside other equations' bodies included."""
+    import jax
+    bodies = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "shard_map":
+            bodies.append(str(eqn.params["jaxpr"]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            bodies += _shard_map_bodies(sub)
+    return bodies
+
+
+@pytest.mark.parametrize("kernel", ["layer_norm", "flash"])
 def test_grad_of_a_per_shard_kernel_sums_nothing_over_an_axis_it_is_whole_on(
-        monkeypatch):
+        monkeypatch, kernel):
     """Layer norm's rows are split over dp and whole over mp. Were the
     kernel differentiated inside its shard_map, the transpose would
     ``psum`` the rows' cotangent over mp: two identical halves added
     over the link, 50 MB a norm site at the four-chip cell's shape. The
     ``custom_vjp`` sits outside: the forward kernel is still per shard,
     the backward is plain XLA and the jaxpr holds no psum at all (the
-    dw/db sums over dp are the partitioner's)."""
+    dw/db sums over dp are the partitioner's). Flash attention is split
+    over both axes (batch over dp, heads over mp) and whole on none: its
+    forward and backward kernels each run inside a shard_map, and no
+    cotangent is summed over either axis."""
     import jax
     import jax.numpy as jnp
 
+    import paddle_tpu as pt
     from paddle_tpu import kernels
 
     _interpret_routed_kernels(monkeypatch, [])
@@ -191,11 +209,27 @@ def test_grad_of_a_per_shard_kernel_sums_nothing_over_an_axis_it_is_whole_on(
         out = kernels.maybe_layer_norm(x, w, b, 1e-5, 2)
         return jnp.sum(out * out)
 
-    with jax.sharding.set_mesh(_dp2mp2()):
-        text = str(jax.make_jaxpr(jax.grad(norm, argnums=(0, 1, 2)))(
-            jnp.ones((8, 16, 128)), jnp.ones((128,)), jnp.ones((128,))))
-    assert "shard_map" in text and "layer_norm_fwd" in text
-    assert "psum" not in text
+    def attn(q, k, v):
+        out = kernels.maybe_flash_attention(q, k, v, layout="bthd")
+        return jnp.sum(out * out)
+
+    if kernel == "layer_norm":
+        fn, inside = norm, ["layer_norm_fwd"]
+        args = (jnp.ones((8, 16, 128)), jnp.ones((128,)), jnp.ones((128,)))
+    else:
+        fn, inside = attn, ["flash_fwd", "flash_bwd"]
+        args = (jnp.ones((4, 64, 4, 128)),) * 3
+    saved = pt.get_flags(["flash_attention_min_seq"])
+    pt.set_flags({"flash_attention_min_seq": 64})
+    try:
+        with jax.sharding.set_mesh(_dp2mp2()):
+            closed = jax.make_jaxpr(jax.grad(fn, argnums=(0, 1, 2)))(*args)
+    finally:
+        pt.set_flags(saved)
+    bodies = _shard_map_bodies(closed.jaxpr)
+    for name in inside:
+        assert any(name in body for body in bodies), (name, len(bodies))
+    assert "psum" not in str(closed)
 
 
 def _two_encoder_layers():

@@ -218,151 +218,6 @@ class TestFlashAttention:
                 rtol=0.1, atol=0.1)
 
 
-class TestFusedAdam:
-    def test_matches_unfused(self, rng):
-        from paddle_tpu.kernels.fused_adam import fused_adam_flat
-
-        n = 1024
-        p = rng.standard_normal(n).astype(np.float32)
-        g = rng.standard_normal(n).astype(np.float32)
-        m = rng.standard_normal(n).astype(np.float32) * 0.1
-        v = np.abs(rng.standard_normal(n)).astype(np.float32) * 0.1
-        beta1, beta2, eps, lr = 0.9, 0.999, 1e-8, 1e-3
-        step_t = 5
-        lr_c = lr * np.sqrt(1 - beta2 ** step_t) / (1 - beta1 ** step_t)
-
-        m_ref = beta1 * m + (1 - beta1) * g
-        v_ref = beta2 * v + (1 - beta2) * g * g
-        p_ref = p - lr_c * m_ref / (np.sqrt(v_ref) + eps)
-
-        p_new, m_new, v_new = fused_adam_flat(
-            jnp.asarray(p), jnp.asarray(g), jnp.asarray(m), jnp.asarray(v),
-            lr_c, beta1, beta2, eps, interpret=True)
-        np.testing.assert_allclose(np.asarray(m_new), m_ref, rtol=1e-5,
-                                   atol=1e-7)
-        np.testing.assert_allclose(np.asarray(v_new), v_ref, rtol=1e-5,
-                                   atol=1e-7)
-        np.testing.assert_allclose(np.asarray(p_new), p_ref, rtol=1e-5,
-                                   atol=1e-6)
-
-    @pytest.mark.parametrize("shape", [(), (7,), (33, 130), (3, 5, 257),
-                                       (1024,)])
-    def test_leaf_bitwise_vs_jitted_unfused(self, rng, shape):
-        """fused_adam_leaf replicates the unfused expression op-for-op,
-        so under jit (the only way TrainStep ever runs it) the results
-        must be BITWISE identical — the FLAGS_fused_adam default flip
-        rides on exact parity, not tolerance."""
-        from paddle_tpu.kernels.fused_adam import fused_adam_leaf
-
-        p = rng.standard_normal(shape).astype(np.float32)
-        g = rng.standard_normal(shape).astype(np.float32)
-        m = (rng.standard_normal(shape) * 0.1).astype(np.float32)
-        v = np.abs(rng.standard_normal(shape)).astype(np.float32) * 0.1
-        beta1, beta2, eps = 0.9, 0.999, 1e-8
-        lr_c = np.float32(2.34e-3)
-
-        @jax.jit
-        def unfused(p, g, m, v):
-            m2 = beta1 * m + (1 - beta1) * g
-            v2 = beta2 * v + (1 - beta2) * jnp.square(g)
-            return p - lr_c * m2 / (jnp.sqrt(v2) + eps), m2, v2
-
-        fused = jax.jit(lambda p, g, m, v: fused_adam_leaf(
-            p, g, m, v, lr_c, beta1, beta2, eps, interpret=True))
-
-        args = tuple(jnp.asarray(a) for a in (p, g, m, v))
-        for got, ref, name in zip(fused(*args), unfused(*args),
-                                  ("p", "m", "v")):
-            assert got.shape == shape
-            np.testing.assert_array_equal(np.asarray(got),
-                                          np.asarray(ref), err_msg=name)
-
-
-class TestFusedAdamTrainStep:
-    """FLAGS_fused_adam through the real train program: a multi-step
-    fit must stay BITWISE identical to the unfused path — params, both
-    moments and the step counter — including a skipped non-finite step
-    and the GradScaler path."""
-
-    def _run(self, monkeypatch, fused, use_scaler=False, nan_step=None,
-             steps=10):
-        import paddle_tpu as pt
-        from paddle_tpu import kernels
-        from paddle_tpu.amp import GradScaler
-        from paddle_tpu.kernels import fused_adam as fa_mod
-        from paddle_tpu.static import TrainStep
-
-        if fused:
-            monkeypatch.setattr(kernels, "_on_tpu", lambda: True)
-            orig = fa_mod.fused_adam_leaf
-
-            def leaf(*a, **k):
-                k.pop("interpret", None)
-                return orig(*a, interpret=True, **k)
-
-            monkeypatch.setattr(fa_mod, "fused_adam_leaf", leaf)
-            pt.set_flags({"fused_adam": True})
-        try:
-            pt.seed(0)
-            model = pt.nn.Sequential(pt.nn.Linear(8, 16), pt.nn.ReLU(),
-                                     pt.nn.Linear(16, 4))
-            scaler = GradScaler(init_loss_scaling=256.0,
-                                decr_every_n_nan_or_inf=1) \
-                if use_scaler else None
-            step = TrainStep(model, pt.optimizer.Adam(
-                learning_rate=1e-3), pt.nn.CrossEntropyLoss(),
-                scaler=scaler)
-            data = np.random.default_rng(7)
-            xs = data.normal(size=(steps, 4, 8)).astype(np.float32)
-            ys = data.integers(0, 4, (steps, 4)).astype(np.int64)
-            for i in range(steps):
-                x = xs[i].copy()
-                if i == nan_step:
-                    x[0, 0] = np.inf  # poisons loss + grads this step
-                step(x, labels=(ys[i],))
-            out = {"params": step.state["params"],
-                   "opt": step.state["opt"]}
-            if use_scaler:
-                out["scaler"] = step.state["scaler"]
-            return jax.device_get(out)
-        finally:
-            if fused:
-                pt.set_flags({"fused_adam": False})
-                monkeypatch.undo()
-
-    def _assert_bitwise(self, a, b):
-        flat_a, tree_a = jax.tree_util.tree_flatten_with_path(a)
-        flat_b = jax.tree_util.tree_flatten(b)[0]
-        assert len(flat_a) == len(flat_b)
-        for (path, la), lb in zip(flat_a, flat_b):
-            np.testing.assert_array_equal(np.asarray(la),
-                                          np.asarray(lb),
-                                          err_msg=str(path))
-
-    def test_ten_steps_bitwise(self, monkeypatch):
-        base = self._run(monkeypatch, fused=False)
-        got = self._run(monkeypatch, fused=True)
-        self._assert_bitwise(got, base)
-
-    def test_skip_step_guard_bitwise(self, monkeypatch):
-        base = self._run(monkeypatch, fused=False, nan_step=4)
-        got = self._run(monkeypatch, fused=True, nan_step=4)
-        # the poisoned step was skipped in both paths: counter advanced
-        # only for the 9 clean steps
-        assert int(got["opt"]["step"]) == 9
-        self._assert_bitwise(got, base)
-
-    def test_grad_scaler_bitwise(self, monkeypatch):
-        base = self._run(monkeypatch, fused=False, use_scaler=True,
-                         nan_step=3)
-        got = self._run(monkeypatch, fused=True, use_scaler=True,
-                        nan_step=3)
-        # dynamic loss scaling reacted identically (one decrement)
-        assert float(got["scaler"]["scale"]) \
-            == float(base["scaler"]["scale"]) < 256.0
-        self._assert_bitwise(got, base)
-
-
 class TestFlashAttentionDropout:
     """In-kernel attention dropout: the keep mask is a pure hash of
     (seed, head, position), so the forward mask can be EXTRACTED by

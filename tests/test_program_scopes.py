@@ -292,12 +292,11 @@ def _flash_grad():
 
 def _kernel_wrappers():
     """(wrapper, arguments, names expected) for every kernel file."""
-    from paddle_tpu.kernels import (fused_adam, fused_softmax_xent,
-                                    layer_norm, paged_attention)
+    from paddle_tpu.kernels import (fused_softmax_xent, layer_norm,
+                                    paged_attention)
     f32 = jnp.float32
     x = jnp.ones((16, 128), f32)
     vec = jnp.ones((128,), f32)
-    flat = jnp.ones((1024,), f32)
     q = jnp.ones((1, 2, 16, 8), f32)
     _, flash = _flash_grad()
     pool = jnp.ones((4, 4, 2, 8), f32)
@@ -315,12 +314,6 @@ def _kernel_wrappers():
         (flash, (q, q, q), ["flash_fwd", "flash_bwd"]),
         (lambda a, w, b: layer_norm.layer_norm_pallas(
             a, w, b, interpret=True), (x, vec, vec), ["layer_norm_fwd"]),
-        (lambda p: fused_adam.fused_adam_leaf(
-            p, p, p, p, 0.1, 0.9, 0.999, 1e-8, interpret=True), (x,),
-         ["fused_adam_leaf"]),
-        (lambda p: fused_adam.fused_adam_flat(
-            p, p, p, p, 0.1, 0.9, 0.999, 1e-8, interpret=True), (flat,),
-         ["fused_adam_flat"]),
         (xent, (x, jnp.ones((256, 128), f32), jnp.zeros((256,), f32)),
          ["fused_xent_fwd", "fused_xent_bwd_dh", "fused_xent_bwd_dw"]),
         (lambda q1: paged_attention._paged_attention_impl(
@@ -348,7 +341,7 @@ def test_every_pallas_call_carries_its_own_name(monkeypatch):
     got = _pallas_names(flash, q, q, q)
     assert got == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
     seen += got[1:]
-    assert len(seen) == len(set(seen)) == 12
+    assert len(seen) == len(set(seen)) == 10
 
 
 def test_every_call_site_in_the_kernel_files_passes_a_name():
@@ -365,7 +358,7 @@ def test_every_call_site_in_the_kernel_files_passes_a_name():
             m = re.search(r'\bname="(\w+)"', text[at:upto])
             assert m, f"{f}: a pallas_call without name= at {at}"
             names.append(m.group(1))
-    assert len(names) == 12 and len(set(names)) == 12, names
+    assert len(names) == 10 and len(set(names)) == 10, names
 
 
 @pytest.mark.parametrize("b,h,t,d", [(2, 3, 16, 8), (1, 2, 32, 16)])

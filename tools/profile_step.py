@@ -75,8 +75,7 @@ def aggregate(outdir: str) -> None:
 
     traces = trace_agg.find_xla_traces(outdir)
     if not traces:
-        # a profiler stage with no trace produced no data — exit nonzero
-        # so capture_all records it not-ok and the watcher retries
+        # a profiler run with no trace produced no data — exit nonzero
         print(f"no trace.json.gz under {outdir}", file=sys.stderr)
         sys.exit(2)
     events = trace_agg.load_trace_events(traces[-1])
