@@ -87,6 +87,9 @@ class CausalLMOutput(NamedTuple):
     moe_pairs_dropped: jnp.ndarray
     # [expert layers, experts scored]: pairs sent to each expert
     moe_expert_load: jnp.ndarray
+    # trips of the expert layers' loops over windows (those that hold a
+    # held pair), forward; the backward makes as many
+    moe_windows_run: jnp.ndarray
 
     def logits(self):
         return jnp.matmul(self.hidden, self.head_weight)
@@ -169,6 +172,7 @@ class NemotronHForCausalLM(nn.Layer):
         remat = self.config.recompute == "layer" and self.training
         held = jnp.zeros((), jnp.int32)
         dropped = jnp.zeros((), jnp.int32)
+        windows_run = jnp.zeros((), jnp.int32)
         ratio = jnp.zeros((), jnp.float32)
         loads = []
         rate = self.config.router_bias_update_rate if self.training else 0
@@ -183,6 +187,7 @@ class NemotronHForCausalLM(nn.Layer):
                 continue
             held = held + stats["pairs_held"]
             dropped = dropped + stats["pairs_dropped"]
+            windows_run = windows_run + stats["windows_run"]
             ratio = jnp.maximum(ratio, stats["load_max_over_mean"])
             loads.append(stats["expert_load"])
             if rate:
@@ -196,7 +201,8 @@ class NemotronHForCausalLM(nn.Layer):
             x = self.norm_f(x)
         return CausalLMOutput(
             x, self.lm_head.weight, held, ratio, dropped,
-            jnp.stack(loads) if loads else jnp.zeros((0, 0), jnp.int32))
+            jnp.stack(loads) if loads else jnp.zeros((0, 0), jnp.int32),
+            windows_run)
 
 
 def _balanced(bias, load, rate):
@@ -289,4 +295,4 @@ def routing_metrics() -> Dict[str, Callable]:
     of a step, returned beside its loss."""
     return {name: (lambda out, *labels, _n=name: getattr(out, _n))
             for name in ("moe_pairs_held", "moe_load_max_over_mean",
-                         "moe_pairs_dropped")}
+                         "moe_pairs_dropped", "moe_windows_run")}

@@ -130,10 +130,11 @@ class MoELayer(Layer):
 # in windows of WINDOW_FACTOR times the balanced load. A window that
 # holds a held pair is computed whole (the rows past the last pair are
 # zeros in the last group), and one that starts past the last held pair
-# is skipped (a lax.cond on the step's own count): a step whose held
-# pairs fit one window takes the same time whatever the routing, the
-# worst routing runs every window, no pair is dropped either way, and
-# the buffers are one window's.
+# is never reached (the loop over windows is bounded by the step's own
+# count of held pairs, and an empty window costs nothing): a step whose
+# held pairs fit one window takes the same time whatever the routing,
+# the worst routing runs every window, no pair is dropped either way,
+# and the buffers are one window's.
 WINDOW_FACTOR = 2
 _ROW_TILE = 512
 
@@ -162,8 +163,12 @@ class DroplessMoE(Layer):
 
     Returns ``(out, stats)``; ``stats`` holds the scalars
     ``pairs_held`` (pairs on held experts in this call),
-    ``load_max_over_mean`` (the fullest held expert over their mean)
-    and ``pairs_dropped`` (held pairs no window covers: 0), and
+    ``load_max_over_mean`` (the fullest held expert over their mean),
+    ``pairs_dropped`` (held pairs no window covers: 0) and
+    ``windows_run`` (the windows that hold a held pair, which are the
+    trips of the loop over windows, forward and backward: ``ceil(
+    pairs_held / rows)``, 1 for a balanced layer, 0 when nothing is
+    held), and
     ``expert_load`` [num_experts], the pairs this call's tokens sent to
     each expert the router scores: what the aux-loss-free balancing
     rule reads to move ``e_score_correction_bias`` (the layer itself
@@ -217,10 +222,10 @@ class DroplessMoE(Layer):
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         return chosen, w * self.routed_scaling_factor
 
-    def _window(self, tokens, weights, w_in, w_out, order, ends, lo,
+    def _window(self, acc, tokens, weights, w_in, w_out, order, ends, lo,
                 rows: int):
-        """What the held pairs ``order[lo:lo + rows]`` add, [N, D]
-        float32. ``ends`` are the cumulative group sizes."""
+        """``acc`` [N, D] float32 and what the held pairs ``order[lo:lo +
+        rows]`` add to it. ``ends`` are the cumulative group sizes."""
         with jax.named_scope("pt.moe_route"):
             pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
             token_of = pair // self.top_k
@@ -242,51 +247,58 @@ class DroplessMoE(Layer):
             out = (jax.lax.ragged_dot(hidden, w_out, sizes)
                    * w[:, None].astype(hidden.dtype)).astype(jnp.float32)
         with jax.named_scope("pt.moe_route"):
-            return jnp.zeros(tokens.shape, jnp.float32).at[token_of].add(
-                out)
+            return acc.at[token_of].add(out)
 
-    def _routed(self, tokens, weights, order, ends, rows: int,
-                windows: int):
-        """The held experts' part, [N, D] float32: the sum of the
-        windows that hold a held pair. Differentiated by hand, a window
-        at a time: left to reverse-mode AD, the scan over windows keeps
-        a [N, D] residual for every window, run or not."""
-        def scan_windows(run, init, order, ends):
-            starts = jnp.arange(windows, dtype=jnp.int32) * rows
-
+    def _routed(self, tokens, weights, order, ends, rows: int, windows):
+        """The held experts' part, [N, D] float32: the sum of the first
+        ``windows`` windows of the sorted list (the step's own count of
+        those that hold a held pair). Differentiated by hand, a window
+        at a time: the trip count is the step's, which reverse-mode AD
+        cannot walk back, and a scan over every window keeps a [N, D]
+        residual for each, run or not."""
+        def loop_windows(add_window, init, trips):
             @jax.named_scope("pt.moe_route")    # the body's own stack
-            def step(acc, lo):
-                add = jax.lax.cond(lo < ends[-1], run,
-                                   lambda lo: init, lo)
-                return jax.tree.map(jnp.add, acc, add), None
-            return jax.lax.scan(step, init, starts)[0]
+            def body(i, acc):
+                return add_window(acc, i * rows)
+            return jax.lax.fori_loop(0, trips, body, init)
 
         @jax.custom_vjp
-        @jax.named_scope("pt.moe_route")    # the accumulators' zeros
-        def routed(diff, order, ends):
-            return scan_windows(
-                lambda lo: self._window(*diff, order, ends, lo, rows),
-                jnp.zeros(tokens.shape, jnp.float32), order, ends)
+        @jax.named_scope("pt.moe_route")    # the accumulator's zeros
+        def routed(diff, order, ends, trips):
+            return loop_windows(
+                lambda acc, lo: self._window(acc, *diff, order, ends, lo,
+                                             rows),
+                jnp.zeros(tokens.shape, jnp.float32), trips)
 
-        def forward(diff, order, ends):
-            return routed(diff, order, ends), (diff, order, ends)
+        def forward(*args):
+            return routed(*args), args
 
         @jax.named_scope("pt.moe_route")
         def backward(saved, g):
-            diff, order, ends = saved
+            diff, order, ends, trips = saved
 
-            def run(lo):
+            def add_window(acc, lo):
+                # the window's result is not wanted here, so neither is
+                # what it would be added to
                 _, pull = jax.vjp(lambda *d: self._window(
-                    *d, order, ends, lo, rows), *diff)
-                return pull(g)
+                    jnp.zeros_like(g), *d, order, ends, lo, rows), *diff)
+                grads = pull(g)
+                per_token = jax.tree.map(jnp.add, acc[:2], grads[:2])
+                # a weight gradient is a grouped kernel too, charged to
+                # the scope of what uses its result: this sum
+                with jax.named_scope("pt.moe_experts"):
+                    return per_token + jax.tree.map(jnp.add, acc[2:],
+                                                    grads[2:])
 
-            grads = scan_windows(run, jax.tree.map(jnp.zeros_like, diff),
-                                 order, ends)
-            return grads, None, None
+            summed = loop_windows(
+                add_window, jax.tree.map(jnp.zeros_like, diff), trips)
+            return summed, None, None, None
 
         routed.defvjp(forward, backward)
+        # the count is traced: an argument, since a custom_vjp function
+        # may not close over a tracer
         return routed((tokens, weights, self.w_in, self.w_out), order,
-                      ends)
+                      ends, jnp.asarray(windows, jnp.int32))
 
     def forward(self, x):
         tokens = x.reshape(-1, x.shape[-1])
@@ -307,7 +319,9 @@ class DroplessMoE(Layer):
             held_load = load[self.expert_offset:self.expert_offset + held]
             ends = jnp.cumsum(held_load)
             pairs_held = ends[-1]
-        routed = self._routed(tokens, weights, order, ends, rows, windows)
+            windows_run = jnp.minimum(-(-pairs_held // rows), windows)
+        routed = self._routed(tokens, weights, order, ends, rows,
+                              windows_run)
         out = routed.astype(x.dtype)
         if self.has_shared:
             with jax.named_scope("pt.moe_shared"):
@@ -319,6 +333,7 @@ class DroplessMoE(Layer):
                 pairs_held, 1).astype(jnp.float32),
             # every window that holds a held pair runs
             "pairs_dropped": jnp.maximum(pairs_held - windows * rows, 0),
+            "windows_run": windows_run,
             "expert_load": load,
         }
         return out.reshape(x.shape), stats

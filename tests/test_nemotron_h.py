@@ -231,13 +231,16 @@ def test_the_shares_add_up_to_the_uncut_layer():
     assert rel(whole_out.reshape(-1, 32), want) < TOL
 
 
-@pytest.mark.parametrize("where", ["all_held", "none_held"])
-def test_adversarial_routing_drops_nothing(where):
-    """The selection bias pushes every token's choices onto the held
-    experts, or onto none of them: the windows cover the worst case."""
+# a selection bias that pushes every token's choices onto the held
+# experts, or onto none of them, or leaves the router alone
+PUSH = {"all_held": 10.0, "none_held": -10.0, "balanced": 0.0}
+
+
+def _layer_against_reference(where):
+    """Experts 8 to 11 of 16 under the bias ``PUSH[where]``: the result
+    and the gradients are the reference's; returns the layer's stats."""
     layer = _moe_layer(4, 8)
-    push = 10.0 if where == "all_held" else -10.0
-    bias = jnp.zeros((16,), jnp.float32).at[8:12].set(push)
+    bias = jnp.zeros((16,), jnp.float32).at[8:12].set(PUSH[where])
     x = jnp.asarray(np.random.default_rng(1).normal(size=(2, SEQ, 32)),
                     jnp.float32)
     params = layer.param_dict()
@@ -253,9 +256,6 @@ def test_adversarial_routing_drops_nothing(where):
     want_grads = jax.grad(want_fn)(params)
     assert rel(out.reshape(-1, 32),
                _ref_layer(params, x.reshape(-1, 32), 4, 8, bias)) < TOL
-    pairs = 2 * SEQ * 3
-    assert int(stats["pairs_held"]) == (pairs if where == "all_held"
-                                        else 0)
     assert int(stats["pairs_dropped"]) == 0
     for name in ("w_in", "w_out", "router_weight", "shared_in.weight"):
         if where == "none_held" and name in ("w_in", "w_out"):
@@ -264,6 +264,71 @@ def test_adversarial_routing_drops_nothing(where):
             continue                    # the reference's is zero too
         else:
             assert rel(grads[name], want_grads[name]) < TOL, name
+    return stats
+
+
+@pytest.mark.parametrize("where", ["all_held", "none_held"])
+def test_adversarial_routing_drops_nothing(where):
+    """Every pair held, or none: the windows cover the worst case."""
+    stats = _layer_against_reference(where)
+    pairs = 2 * SEQ * 3
+    assert int(stats["pairs_held"]) == (pairs if where == "all_held"
+                                        else 0)
+
+
+@pytest.mark.parametrize("where", sorted(PUSH))
+def test_the_loop_runs_the_windows_that_hold_a_pair(where):
+    """``windows_run`` is the loop's trip count: every window when every
+    pair is held, none when none is, one for the balanced layer (a
+    window is twice its load), and the result is the reference's each
+    time."""
+    stats = _layer_against_reference(where)
+    total = 2 * SEQ * 3
+    rows = -(-moe.WINDOW_FACTOR * total * 4 // (16 * moe._ROW_TILE)) \
+        * moe._ROW_TILE
+    assert -(-total // rows) == 2, "the layer walks two windows at most"
+    held = int(stats["pairs_held"])
+    assert int(stats["windows_run"]) == -(-held // rows) \
+        == {"all_held": 2, "none_held": 0, "balanced": 1}[where]
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of what it nests."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        yield from _nested(eqn)
+
+
+def _nested(eqn):
+    for inner in jax.core.jaxprs_in_params(eqn.params):
+        yield from _equations(inner)
+
+
+@pytest.mark.parametrize("recompute", [False, True])
+def test_the_gradient_walks_the_windows_twice_and_skips_none(recompute):
+    """One loop over windows forward and one backward, each bounded by
+    the step's count (a ``while``, not a ``scan`` over every position),
+    and no ``cond`` inside either: a window that holds no pair is not
+    reached, so nothing is paid to skip it. The layer's checkpoint adds
+    no third loop: nothing after the mixer needs its output again."""
+    layer = _moe_layer(4, 8)
+    x = jnp.asarray(np.random.default_rng(1).normal(size=(2, SEQ, 32)),
+                    jnp.float32)
+
+    def run(p):
+        call = lambda h: functional_call(layer, p, layer.buffer_dict(),
+                                         h)[0]
+        out = (jax.checkpoint(call) if recompute else call)(x)
+        return jnp.sum(out * out)
+
+    jaxpr = jax.make_jaxpr(jax.grad(run))(layer.param_dict()).jaxpr
+    loops = {e: {i.primitive.name for i in _nested(e)}
+             for e in _equations(jaxpr)
+             if e.primitive.name in ("while", "scan")}
+    over_windows = [(e.primitive.name, "cond" in inside)
+                    for e, inside in loops.items()
+                    if "ragged_dot_general" in inside]
+    assert over_windows == [("while", False)] * 2
 
 
 def test_rows_past_the_last_group_are_never_read(monkeypatch):
@@ -319,8 +384,9 @@ def test_a_window_is_computed_whole(held_pairs, monkeypatch):
         return true_dot(x, w, group_sizes)
 
     monkeypatch.setattr(jax.lax, "ragged_dot", counting)
-    got = layer._window(tokens, weights, layer.w_in, layer.w_out, order,
-                        ends, 0, rows)
+    carry = jnp.asarray(rng.normal(size=(n, 32)), jnp.float32)
+    got = layer._window(carry, tokens, weights, layer.w_in, layer.w_out,
+                        order, ends, 0, rows) - carry
     assert seen == [(rows, rows)] * 2
     want = np.zeros((n, 32), np.float32)
     expert = np.repeat(np.arange(4), sizes)
@@ -396,6 +462,8 @@ def test_train_step_returns_the_routing_counters_and_learns():
     assert int(last["moe_pairs_dropped"]) == 0
     assert 0 < int(last["moe_pairs_held"]) < 2 * 4 * SEQ * 3
     assert float(last["moe_load_max_over_mean"]) >= 1.0
+    # two E layers of at most two windows each, and pairs in both
+    assert 2 <= int(last["moe_windows_run"]) <= 4
 
 
 def test_the_step_names_its_blocks():
