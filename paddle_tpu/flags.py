@@ -137,7 +137,8 @@ define_flag("matmul_precision", "default",
             "jax matmul precision: default | float32 | tensorfloat32 | "
             "highest. bf16 MXU passes use 'default'.")
 define_flag("use_pallas_kernels", True,
-            "Route hot ops (attention, layer_norm) through Pallas "
+            "Route hot ops (attention, layer_norm, the routed experts' "
+            "grouped matmul) through Pallas "
             "kernels when on TPU (master switch; per-kernel flags "
             "below). [structural] The switch itself only enables "
             "routing; each routed kernel carries its own evidence "

@@ -7,7 +7,7 @@ Routing policy: each ``maybe_*`` entry point
 checks the ``use_pallas_kernels`` flag and the backend, and falls back to the
 pure-XLA composition in ops/ — so CPU tests and TPU production share one
 call site. Kernels themselves live in sibling modules (flash_attention,
-layer_norm, fused_softmax_xent, paged_attention).
+layer_norm, fused_softmax_xent, paged_attention, grouped_matmul).
 """
 
 from __future__ import annotations
@@ -107,6 +107,33 @@ def maybe_layer_norm(x, weight, bias, epsilon: float, begin_norm_axis: int):
         except NotImplementedError:
             pass
     return ref_impl(x, weight, bias, epsilon, begin_norm_axis)
+
+
+def maybe_group_tiles(sizes, rows: int):
+    """The walk over (group, row tile) pairs that the grouped-matmul
+    kernels of one window share (kernels/grouped_matmul.py), or ``None``
+    where ``maybe_grouped_matmul`` runs ``jax.lax.ragged_dot``: off a
+    TPU, under a mesh (GSPMD cannot partition a Mosaic kernel, and the
+    held experts are one rank's), or for rows the tile does not
+    divide."""
+    from .grouped_matmul import ROW_TILE, group_tiles
+    if not pallas_enabled() or rows % ROW_TILE:
+        return None
+    from ..parallel.mesh import auto_axis_sizes
+    if auto_axis_sizes():
+        return None
+    return group_tiles(sizes, rows)
+
+
+def maybe_grouped_matmul(lhs, rhs, sizes, tiles=None):
+    """Rows of group ``i`` of ``lhs`` [m, k] times ``rhs[i]`` of [g, k,
+    n], for ``sizes`` [g] rows a group: the repo's kernels, forward and
+    both gradients, where ``tiles`` is ``maybe_group_tiles``'s walk, and
+    ``jax.lax.ragged_dot`` where it is ``None``."""
+    if tiles is None:
+        return jax.lax.ragged_dot(lhs, rhs, sizes)
+    from .grouped_matmul import grouped_matmul
+    return grouped_matmul(lhs, rhs, sizes, tiles)
 
 
 def fused_softmax_xent_enabled() -> bool:

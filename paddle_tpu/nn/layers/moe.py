@@ -23,6 +23,7 @@ and a sort by expert with a grouped matmul over the experts held here.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Optional
 
@@ -30,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core.dtype import get_default_dtype
+from ...observability import xprof
 from .. import initializer as I
 from ..layer import Layer, Parameter
 
@@ -134,7 +136,10 @@ class MoELayer(Layer):
 # count of held pairs, and an empty window costs nothing): a step whose
 # held pairs fit one window takes the same time whatever the routing,
 # the worst routing runs every window, no pair is dropped either way,
-# and the buffers are one window's.
+# and the buffers are one window's. A window's products go through one
+# seam, `kernels.maybe_grouped_matmul`: on a TPU the repo's `moe_gmm` /
+# `moe_tgmm` kernels (kernels/grouped_matmul.py), which walk a window in
+# row tiles that divide _ROW_TILE; elsewhere `jax.lax.ragged_dot`.
 WINDOW_FACTOR = 2
 _ROW_TILE = 512
 
@@ -153,9 +158,11 @@ class DroplessMoE(Layer):
     (on an expert-parallel rank their part arrives by the exchange,
     which this layer does not contain). The held pairs are sorted by
     expert and run, a window of the sorted list at a time, through one
-    pair of grouped matmuls (``jax.lax.ragged_dot`` with the true group
-    sizes, a window's empty rows zeros in its last group): no capacity,
-    no dropped pair, whatever the routing. A window is twice the
+    pair of grouped matmuls (``kernels.maybe_grouped_matmul`` with the
+    true group sizes, a window's empty rows zeros in its last group: on
+    a TPU the Pallas kernels ``moe_gmm`` and ``moe_tgmm`` of
+    kernels/grouped_matmul.py, elsewhere ``jax.lax.ragged_dot``): no
+    capacity, no dropped pair, whatever the routing. A window is twice the
     balanced load and is computed whole, so a balanced layer multiplies
     as many rows of zeros as rows of pairs: a step's time follows the
     number of windows that hold a pair, not the pairs (PERF.md section
@@ -226,6 +233,7 @@ class DroplessMoE(Layer):
                 rows: int):
         """``acc`` [N, D] float32 and what the held pairs ``order[lo:lo +
         rows]`` add to it. ``ends`` are the cumulative group sizes."""
+        from ...kernels import maybe_group_tiles, maybe_grouped_matmul
         with jax.named_scope("pt.moe_route"):
             pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
             token_of = pair // self.top_k
@@ -238,13 +246,12 @@ class DroplessMoE(Layer):
                 rows - inside[-1])
             rows_in = tokens[token_of]
             w = jnp.where(live, weights.reshape(-1)[pair], 0.0)
-        # XLA:TPU renames the grouped kernels, and a profile's reader
-        # charges them to the scope of what uses (or feeds) them: the
-        # element-wise work next to each product is under this scope
+            # what the window's products share (None off a TPU)
+            tiles = maybe_group_tiles(sizes, rows)
         with jax.named_scope("pt.moe_experts"):
-            hidden = jnp.square(jax.nn.relu(jax.lax.ragged_dot(
-                jnp.where(live[:, None], rows_in, 0), w_in, sizes)))
-            out = (jax.lax.ragged_dot(hidden, w_out, sizes)
+            hidden = jnp.square(jax.nn.relu(maybe_grouped_matmul(
+                jnp.where(live[:, None], rows_in, 0), w_in, sizes, tiles)))
+            out = (maybe_grouped_matmul(hidden, w_out, sizes, tiles)
                    * w[:, None].astype(hidden.dtype)).astype(jnp.float32)
         with jax.named_scope("pt.moe_route"):
             return acc.at[token_of].add(out)
@@ -262,16 +269,25 @@ class DroplessMoE(Layer):
                 return add_window(acc, i * rows)
             return jax.lax.fori_loop(0, trips, body, init)
 
+        primal_traced = []
+
         @jax.custom_vjp
         @jax.named_scope("pt.moe_route")    # the accumulator's zeros
         def routed(diff, order, ends, trips):
+            primal_traced.append(True)
             return loop_windows(
                 lambda acc, lo: self._window(acc, *diff, order, ends, lo,
                                              rows),
                 jnp.zeros(tokens.shape, jnp.float32), trips)
 
         def forward(*args):
-            return routed(*args), args
+            # traced after the primal, this rule is `jax.checkpoint`'s
+            # recomputation: nothing after the mixer reads its result,
+            # so its products are dead code and not call sites that run
+            # (without a checkpoint it is the one forward, and notes)
+            with xprof.unnoted() if primal_traced \
+                    else contextlib.nullcontext():
+                return routed(*args), args
 
         @jax.named_scope("pt.moe_route")
         def backward(saved, g):
@@ -284,8 +300,7 @@ class DroplessMoE(Layer):
                     jnp.zeros_like(g), *d, order, ends, lo, rows), *diff)
                 grads = pull(g)
                 per_token = jax.tree.map(jnp.add, acc[:2], grads[:2])
-                # a weight gradient is a grouped kernel too, charged to
-                # the scope of what uses its result: this sum
+                # the weight gradients' sums belong with their products
                 with jax.named_scope("pt.moe_experts"):
                     return per_token + jax.tree.map(jnp.add, acc[2:],
                                                     grads[2:])

@@ -272,6 +272,20 @@ def note_kernel(name: str, flops: float, bytes_: float) -> None:
         notes.append((name, float(flops), float(bytes_)))
 
 
+@contextlib.contextmanager
+def unnoted() -> Iterator[None]:
+    """Kernels traced inside note nothing: for a trace whose calls the
+    caller knows the program will not run (a ``custom_vjp``'s forward
+    rule traced again by ``jax.checkpoint``'s recomputation, where
+    nothing reads its result). A noted site that never runs would make
+    a kernel's share of the peak unreadable."""
+    was, _TLS.notes = getattr(_TLS, "notes", None), None
+    try:
+        yield
+    finally:
+        _TLS.notes = was
+
+
 def note_dropout_mask(elements: int) -> None:
     """Called once per traced dropout call site with the elements it
     masks. A no-op unless metrics are on and a tracked entry point is

@@ -292,6 +292,27 @@ def test_the_loop_runs_the_windows_that_hold_a_pair(where):
         == {"all_held": 2, "none_held": 0, "balanced": 1}[where]
 
 
+# the grouped product a window runs: `jax.lax.ragged_dot`, the path off
+# a TPU, or the kernels a TPU runs, here under the Pallas interpreter
+PRODUCTS = ["ragged_dot", "kernels"]
+
+
+def _route_the_seam(monkeypatch, product, wrap=lambda seam: seam):
+    """Send the seam a window calls, ``kernels.maybe_grouped_matmul``,
+    to ``product``, through ``wrap``."""
+    from paddle_tpu import kernels
+    if product == "kernels":
+        from paddle_tpu.kernels import grouped_matmul as G
+        monkeypatch.setattr(
+            kernels, "maybe_group_tiles",
+            lambda sizes, rows: G.group_tiles(sizes, rows, moe._ROW_TILE))
+        seam = lambda lhs, rhs, sizes, tiles=None: G.grouped_matmul(
+            lhs, rhs, sizes, tiles, interpret=True)
+    else:
+        seam = kernels.maybe_grouped_matmul
+    monkeypatch.setattr(kernels, "maybe_grouped_matmul", wrap(seam))
+
+
 def _equations(jaxpr):
     """Every equation of ``jaxpr`` and of what it nests."""
     for eqn in jaxpr.eqns:
@@ -300,17 +321,22 @@ def _equations(jaxpr):
 
 
 def _nested(eqn):
+    if eqn.primitive.name == "pallas_call":
+        return      # a kernel's own branches are not the program's
     for inner in jax.core.jaxprs_in_params(eqn.params):
         yield from _equations(inner)
 
 
+@pytest.mark.parametrize("product", PRODUCTS)
 @pytest.mark.parametrize("recompute", [False, True])
-def test_the_gradient_walks_the_windows_twice_and_skips_none(recompute):
+def test_the_gradient_walks_the_windows_twice_and_skips_none(
+        recompute, product, monkeypatch):
     """One loop over windows forward and one backward, each bounded by
     the step's count (a ``while``, not a ``scan`` over every position),
     and no ``cond`` inside either: a window that holds no pair is not
     reached, so nothing is paid to skip it. The layer's checkpoint adds
     no third loop: nothing after the mixer needs its output again."""
+    _route_the_seam(monkeypatch, product)
     layer = _moe_layer(4, 8)
     x = jnp.asarray(np.random.default_rng(1).normal(size=(2, SEQ, 32)),
                     jnp.float32)
@@ -327,21 +353,24 @@ def test_the_gradient_walks_the_windows_twice_and_skips_none(recompute):
              if e.primitive.name in ("while", "scan")}
     over_windows = [(e.primitive.name, "cond" in inside)
                     for e, inside in loops.items()
-                    if "ragged_dot_general" in inside]
+                    if {"ragged_dot": "ragged_dot_general",
+                        "kernels": "pallas_call"}[product] in inside]
     assert over_windows == [("while", False)] * 2
 
 
-def test_rows_past_the_last_group_are_never_read(monkeypatch):
-    """XLA:TPU's grouped-matmul kernel leaves the rows past the last
-    group uninitialised (the CPU's zero-fills them). A window hands the
-    kernel groups that cover every row of it, and the layer's result
-    and gradients do not depend on what a kernel would leave past
-    them."""
-    true_dot = jax.lax.ragged_dot
-
-    def garbage_past_the_groups(x, w, sizes):
-        dead = jnp.arange(x.shape[0]) >= jnp.sum(sizes)
-        return jnp.where(dead[:, None], jnp.nan, true_dot(x, w, sizes))
+@pytest.mark.parametrize("product", PRODUCTS)
+def test_rows_past_the_last_group_are_never_read(product, monkeypatch):
+    """XLA:TPU's own grouped-matmul kernel leaves the rows past the last
+    group uninitialised (the CPU's zero-fills them, and so do the
+    repo's kernels). A window hands the product groups that cover every
+    row of it, and the layer's result and gradients do not depend on
+    what a kernel would leave past them."""
+    def garbage_past_the_groups(seam):
+        def product_of(x, w, sizes, *tiles):
+            dead = jnp.arange(x.shape[0]) >= jnp.sum(sizes)
+            return jnp.where(dead[:, None], jnp.nan,
+                             seam(x, w, sizes, *tiles))
+        return product_of
 
     layer = _moe_layer(4, 8)
     x = jnp.asarray(np.random.default_rng(2).normal(size=(2, SEQ, 32)),
@@ -353,7 +382,7 @@ def test_rows_past_the_last_group_are_never_read(monkeypatch):
 
     params = layer.param_dict()
     (_, want), want_grads = jax.value_and_grad(run, has_aux=True)(params)
-    monkeypatch.setattr(jax.lax, "ragged_dot", garbage_past_the_groups)
+    _route_the_seam(monkeypatch, product, garbage_past_the_groups)
     (_, got), grads = jax.value_and_grad(run, has_aux=True)(params)
     assert np.isfinite(np.asarray(got)).all()
     assert rel(got, want) < 1e-6
@@ -361,12 +390,14 @@ def test_rows_past_the_last_group_are_never_read(monkeypatch):
         assert rel(grads[name], want_grads[name]) < 1e-6, name
 
 
+@pytest.mark.parametrize("product", PRODUCTS)
 @pytest.mark.parametrize("held_pairs", [0, 5, 16])
-def test_a_window_is_computed_whole(held_pairs, monkeypatch):
+def test_a_window_is_computed_whole(held_pairs, product, monkeypatch):
     """Whatever share of a window holds pairs, the grouped matmuls get
     groups that sum to the window's rows (the rows past the last pair
     are zeros in the last group), so a window's time does not follow
-    the routing; the zeros add nothing."""
+    the routing; the zeros add nothing. The kernels walk every row tile
+    of such a window, whatever the groups."""
     layer = _moe_layer(4, 0)
     rows, n = 16, 12
     rng = np.random.default_rng(held_pairs)
@@ -377,13 +408,17 @@ def test_a_window_is_computed_whole(held_pairs, monkeypatch):
     order = jnp.asarray(np.pad(rng.permutation(n * 3), (0, rows)))
     ends = jnp.asarray(np.cumsum(sizes), jnp.int32)
     seen = []
-    true_dot = jax.lax.ragged_dot
 
-    def counting(x, w, group_sizes):
-        seen.append((x.shape[0], int(jnp.sum(group_sizes))))
-        return true_dot(x, w, group_sizes)
+    def counting(seam):
+        def product_of(x, w, group_sizes, tiles=None):
+            seen.append((x.shape[0], int(jnp.sum(group_sizes))))
+            if tiles is not None:
+                walked = np.asarray(tiles.tile_of)[:int(tiles.visits[0])]
+                assert set(walked) == set(range(rows // moe._ROW_TILE))
+            return seam(x, w, group_sizes, tiles)
+        return product_of
 
-    monkeypatch.setattr(jax.lax, "ragged_dot", counting)
+    _route_the_seam(monkeypatch, product, counting)
     carry = jnp.asarray(rng.normal(size=(n, 32)), jnp.float32)
     got = layer._window(carry, tokens, weights, layer.w_in, layer.w_out,
                         order, ends, 0, rows) - carry
@@ -504,6 +539,45 @@ def test_the_step_names_its_blocks():
     # the scan's loop bodies carry the scope themselves
     assert any("while/body" in n for n in by_block["pt.ssm_scan"])
     assert any("while/body" in n for n in by_block["pt.head_loss"])
+
+
+@pytest.mark.parametrize("recompute", ["layer", "none"])
+def test_the_step_notes_every_grouped_kernel_that_runs(recompute,
+                                                       monkeypatch):
+    """A live window runs eight grouped kernels a layer: the two
+    products forward, the two again inside the backward's ``jax.vjp``,
+    the two input gradients (``moe_gmm``) and the two weight gradients
+    (``moe_tgmm``). The step's trace notes exactly those, with or
+    without the layers' checkpoint: under it JAX traces the window loop
+    once more, as the forward rule of the recomputation, whose products
+    nothing reads; a site noted and never run would leave
+    ``train.moe_gmm_roofline`` with nothing to read."""
+    from collections import Counter
+
+    from paddle_tpu import observability as obs
+    from paddle_tpu.kernels.grouped_matmul import gmm_work
+    from paddle_tpu.observability import xprof
+    _route_the_seam(monkeypatch, "kernels")
+    obs.reset_all()
+    pt.set_flags({"enable_metrics": True})
+    try:
+        step = TrainStep(build(recompute=recompute),
+                         pt.optimizer.AdamW(1e-3), next_token_loss,
+                         extra_metrics=routing_metrics())
+        ids, labels = batch()
+        out = step(ids, labels=(labels,))
+        notes = xprof.kernel_notes(step._span_name)
+    finally:
+        pt.set_flags({"enable_metrics": False})
+        obs.reset_all()
+    assert np.isfinite(float(out["loss"]))
+    layers = CFG["hybrid_override_pattern"].count("E")
+    assert Counter(n[0] for n in notes) == {"moe_gmm": 6 * layers,
+                                            "moe_tgmm": 2 * layers}
+    # every site does one pass over the window's rows
+    rows = -(-moe.WINDOW_FACTOR * 2 * SEQ * 3 * 4 // (16 * moe._ROW_TILE)) \
+        * moe._ROW_TILE
+    assert {n[1:] for n in notes} == {gmm_work(rows, 32, 24, 4, 4)}
 
 
 def test_hapi_fit_trains_it_like_any_model():

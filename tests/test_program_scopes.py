@@ -292,8 +292,8 @@ def _flash_grad():
 
 def _kernel_wrappers():
     """(wrapper, arguments, names expected) for every kernel file."""
-    from paddle_tpu.kernels import (fused_softmax_xent, layer_norm,
-                                    paged_attention)
+    from paddle_tpu.kernels import (fused_softmax_xent, grouped_matmul,
+                                    layer_norm, paged_attention)
     f32 = jnp.float32
     x = jnp.ones((16, 128), f32)
     vec = jnp.ones((128,), f32)
@@ -310,8 +310,18 @@ def _kernel_wrappers():
                 h_, w_, b_, labels, interpret=True)),
             argnums=(0, 1))(h, w, b)
 
+    def grouped(a, w):
+        return jax.grad(lambda a_, w_: jnp.sum(
+            grouped_matmul.grouped_matmul(
+                a_, w_, jnp.array([5, 11], jnp.int32),
+                grouped_matmul.group_tiles(
+                    jnp.array([5, 11], jnp.int32), 16, 8),
+                interpret=True)), argnums=(0, 1))(a, w)
+
     return [
         (flash, (q, q, q), ["flash_fwd", "flash_bwd"]),
+        (grouped, (x, jnp.ones((2, 128, 128), f32)),
+         ["moe_gmm", "moe_gmm", "moe_tgmm"]),
         (lambda a, w, b: layer_norm.layer_norm_pallas(
             a, w, b, interpret=True), (x, vec, vec), ["layer_norm_fwd"]),
         (xent, (x, jnp.ones((256, 128), f32), jnp.zeros((256,), f32)),
@@ -331,7 +341,7 @@ def test_every_pallas_call_carries_its_own_name(monkeypatch):
     for fn, args, want in _kernel_wrappers():
         got = _pallas_names(fn, *args)
         assert got == want, (got, want)
-        seen += got
+        seen += sorted(set(got))
     # the two-kernel backward: sequences longer than one block
     from paddle_tpu.kernels import flash_attention as fa
     monkeypatch.setattr(fa, "BLOCK_Q", 8)
@@ -341,7 +351,7 @@ def test_every_pallas_call_carries_its_own_name(monkeypatch):
     got = _pallas_names(flash, q, q, q)
     assert got == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
     seen += got[1:]
-    assert len(seen) == len(set(seen)) == 10
+    assert len(seen) == len(set(seen)) == 12
 
 
 def test_every_call_site_in_the_kernel_files_passes_a_name():
@@ -358,7 +368,7 @@ def test_every_call_site_in_the_kernel_files_passes_a_name():
             m = re.search(r'\bname="(\w+)"', text[at:upto])
             assert m, f"{f}: a pallas_call without name= at {at}"
             names.append(m.group(1))
-    assert len(names) == 10 and len(set(names)) == 10, names
+    assert len(names) == 12 and len(set(names)) == 12, names
 
 
 @pytest.mark.parametrize("b,h,t,d", [(2, 3, 16, 8), (1, 2, 32, 16)])

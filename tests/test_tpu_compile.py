@@ -119,16 +119,22 @@ def test_flash_attention_causal_8192_positions_head_128_compiles(chip):
         assert kernel in text
 
 
-def test_grouped_matmul_of_held_pairs_compiles_to_one_pass(chip):
-    """``jax.lax.ragged_dot`` at the routed experts' widths, forward
-    and both gradients: XLA:TPU lowers each to its own grouped-matmul
-    kernel over the true group sizes (a custom call), not to a dense
-    product of every row with every expert."""
+def test_grouped_matmul_kernels_of_held_pairs_compile(chip):
+    """One window of the routed experts at the hybrid decoder's widths
+    (12288 rows, 2688 x 1856, 8 held experts), forward and both
+    gradients: the repo's ``moe_gmm`` and ``moe_tgmm`` (a group's whole
+    matrix resident in VMEM, far past the scoped default: the calls ask
+    for what they hold) and no grouped-matmul call of XLA's own. Every
+    call does one pass over the true group sizes, not a dense product
+    of every row with every expert."""
+    from paddle_tpu.kernels import grouped_matmul as G
     rows, d, f, experts = 12288, 2688, 1856, 8
 
     def loss(x, w_in, w_out, sizes):
-        h = jnp.square(jax.nn.relu(jax.lax.ragged_dot(x, w_in, sizes)))
-        return jnp.sum(jax.lax.ragged_dot(h, w_out, sizes)
+        tiles = G.group_tiles(sizes, rows)
+        h = jnp.square(jax.nn.relu(
+            G.grouped_matmul(x, w_in, sizes, tiles)))
+        return jnp.sum(G.grouped_matmul(h, w_out, sizes, tiles)
                        .astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*[
@@ -136,12 +142,18 @@ def test_grouped_matmul_of_held_pairs_compiles_to_one_pass(chip):
             ((rows, d), jnp.bfloat16), ((experts, d, f), jnp.bfloat16),
             ((experts, f, d), jnp.bfloat16), ((experts,), jnp.int32))
     ]).compile()
-    assert "ragged-dot" in compiled.as_text()
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "custom-call(" in line]
+    # the first product (the second's result is not wanted), the two
+    # input gradients, the two weight gradients
+    assert sum("moe_gmm" in c for c in calls) == 3
+    assert sum("moe_tgmm" in c for c in calls) == 2
     one_pass = 2.0 * rows * d * f
-    # two forward products, their two input and two weight gradients
-    # and one recomputed forward product: 7 passes (8 allowed), where a
-    # dense lowering would take `experts` times that
-    assert compiled.cost_analysis()["flops"] < 8.5 * one_pass
+    assert G.gmm_work(rows, d, f, experts, 2)[0] == one_pass
+    assert 4.5 * one_pass < compiled.cost_analysis()["flops"] \
+        < 8.5 * one_pass
 
 
 def test_layer_norm_fwd_bwd_compiles(chip):
