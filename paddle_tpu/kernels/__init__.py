@@ -7,7 +7,8 @@ Routing policy: each ``maybe_*`` entry point
 checks the ``use_pallas_kernels`` flag and the backend, and falls back to the
 pure-XLA composition in ops/ — so CPU tests and TPU production share one
 call site. Kernels themselves live in sibling modules (flash_attention,
-layer_norm, fused_softmax_xent, paged_attention, grouped_matmul).
+layer_norm, fused_softmax_xent, paged_attention, grouped_matmul,
+ssd_scan).
 """
 
 from __future__ import annotations
@@ -134,6 +135,25 @@ def maybe_grouped_matmul(lhs, rhs, sizes, tiles=None):
         return jax.lax.ragged_dot(lhs, rhs, sizes)
     from .grouped_matmul import grouped_matmul
     return grouped_matmul(lhs, rhs, sizes, tiles)
+
+
+def maybe_ssd_scan(x, dt, b_mat, c_mat, a, chunk: int):
+    """``nn.layers.ssm.ssd_chunked_scan`` by the fused kernels of
+    kernels/ssd_scan.py, forward and gradient, or ``None`` where the
+    caller runs the XLA form: off a TPU, under a mesh (GSPMD cannot
+    partition a Mosaic kernel), for a length the chunk does not divide,
+    for a chunk or a state that are no whole lane tiles, or for heads
+    that are no whole sublane tiles. A site that takes the kernels notes
+    itself (``pt_ssd_scan_kernel_sites``)."""
+    from .ssd_scan import ssd_scan, supported
+    if not pallas_enabled() or not supported(x.shape, b_mat.shape, chunk):
+        return None
+    from ..parallel.mesh import auto_axis_sizes
+    if auto_axis_sizes():
+        return None
+    from ..observability.xprof import note_ssd_scan_kernel
+    note_ssd_scan_kernel()
+    return ssd_scan(x, dt, b_mat, c_mat, a, chunk)
 
 
 def fused_softmax_xent_enabled() -> bool:

@@ -8,6 +8,14 @@ matmuls on the MXU, between chunks a scan over the chunk states. ``dt``,
 the cumulative log-decay and the states are float32 whatever the
 parameters' dtype; matmul operands take the input's dtype and accumulate
 in float32.
+
+The form has two implementations. ``ssd_chunked_scan`` below is the one
+XLA compiles, ``Mamba2Mixer`` mapping it over the sequences under a
+``jax.checkpoint``; ``kernels/ssd_scan.py`` is the same mathematics as a
+forward and a backward Pallas kernel that keep the decay blocks and the
+chunk states in VMEM. ``kernels.maybe_ssd_scan`` chooses from what it
+sees: the kernels on a TPU with no mesh in scope, for whole chunks and
+whole tiles, the XLA form everywhere else.
 """
 
 from __future__ import annotations
@@ -144,6 +152,7 @@ class Mamba2Mixer(Layer):
                                bias_attr=False)
 
     def forward(self, u):
+        from ...kernels import maybe_ssd_scan
         bsz, length, _ = u.shape
         h, p = self.num_heads, self.head_dim
         gn = self.n_groups * self.state_size
@@ -162,13 +171,18 @@ class Mamba2Mixer(Layer):
             dt = jax.nn.softplus(dt.astype(jnp.float32)
                                  + self.dt_bias.astype(jnp.float32))
             a = -jnp.exp(self.A_log.astype(jnp.float32))
-            # a sequence at a time, recomputed in the backward pass:
-            # the scan's FLOPs are a few percent of the layer's and its
-            # [chunk, chunk] temporaries most of the layer's memory
-            y = jax.lax.map(
-                jax.checkpoint(lambda s: ssd_chunked_scan(
-                    *(t[None] for t in s), a, self.chunk_size)[0]),
-                (x, dt, b_mat.reshape(group), c_mat.reshape(group)))
+            scan_in = (x, dt, b_mat.reshape(group), c_mat.reshape(group))
+            y = maybe_ssd_scan(*scan_in, a, self.chunk_size)
+            if y is None:
+                # the XLA form, a sequence at a time and recomputed in
+                # the backward pass: its [chunk, chunk] temporaries are
+                # most of the layer's memory (the kernels hold them in
+                # VMEM, walk the sequences on their grid and keep the
+                # chunk states for their own backward)
+                y = jax.lax.map(
+                    jax.checkpoint(lambda s: ssd_chunked_scan(
+                        *(t[None] for t in s), a, self.chunk_size)[0]),
+                    scan_in)
             y = y + x * self.D.astype(x.dtype)[:, None]
             y = self.norm(y.reshape(bsz, length, self.inner)
                           * jax.nn.silu(z))

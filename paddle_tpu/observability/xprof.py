@@ -39,6 +39,8 @@ metrics are on, all at trace time and nothing per step:
 - ``note_qkv_grad_summed``: each self-attention site whose three
   projection input gradients are summed before they cross the ``mp``
   link notes itself, published as ``pt_qkv_grad_summed_sites``;
+- ``note_ssd_scan_kernel``: each Mamba-2 layer whose scan runs the fused
+  kernels notes itself, published as ``pt_ssd_scan_kernel_sites``;
 - ``op_scopes(fn)``: on demand, the compiled program's
   ``{instruction name: op_name}``. A device profile names an operation
   by its HLO instruction; the ``jax.named_scope`` it was traced under
@@ -58,8 +60,8 @@ from . import metrics as _metrics
 
 __all__ = ["ProgramCardRegistry", "cards", "enabled", "harvest",
            "flops_of", "note_kernel", "note_dropout_mask",
-           "note_qkv_grad_summed", "kernel_notes", "op_scopes",
-           "parse_op_names"]
+           "note_qkv_grad_summed", "note_ssd_scan_kernel", "kernel_notes",
+           "op_scopes", "parse_op_names"]
 
 # Cost-analysis keys promoted onto the card top level when present.
 _COST_KEYS = ("flops", "transcendentals", "bytes accessed")
@@ -226,24 +228,26 @@ _KERNEL_NOTES: Dict[str, List[KernelNote]] = {}
 @contextlib.contextmanager
 def tracing(fn_name: str) -> Iterator[None]:
     """Entered by the recompile tracker round the body of a jit entry
-    point, which runs only while jax traces it: kernels, dropout sites
-    and summed q/k/v gradients traced inside note themselves under
-    ``fn_name``. The newest trace replaces what the one before noted (a
-    retrace, or ``op_scopes`` lowering the entry point again, must not
-    count a call site twice)."""
+    point, which runs only while jax traces it: kernels, dropout sites,
+    summed q/k/v gradients and fused scans traced inside note themselves
+    under ``fn_name``. The newest trace replaces what the one before
+    noted (a retrace, or ``op_scopes`` lowering the entry point again,
+    must not count a call site twice)."""
     outer = (getattr(_TLS, "notes", None), getattr(_TLS, "masked", None),
-             getattr(_TLS, "summed", None))
+             getattr(_TLS, "summed", None), getattr(_TLS, "scans", None))
     _TLS.notes = notes = []
     _TLS.masked = masked = []
     _TLS.summed = summed = []
+    _TLS.scans = scans = []
     try:
         yield
     finally:
-        _TLS.notes, _TLS.masked, _TLS.summed = outer
+        _TLS.notes, _TLS.masked, _TLS.summed, _TLS.scans = outer
         if outer[0] is not None:    # an entry point traced inside another
             outer[0].extend(notes)
             outer[1].extend(masked)
             outer[2].extend(summed)
+            outer[3].extend(scans)
         if _metrics.enabled():
             with _NOTES_LOCK:
                 _KERNEL_NOTES[fn_name] = notes
@@ -261,6 +265,11 @@ def tracing(fn_name: str) -> Iterator[None]:
                 "self-attention sites whose q/k/v input gradients are "
                 "summed before the mp all-reduce, in the newest trace "
                 "of the entry point").set(len(summed), fn=fn_name)
+            _metrics.gauge(
+                "pt_ssd_scan_kernel_sites",
+                "Mamba-2 layers whose chunked scan runs the fused Pallas "
+                "kernels, in the newest trace of the entry point").set(
+                    len(scans), fn=fn_name)
 
 
 def note_kernel(name: str, flops: float, bytes_: float) -> None:
@@ -303,6 +312,15 @@ def note_qkv_grad_summed() -> None:
     summed = getattr(_TLS, "summed", None)
     if summed is not None and _metrics.enabled():
         summed.append(1)
+
+
+def note_ssd_scan_kernel() -> None:
+    """Called once per traced Mamba-2 layer whose scan the seam
+    (``kernels.maybe_ssd_scan``) sends to the fused kernels. A no-op
+    unless metrics are on and a tracked entry point is being traced."""
+    scans = getattr(_TLS, "scans", None)
+    if scans is not None and _metrics.enabled():
+        scans.append(1)
 
 
 def kernel_notes(fn_name: str) -> List[KernelNote]:

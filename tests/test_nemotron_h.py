@@ -580,6 +580,119 @@ def test_the_step_notes_every_grouped_kernel_that_runs(recompute,
     assert {n[1:] for n in notes} == {gmm_work(rows, 32, 24, 4, 4)}
 
 
+def _scan_seam_as_on_a_tpu(monkeypatch):
+    """The Mamba-2 layers' seam, ``kernels.maybe_ssd_scan``, as a TPU
+    would see it and no other seam with it: the seam itself runs, under
+    a backend that reads as a TPU while it does, and its kernels run
+    under the interpreter."""
+    import functools
+
+    from paddle_tpu import kernels
+    from paddle_tpu.kernels import ssd_scan as K
+    real = kernels.maybe_ssd_scan
+    monkeypatch.setattr(K, "ssd_scan",
+                        functools.partial(K.ssd_scan, interpret=True))
+
+    def seam(*args):
+        with monkeypatch.context() as m:
+            m.setattr(kernels, "_on_tpu", lambda: True)
+            return real(*args)
+
+    monkeypatch.setattr(kernels, "maybe_ssd_scan", seam)
+
+
+@pytest.mark.parametrize("recompute", ["layer", "none"])
+def test_the_step_notes_every_scan_kernel_that_runs(recompute, monkeypatch):
+    """A Mamba-2 layer whose scan takes the fused kernels runs ``ssd_fwd``
+    and ``ssd_bwd`` once each, and under the layers' checkpoint
+    ``ssd_fwd`` once more, in the forward pass. The step's trace notes
+    as many: under the checkpoint JAX traces the scan alone when it
+    traces the layer and the ``custom_vjp``'s forward rule when it
+    differentiates it, and runs the rule both times; every ``ssd_fwd``
+    call does the same work, states and all, so the notes are the
+    calls' whichever trace each came from. The gauge
+    ``pt_ssd_scan_kernel_sites`` counts the layers."""
+    from collections import Counter
+
+    from paddle_tpu import observability as obs
+    from paddle_tpu.kernels.ssd_scan import ssd_work
+    from paddle_tpu.observability import xprof
+    _scan_seam_as_on_a_tpu(monkeypatch)
+    # the narrowest Mamba-2 layer the kernels take: whole lane tiles
+    wide = dict(mamba_head_dim=64, ssm_state_size=128, chunk_size=128)
+    rng = np.random.default_rng(1)
+    ids = rng.integers(0, CFG["vocab_size"], (2, 129)).astype(np.int32)
+    obs.reset_all()
+    pt.set_flags({"enable_metrics": True})
+    try:
+        step = TrainStep(build(recompute=recompute, **wide),
+                         pt.optimizer.AdamW(1e-3), next_token_loss,
+                         extra_metrics=routing_metrics())
+        out = step(ids[:, :-1], labels=(ids[:, 1:],))
+        notes = xprof.kernel_notes(step._span_name)
+        sites = obs.gauge("pt_ssd_scan_kernel_sites").value(
+            fn=step._span_name)
+    finally:
+        pt.set_flags({"enable_metrics": False})
+        obs.reset_all()
+    assert np.isfinite(float(out["loss"]))
+    layers = CFG["hybrid_override_pattern"].count("M")
+    assert sites == layers
+    forwards = 2 if recompute == "layer" else 1
+    assert Counter(n[0] for n in notes) == {"ssd_fwd": forwards * layers,
+                                            "ssd_bwd": layers}
+    shapes = ((2, 128, 4, 64), (2, 128, 2, 128), 128, 4)
+    assert set(notes) == {("ssd_fwd",) + ssd_work(*shapes),
+                          ("ssd_bwd",) + ssd_work(*shapes, backward=True)}
+
+
+def test_off_a_tpu_no_layer_of_the_step_takes_the_scan_kernels():
+    from paddle_tpu import observability as obs
+    from paddle_tpu.observability import xprof
+    obs.reset_all()
+    pt.set_flags({"enable_metrics": True})
+    try:
+        step = TrainStep(build(recompute="layer"), pt.optimizer.AdamW(1e-3),
+                         next_token_loss, extra_metrics=routing_metrics())
+        ids, labels = batch()
+        step(ids, labels=(labels,))
+        notes = xprof.kernel_notes(step._span_name)
+        sites = obs.gauge("pt_ssd_scan_kernel_sites").value(
+            fn=step._span_name)
+    finally:
+        pt.set_flags({"enable_metrics": False})
+        obs.reset_all()
+    assert sites == 0
+    assert not [n for n in notes if n[0].startswith("ssd_")]
+
+
+def test_the_scan_kernels_time_is_data_for_the_reader_that_is_there():
+    """``train.ssd_kernel_ms_per_step`` is data for the reader of the
+    flash kernels' time (named through ``readers.kernel_time``, as the
+    later blocks name theirs through ``readers.blocks``), over every
+    kernel name ``kernels/ssd_scan.py`` gives a ``pallas_call``."""
+    import re
+
+    from benchmarks import manifest as mf
+    from benchmarks.readers import program
+    from paddle_tpu.kernels import ssd_scan as K
+    manifest = mf.Manifest()
+    spec = manifest.metric_file("train.ssd_kernel_ms_per_step")
+    accepted = manifest.metric_file("train.flash_bwd_ms_per_step")
+    assert mf.resolve(spec["reader"]) is program.kernel_ms_per_unit
+    assert spec["args"] == dict(accepted["args"],
+                                kernels=["ssd_fwd", "ssd_bwd"])
+    with open(K.__file__) as f:
+        named = re.findall(r'name="(\w+)"', f.read())
+    assert sorted(named) == sorted(spec["args"]["kernels"])
+    for name in named:
+        assert callable(getattr(K, name))
+    entry = [m for m in manifest.doc["per_layer"]
+             if m["name"] == spec["name"]]
+    assert entry[0]["workloads"] == ["nemotron3_nano_ep16_s8k"]
+    assert entry[0]["layer"] == "kernels"
+
+
 def test_hapi_fit_trains_it_like_any_model():
     from paddle_tpu import hapi
     from paddle_tpu.data import DataLoader, TensorDataset

@@ -368,7 +368,7 @@ def test_every_call_site_in_the_kernel_files_passes_a_name():
             m = re.search(r'\bname="(\w+)"', text[at:upto])
             assert m, f"{f}: a pallas_call without name= at {at}"
             names.append(m.group(1))
-    assert len(names) == 12 and len(set(names)) == 12, names
+    assert len(names) == 14 and len(set(names)) == 14, names
 
 
 @pytest.mark.parametrize("b,h,t,d", [(2, 3, 16, 8), (1, 2, 32, 16)])

@@ -156,6 +156,89 @@ def test_grouped_matmul_kernels_of_held_pairs_compile(chip):
         < 8.5 * one_pass
 
 
+# the hybrid decoder's Mamba-2 layer (nemotron3_nano_ep16_s8k): two
+# sequences of 8192, 64 heads of 64 in 8 groups of state 128, chunk 128
+_SSM = dict(rows=2, length=8192, heads=64, width=64, groups=8, state=128,
+            chunk=128, hidden=2688)
+
+
+def _custom_calls(text, name):
+    return [line for line in text.splitlines()
+            if "custom-call(" in line and "tpu_custom_call" in line
+            and f"%{name}" in line.split(" = ")[0]]
+
+
+def test_ssd_scan_kernels_compile_at_the_hybrid_decoders_shapes(chip):
+    """``ssd_fwd`` and ``ssd_bwd`` at the
+    cell's shapes, bf16 with float32 dt: the transposes, the float32
+    products with a triangle and the matmuls that contract a first
+    dimension are what the interpreter cannot vouch for."""
+    from paddle_tpu.kernels.ssd_scan import ssd_scan
+    c = _SSM
+
+    def loss(x, dt, b_mat, c_mat, a):
+        return jnp.sum(ssd_scan(x, dt, b_mat, c_mat, a, c["chunk"])
+                       .astype(jnp.float32))
+
+    seq = (c["rows"], c["length"])
+    text = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), chip,
+        (seq + (c["heads"], c["width"]), jnp.bfloat16),
+        (seq + (c["heads"],), jnp.float32),
+        (seq + (c["groups"], c["state"]), jnp.bfloat16),
+        (seq + (c["groups"], c["state"]), jnp.bfloat16),
+        ((c["heads"],), jnp.float32))
+    assert len(_custom_calls(text, "ssd_fwd")) == 1
+    assert len(_custom_calls(text, "ssd_bwd")) == 1
+    # the one residual besides the inputs: the state entering each chunk
+    assert "f32[2,64,8,512,128]" in text
+
+
+def test_a_mamba2_layers_gradient_runs_the_scan_kernels_and_no_loop(chip):
+    """One ``Mamba2Mixer`` at the cell's widths under the layers'
+    checkpoint, forward and backward, with the seam seeing a TPU: the
+    scan is ``ssd_fwd`` twice (the forward pass and the recomputation)
+    and ``ssd_bwd`` once, and under
+    ``pt.ssm_scan`` there is no ``while`` (the map over sequences, the
+    scan over chunk states) and no ``reduce-window`` (the cumulative sum
+    of the log-decay as XLA lowers it: 32 ms a step, PERF.md section 6,
+    PR 34)."""
+    import re
+    from unittest import mock
+
+    import paddle_tpu as pt
+    from paddle_tpu import kernels
+    from paddle_tpu.nn.layer import functional_call
+    c = _SSM
+    mixer = pt.nn.Mamba2Mixer(c["hidden"], c["heads"], c["width"],
+                              c["state"], c["groups"],
+                              chunk_size=c["chunk"])
+    mixer.to(dtype="bfloat16")
+    params = mixer.param_dict()
+
+    def loss(p, u):
+        y = jax.checkpoint(lambda h: functional_call(mixer, p, {}, h))(u)
+        # something after the layer reads its result, as the next layer
+        # does: the forward pass's scan is no dead code
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    with mock.patch.object(kernels, "_on_tpu", lambda: True):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip)
+             for k, v in params.items()},
+            jax.ShapeDtypeStruct((c["rows"], c["length"], c["hidden"]),
+                                 jnp.bfloat16, sharding=chip)
+        ).compile().as_text()
+    assert len(_custom_calls(text, "ssd_fwd")) == 2
+    assert len(_custom_calls(text, "ssd_bwd")) == 1
+    scan = [line for line in text.splitlines()
+            if re.search(r'op_name="[^"]*pt\.ssm_scan', line)]
+    assert scan
+    assert not [line for line in scan
+                if re.search(r" (while|reduce-window)\(", line)]
+    assert "reduce-window" not in text
+
+
 def test_layer_norm_fwd_bwd_compiles(chip):
     from paddle_tpu.kernels.layer_norm import layer_norm_pallas
 
