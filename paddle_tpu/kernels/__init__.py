@@ -240,9 +240,35 @@ def _mask_to_kv_bias(mask):
     return mask[:, 0, 0, :].astype(jnp.float32)
 
 
+def _block_diffusion_attention(q, k, v, scale, layout: str, bd):
+    """``maybe_flash_attention`` under the block-diffusion mask."""
+    import jax.numpy as jnp
+
+    from .flash_attention import bd_allowed, bthd_supported, flash_attention
+    bthd = layout == "bthd"
+    t = q.shape[1 if bthd else 2]
+    d = q.shape[-1]
+    from ..parallel.mesh import auto_axis_sizes
+    if (pallas_enabled() and d % 128 == 0 and not auto_axis_sizes()
+            and (not bthd or bthd_supported(d, q.shape[2]))):
+        from ..observability.xprof import note_bd_attention
+        note_bd_attention()
+        return flash_attention(q, k, v, scale=scale, bthd=bthd,
+                               block_diffusion=tuple(bd))
+    pos = jnp.arange(t, dtype=jnp.int32)
+    mask = bd_allowed(pos[:, None], pos[None, :], t, *bd)
+    from ..ops.attention import scaled_dot_product_attention as ref_impl
+    if bthd:
+        out = ref_impl(jnp.moveaxis(q, 2, 1), jnp.moveaxis(k, 2, 1),
+                       jnp.moveaxis(v, 2, 1), mask=mask, scale=scale)
+        return jnp.moveaxis(out, 1, 2)
+    return ref_impl(q, k, v, mask=mask, scale=scale)
+
+
 def maybe_flash_attention(q, k, v, mask=None, scale: Optional[float] = None,
                           causal: bool = False, dropout_p: float = 0.0,
-                          training: bool = False, layout: str = "bhtd"):
+                          training: bool = False, layout: str = "bhtd",
+                          block_diffusion=None):
     """q/k/v: [B, H, T, D] (``layout="bhtd"``, default) or
     [B, T, H, D] (``layout="bthd"`` — the projections' natural layout;
     the flash kernel gathers heads inside its block DMA, so the routed
@@ -262,9 +288,21 @@ def maybe_flash_attention(q, k, v, mask=None, scale: Optional[float] = None,
     bits in the recompute backward), so training models like BERT
     (head dim 64, attn dropout 0.1) stay on the flash path when
     routed.
+
+    ``block_diffusion=(length, block)`` (static ints) is the
+    block-diffusion training mask over ``2 * length`` positions in
+    ``causal``'s place (``flash_attention`` has the rule): on a TPU the
+    ``bd_flash_*`` kernels whatever the length, with no [2L, 2L] array
+    anywhere; elsewhere plain XLA attention under the dense mask, for
+    small sizes only. A site that takes the kernels notes itself
+    (``pt_bd_attention_sites``).
     """
     from ..ops.attention import scaled_dot_product_attention as ref_impl
     import jax.numpy as jnp
+
+    if block_diffusion is not None:
+        return _block_diffusion_attention(q, k, v, scale, layout,
+                                          block_diffusion)
 
     bthd = layout == "bthd"
     t_axis = 1 if bthd else 2

@@ -60,13 +60,16 @@ def _block_sizes(tq: int, tk: int):
 
 
 def flash_fwd_work(b: int, h: int, tq: int, tk: int, d: int,
-                   itemsize: int, causal: bool = False):
+                   itemsize: int, causal: bool = False, bd=None):
     """(FLOPs, HBM bytes) one forward call must do: the two matmuls
-    QK^T and PV (``4*B*H*Tq*Tk*D``, half of it under a causal mask);
-    q, k, v read and the output written once, plus the f32 logsumexp
-    row."""
+    QK^T and PV (``4*B*H*Tq*Tk*D``, half of it under a causal mask,
+    ``4*B*H*D*(L^2 + K*L)`` under the block-diffusion mask ``bd = (L,
+    K)``: the allowed pairs, never the tiles visited); q, k, v read and
+    the output written once, plus the f32 logsumexp row."""
     flops = 4.0 * b * h * tq * tk * d
-    if causal:
+    if bd is not None:
+        flops = 4.0 * b * h * d * bd_allowed_pairs(*bd)
+    elif causal:
         flops /= 2
     return flops, float(b * h * (2 * tq + 2 * tk) * d * itemsize
                         + 4 * b * h * tq)
@@ -74,7 +77,7 @@ def flash_fwd_work(b: int, h: int, tq: int, tk: int, d: int,
 
 def flash_bwd_work(b: int, h: int, tq: int, tk: int, d: int,
                    itemsize: int, causal: bool = False,
-                   matmuls: int = 5):
+                   matmuls: int = 5, bd=None):
     """(FLOPs, HBM bytes) one backward call must do. The recompute
     backward runs five matmuls — S = QK^T, dP = dO V^T, dV = P^T dO,
     dQ = dS K, dK = dS^T Q: ``10*B*H*Tq*Tk*D`` — in the fused
@@ -83,7 +86,9 @@ def flash_bwd_work(b: int, h: int, tq: int, tk: int, d: int,
     v, dO read, the two f32 row vectors (lse, delta), and the
     gradients this call writes."""
     flops = 2.0 * matmuls * b * h * tq * tk * d
-    if causal:
+    if bd is not None:      # the allowed pairs, as in flash_fwd_work
+        flops = 2.0 * matmuls * b * h * d * bd_allowed_pairs(*bd)
+    elif causal:
         flops /= 2
     written = {5: tq + 2 * tk, 3: tq, 4: 2 * tk}[matmuls]
     return flops, float(b * h * (2 * tq + 2 * tk + written) * d
@@ -144,6 +149,124 @@ def _row_bytes(rows: int) -> int:
     return rows * 128 * 4
 
 
+# -- the block-diffusion mask ---------------------------------------------------
+#
+# A sequence of ``length`` tokens cut in blocks of ``block`` is run as
+# ``2 * length`` positions: the noised copy (positions below ``length``)
+# and the clean copy after it. A noisy query sees the noisy keys of its
+# own block and the clean keys of earlier blocks; a clean query the clean
+# keys of its own and earlier blocks; nobody sees a noisy key of another
+# block (BD3-LMs, arXiv:2503.09573, the vectorised training form). The
+# kernels compute the rule from iotas and walk only the tiles that hold
+# an allowed pair: for a tile of queries (or, in the dk/dv kernel, of
+# keys) these are one run of tiles in the noisy half and one in the
+# clean half, which the two functions below give as (first, count) each.
+
+def _bd_check(bd, tq: int, tk: int) -> None:
+    length, block = bd
+    if tq != 2 * length or tk != 2 * length or length % block:
+        raise ValueError(
+            f"block_diffusion=({length}, {block}) wants q and k of "
+            f"{2 * length} positions and whole blocks; got {tq}, {tk}")
+
+
+def _bd_sides(pos, length: int, block: int):
+    """(noisy, block index) of the positions ``pos``."""
+    noisy = pos < length
+    local = jnp.where(noisy, pos, pos - length)
+    if block & (block - 1) == 0:
+        return noisy, jnp.right_shift(local, block.bit_length() - 1)
+    return noisy, local // block
+
+
+def bd_allowed(q_pos, k_pos, seq: int, length: int, block: int):
+    """The rule on a tile: ``q_pos`` [BQ, 1] and ``k_pos`` [1, BK] int32
+    positions -> [BQ, BK] bool. A position past ``seq`` (a padded tail)
+    neither sees nor is seen. Two compares and an ``or`` on the tile;
+    the rest is on its edges."""
+    q_noisy, q_blk = _bd_sides(q_pos, length, block)
+    k_noisy, k_blk = _bd_sides(k_pos, length, block)
+    q_real, k_real = q_pos < seq, k_pos < seq
+    # a noisy key is seen by the noisy queries of its block; a clean key
+    # by the queries of later blocks, and by the clean ones of its own
+    same = jnp.where(jnp.logical_and(q_noisy, q_real), q_blk, -1)
+    upto = jnp.where(q_real, q_blk + jnp.where(q_noisy, 0, 1), -1)
+    k_same = jnp.where(jnp.logical_and(k_noisy, k_real), k_blk, -2)
+    k_before = jnp.where(jnp.logical_or(k_noisy, ~k_real), 2 ** 30, k_blk)
+    return jnp.logical_or(k_same == same, k_before < upto)
+
+
+def _bd_tile_valid(r0, block_q: int, c0, block_k: int, seq: int, bd):
+    q_pos = r0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    k_pos = c0 + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    return bd_allowed(q_pos, k_pos, seq, *bd)
+
+
+def bd_key_tiles(r0, block_q: int, block_k: int, length: int, block: int):
+    """Key tiles of width ``block_k`` that hold a pair allowed to the
+    queries ``[r0, r0 + block_q)``: ``(first noisy, count, first clean,
+    count)``, the two runs disjoint."""
+    r1 = jnp.minimum(r0 + block_q, 2 * length) - 1
+    has_noisy = r0 < length
+    rn1 = jnp.minimum(r1, length - 1)
+    n_lo = (r0 // block * block) // block_k
+    n_hi = (rn1 // block * block + block - 1) // block_k + 1
+    n_cnt = jnp.where(has_noisy, n_hi - n_lo, 0)
+    # the clean keys end (exclusive) at the last noisy row's block, or
+    # past the last clean row's
+    c_end = jnp.maximum(
+        jnp.where(has_noisy, length + rn1 // block * block, 0),
+        jnp.where(r1 >= length,
+                  (r1 - length) // block * block + block + length, 0))
+    c_lo = jnp.maximum(length // block_k, jnp.where(has_noisy, n_hi, 0))
+    c_hi = jnp.where(c_end > length, (c_end - 1) // block_k + 1, 0)
+    return n_lo, n_cnt, c_lo, jnp.maximum(c_hi - c_lo, 0)
+
+
+def bd_query_tiles(c0, block_k: int, block_q: int, length: int,
+                   block: int):
+    """Query tiles of height ``block_q`` that hold a pair allowed to see
+    the keys ``[c0, c0 + block_k)``: ``(first noisy, count, first clean,
+    count)``, the two runs disjoint."""
+    c1 = jnp.minimum(c0 + block_k, 2 * length) - 1
+    has_nk, has_ck = c0 < length, c1 >= length
+    cn1 = jnp.minimum(c1, length - 1)
+    cc0 = jnp.maximum(c0, length) - length
+    # noisy rows: the blocks of the noisy keys, and for the clean keys
+    # every later block's rows (the two meet when the tile straddles)
+    later = cc0 // block * block + block
+    has_later = jnp.logical_and(has_ck, later < length)
+    big = 4 * length
+    first = jnp.minimum(jnp.where(has_nk, c0 // block * block, big),
+                        jnp.where(has_later, later, big))
+    last = jnp.maximum(
+        jnp.where(has_nk, cn1 // block * block + block - 1, -1),
+        jnp.where(has_later, length - 1, -1))
+    any_noisy = jnp.logical_or(has_nk, has_later)
+    n_lo = first // block_q
+    n_hi = last // block_q + 1
+    n_cnt = jnp.where(any_noisy, n_hi - n_lo, 0)
+    # clean rows: from the first clean key's block to the end
+    c_lo = jnp.maximum((length + cc0 // block * block) // block_q,
+                       jnp.where(any_noisy, n_hi, 0))
+    c_hi = (2 * length - 1) // block_q + 1
+    return n_lo, n_cnt, c_lo, jnp.where(
+        has_ck, jnp.maximum(c_hi - c_lo, 0), 0)
+
+
+def _bd_tile_of(t, runs):
+    """The ``t``-th tile of the two runs ``bd_*_tiles`` gave."""
+    n_lo, n_cnt, c_lo, _ = runs
+    return jnp.where(t < n_cnt, n_lo + t, c_lo + t - n_cnt)
+
+
+def bd_allowed_pairs(length: int, block: int) -> int:
+    """Allowed (query, key) pairs of one sequence: ``L^2 + K L`` (noisy
+    to noisy ``K L``, noisy to clean ``L (L - K) / 2``, clean to clean
+    ``L (L + K) / 2``)."""
+    return length * length + block * length
+
+
 def _dropout_keep(seed, g, q_pos, k_pos, dropout_p: float):
     """Counter-based keep mask: bits are a pure hash of (seed, head,
     global q/k position), so the SAME mask regenerates bitwise in the
@@ -175,7 +298,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bias_ref, o_ref,
                       lse_ref, *, scale: float, causal: bool,
                       block_k: int, seq_k: int, seq_q: int,
                       dropout_p: float, has_bias: bool, d_head: int,
-                      hpb: int, n_heads: int):
+                      hpb: int, n_heads: int, bd=None):
     # refs carry hpb heads side-by-side in the minor dim ([BQ, hpb*D]):
     # hpb == 1 is the classic one-head-per-program layout; hpb == 2
     # packs head PAIRS so the [B, T, H, D] layout's d=64 slabs form a
@@ -197,14 +320,18 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bias_ref, o_ref,
             .astype(jnp.float32)
         v2 = v_ref[0, pl.ds(j * block_k, block_k), :] \
             .astype(jnp.float32)
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        valid = k_pos < seq_k                          # tail-block mask
-        q_pos = i_q * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        if causal:
-            valid = jnp.logical_and(valid,
-                                    q_pos + causal_offset >= k_pos)
+        if bd is not None:
+            valid = _bd_tile_valid(i_q * block_q, block_q, j * block_k,
+                                   block_k, seq_k, bd)
+        else:
+            k_pos = j * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            valid = k_pos < seq_k                      # tail-block mask
+            q_pos = i_q * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            if causal:
+                valid = jnp.logical_and(valid,
+                                        q_pos + causal_offset >= k_pos)
         bias = bias_ref[0, :, pl.ds(j * block_k, block_k)] \
             if has_bias else None
         new = ([], [], [])
@@ -244,14 +371,24 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bias_ref, o_ref,
                for _ in range(hpb))
     l0 = tuple(jnp.zeros((block_q, 1), jnp.float32)
                for _ in range(hpb))
-    if causal:
-        # only scan K blocks that intersect this Q block's visible range
-        max_k = (i_q + 1) * block_q - 1 + causal_offset
-        upper = jnp.clip(max_k // block_k + 1, 1, num_k)
+    if bd is not None:
+        # only the tiles that hold an allowed pair: a run of noisy key
+        # tiles, then a run of clean ones
+        runs = bd_key_tiles(i_q * block_q, block_q, block_k, *bd)
+        accs, m_fin, l_fin = jax.lax.fori_loop(
+            0, runs[1] + runs[3],
+            lambda t, carry: body(_bd_tile_of(t, runs), carry),
+            (acc0, m0, l0))
     else:
-        upper = num_k
-    accs, m_fin, l_fin = jax.lax.fori_loop(0, upper, body,
-                                           (acc0, m0, l0))
+        if causal:
+            # only scan K blocks that intersect this Q block's visible
+            # range
+            max_k = (i_q + 1) * block_q - 1 + causal_offset
+            upper = jnp.clip(max_k // block_k + 1, 1, num_k)
+        else:
+            upper = num_k
+        accs, m_fin, l_fin = jax.lax.fori_loop(0, upper, body,
+                                               (acc0, m0, l0))
     outs, lses = [], []
     for half in range(hpb):
         safe_l = jnp.maximum(l_fin[half], 1e-30)
@@ -263,6 +400,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bias_ref, o_ref,
     o_ref[0] = jnp.concatenate(outs, axis=1).astype(o_ref.dtype) \
         if hpb > 1 else outs[0].astype(o_ref.dtype)
     lse_ref[0] = jnp.concatenate(lses, axis=1) if hpb > 1 else lses[0]
+
+
+# the Mosaic calls under the block-diffusion mask carry names of their
+# own, so a metric that reads ``flash_*`` goes on reading what it read
+_BD_PREFIX = "bd_"
 
 
 def _seed_arr(seed):
@@ -284,7 +426,7 @@ def _bias_arr(kv_bias, b, tk, tk_p):
 
 def _flash_forward(q, k, v, seed, scale: float, causal: bool,
                    dropout_p: float, interpret: bool = False,
-                   kv_bias=None, bthd: bool = False):
+                   kv_bias=None, bthd: bool = False, bd=None):
     """``bthd=False``: q/k/v are [B, H, T, D] (classic layout).
     ``bthd=True``: q/k/v are [B, T, H, D] — the layout attention
     projections produce naturally. The kernels are IDENTICAL in both
@@ -300,6 +442,8 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
     else:
         b, h, tq, d = q.shape
         tk = k.shape[2]
+    if bd is not None:
+        _bd_check(bd, tq, tk)
     bq, bk = _block_sizes(tq, tk)
     # pad sequences to block multiples: pl.ds on a short tail CLAMPS the
     # start index (shifting rows under the validity mask), so the buffers
@@ -344,7 +488,7 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
                                causal=causal, block_k=bk, seq_k=tk,
                                seq_q=tq, dropout_p=dropout_p,
                                has_bias=has_bias, d_head=d, hpb=hpb,
-                               n_heads=h)
+                               n_heads=h, bd=bd)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -372,10 +516,10 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
         interpret=interpret,
         compiler_params=_grid_params(
             *[tk_p * hpb * d * k.dtype.itemsize] * 2),
-        name="flash_fwd",
+        name="flash_fwd" if bd is None else "bd_flash_fwd",
     )(qr, kr, vr, _seed_arr(seed), _bias_arr(kv_bias, b, tk, tk_p))
-    note_kernel("flash_fwd", *flash_fwd_work(
-        b, h, tq, tk, d, q.dtype.itemsize, causal))
+    note_kernel(_BD_PREFIX * (bd is not None) + "flash_fwd", *flash_fwd_work(
+        b, h, tq, tk, d, q.dtype.itemsize, causal, bd=bd))
     # lse -> [B, H, Tq]: head = group*hpb + half, so the trailing half
     # dim interleaves back via a (tiny, h*tq fp32) transpose
     lse_pub = lse[:, :tq, :].reshape(b, hg, tq, hpb)
@@ -385,11 +529,12 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
     return out[:, :tq].reshape(b, h, tq, d), lse_pub
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 9, 10))
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None,
                     interpret: bool = False, dropout_p: float = 0.0,
-                    seed=None, kv_bias=None, bthd: bool = False):
+                    seed=None, kv_bias=None, bthd: bool = False,
+                    block_diffusion=None):
     """Fused attention:
     dropout(softmax(QK^T * scale + kv_bias [+ causal mask])) V.
 
@@ -409,26 +554,44 @@ def flash_attention(q, k, v, causal: bool = False,
     the projections' natural layout — instead of [B, H, T, D]. Same
     kernels; the head gather rides the block DMA, eliminating the
     physical transposes around attention (see _flash_forward).
+
+    ``block_diffusion``: ``(length, block)`` static ints, the
+    block-diffusion training mask in ``causal``'s place: q and k hold
+    ``2 * length`` positions, a noised copy of a sequence then its clean
+    copy, cut in blocks of ``block`` (``bd_allowed`` is the rule). One
+    softmax over a query's whole key set, the rule computed in the
+    kernels from iotas, no tile without an allowed pair visited,
+    forward or backward; the Mosaic calls are named ``bd_flash_*``. Not
+    with ``causal``, dropout or a key bias.
     """
+    _check_mask(causal, dropout_p, kv_bias, block_diffusion)
     if dropout_p > 0.0 and seed is None:
         raise ValueError("flash_attention: dropout_p > 0 requires a "
                          "seed (vary it per step)")
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     out, _ = _flash_forward(q, k, v, seed, scale, causal, dropout_p,
-                            interpret, kv_bias, bthd)
+                            interpret, kv_bias, bthd, block_diffusion)
     return out
 
 
+def _check_mask(causal, dropout_p, kv_bias, block_diffusion) -> None:
+    if block_diffusion is not None and (causal or dropout_p > 0.0
+                                        or kv_bias is not None):
+        raise ValueError("flash_attention: block_diffusion is a mask of "
+                         "its own: no causal, dropout_p or kv_bias")
+
+
 def _fwd(q, k, v, causal, scale, interpret, dropout_p, seed, kv_bias,
-         bthd):
+         bthd, block_diffusion=None):
+    _check_mask(causal, dropout_p, kv_bias, block_diffusion)
     if dropout_p > 0.0 and seed is None:
         raise ValueError("flash_attention: dropout_p > 0 requires a "
                          "seed (vary it per step)")
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     out, lse = _flash_forward(q, k, v, seed, scale, causal, dropout_p,
-                              interpret, kv_bias, bthd)
+                              interpret, kv_bias, bthd, block_diffusion)
     return out, (q, k, v, seed, kv_bias, out, lse, scale)
 
 
@@ -468,7 +631,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    seed_ref, bias_ref, dq_ref, *, scale: float,
                    causal: bool, block_k: int, seq_k: int, seq_q: int,
                    dropout_p: float, has_bias: bool, d_head: int,
-                   hpb: int, n_heads: int):
+                   hpb: int, n_heads: int, bd=None):
     q2 = q_ref[0].astype(jnp.float32)                  # [BQ, hpb*D]
     do2 = do_ref[0].astype(jnp.float32)                # [BQ, hpb*D]
     lse2 = lse_ref[0]                                  # [BQ, hpb] f32
@@ -484,14 +647,19 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             .astype(jnp.float32)
         v2 = v_ref[0, pl.ds(j * block_k, block_k), :] \
             .astype(jnp.float32)
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        valid = k_pos < seq_k
-        q_pos = i_q * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        if causal:
-            valid = jnp.logical_and(valid,
-                                    q_pos + causal_offset >= k_pos)
+        if bd is not None:
+            valid = _bd_tile_valid(i_q * block_q, block_q, j * block_k,
+                                   block_k, seq_k, bd)
+            q_pos = k_pos = None           # dropout's, which bd has not
+        else:
+            k_pos = j * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            valid = k_pos < seq_k
+            q_pos = i_q * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            if causal:
+                valid = jnp.logical_and(valid,
+                                        q_pos + causal_offset >= k_pos)
         bias = bias_ref[0, :, pl.ds(j * block_k, block_k)] \
             if has_bias else None
         out = []
@@ -515,7 +683,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         upper = num_k
     dq0 = tuple(jnp.zeros((block_q, d_head), jnp.float32)
                 for _ in range(hpb))
-    dqs = jax.lax.fori_loop(0, upper, body, dq0)
+    if bd is not None:
+        runs = bd_key_tiles(i_q * block_q, block_q, block_k, *bd)
+        dqs = jax.lax.fori_loop(
+            0, runs[1] + runs[3],
+            lambda t, acc: body(_bd_tile_of(t, runs), acc), dq0)
+    else:
+        dqs = jax.lax.fori_loop(0, upper, body, dq0)
     dq_ref[0] = (jnp.concatenate(dqs, axis=1) if hpb > 1 else dqs[0]) \
         .astype(dq_ref.dtype)
 
@@ -524,7 +698,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     seed_ref, bias_ref, dk_ref, dv_ref, *, scale: float,
                     causal: bool, block_q: int, seq_k: int, seq_q: int,
                     dropout_p: float, has_bias: bool, d_head: int,
-                    hpb: int, n_heads: int):
+                    hpb: int, n_heads: int, bd=None):
     # Padded-q correctness: dO and delta are zero-padded, so a padded
     # query row contributes p^T@dO = 0 to dv and p*(0-0) = 0 to dk —
     # no explicit q-validity mask is needed.
@@ -545,14 +719,19 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             .astype(jnp.float32)
         lse2 = lse_ref[0, pl.ds(i * block_q, block_q), :]  # [BQ, hpb]
         delta2 = delta_ref[0, pl.ds(i * block_q, block_q), :]
-        k_pos = j_k * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        valid = k_pos < seq_k
-        q_pos = i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        if causal:
-            valid = jnp.logical_and(valid,
-                                    q_pos + causal_offset >= k_pos)
+        if bd is not None:
+            valid = _bd_tile_valid(i * block_q, block_q, j_k * block_k,
+                                   block_k, seq_k, bd)
+            q_pos = k_pos = None           # dropout's, which bd has not
+        else:
+            k_pos = j_k * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            valid = k_pos < seq_k
+            q_pos = i * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            if causal:
+                valid = jnp.logical_and(valid,
+                                        q_pos + causal_offset >= k_pos)
         new_dk, new_dv = [], []
         for half in range(hpb):
             sl = slice(half * d_head, (half + 1) * d_head)
@@ -581,7 +760,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         lower = 0
     zeros = tuple(jnp.zeros((block_k, d_head), jnp.float32)
                   for _ in range(hpb))
-    dks, dvs = jax.lax.fori_loop(lower, num_q, body, (zeros, zeros))
+    if bd is not None:
+        runs = bd_query_tiles(j_k * block_k, block_k, block_q, *bd)
+        dks, dvs = jax.lax.fori_loop(
+            0, runs[1] + runs[3],
+            lambda t, carry: body(_bd_tile_of(t, runs), carry),
+            (zeros, zeros))
+    else:
+        dks, dvs = jax.lax.fori_loop(lower, num_q, body, (zeros, zeros))
     dk_ref[0] = (jnp.concatenate(dks, axis=1) if hpb > 1 else dks[0]) \
         .astype(dk_ref.dtype)
     dv_ref[0] = (jnp.concatenate(dvs, axis=1) if hpb > 1 else dvs[0]) \
@@ -592,7 +778,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       seed_ref, bias_ref, dq_ref, dk_ref, dv_ref, *,
                       scale: float, causal: bool, seq_k: int,
                       seq_q: int, dropout_p: float, has_bias: bool,
-                      d_head: int, hpb: int, n_heads: int):
+                      d_head: int, hpb: int, n_heads: int, bd=None):
     """Single-block backward: when BOTH padded sequences fit one tile
     (tq_p == bq and tk_p == bk — e.g. BERT's T=512 with 512-tiles),
     the dq and dkv kernels' scans each degenerate to one iteration
@@ -609,12 +795,16 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     delta2 = delta_ref[0]
     block_q, block_k = q2.shape[0], k2.shape[0]
     g = pl.program_id(0)
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    valid = k_pos < seq_k
-    q_pos = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    if causal:
-        valid = jnp.logical_and(
-            valid, q_pos + (seq_k - seq_q) >= k_pos)
+    if bd is not None:
+        valid = _bd_tile_valid(0, block_q, 0, block_k, seq_k, bd)
+        q_pos = k_pos = None               # dropout's, which bd has not
+    else:
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+        valid = k_pos < seq_k
+        q_pos = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+        if causal:
+            valid = jnp.logical_and(
+                valid, q_pos + (seq_k - seq_q) >= k_pos)
     dqs, dks, dvs = [], [], []
     for half in range(hpb):
         sl = slice(half * d_head, (half + 1) * d_head)
@@ -643,7 +833,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
                     causal: bool, dropout_p: float,
                     interpret: bool = False, dlse=None, kv_bias=None,
-                    bthd: bool = False):
+                    bthd: bool = False, bd=None):
     if bthd:
         b, tq, h, d = q.shape
         tk = k.shape[1]
@@ -729,6 +919,7 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
     bias_map = (lambda g_, i: (g_ // hg, 0, 0)) if has_bias else \
         (lambda g_, i: (0, 0, 0))
     shape = (b, h, tq, tk, d, q.dtype.itemsize)     # for the work notes
+    prefix = _BD_PREFIX * (bd is not None)
     row_spec = pl.BlockSpec((1, bq, hpb), lambda g_, i: (g_, i, 0),
                             memory_space=pltpu.VMEM)
     rowfull_spec = pl.BlockSpec((1, tq_p, hpb),
@@ -742,7 +933,7 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
             functools.partial(_bwd_fused_kernel, scale=scale,
                               causal=causal, seq_k=tk, seq_q=tq,
                               dropout_p=dropout_p, has_bias=has_bias,
-                              d_head=d, hpb=hpb, n_heads=h),
+                              d_head=d, hpb=hpb, n_heads=h, bd=bd),
             grid=(b * hg, 1),
             in_specs=[
                 seq_spec(bq, q_map),
@@ -764,9 +955,10 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
             out_shape=[dq_struct, dk_struct, dv_struct],
             interpret=interpret,
             compiler_params=_GRID_PARALLEL,
-            name="flash_bwd",
+            name="flash_bwd" if bd is None else "bd_flash_bwd",
         )(qr, kr, vr, dor, lse_r, delta, seed_a, bias_a)
-        note_kernel("flash_bwd", *flash_bwd_work(*shape, causal))
+        note_kernel(prefix + "flash_bwd",
+                    *flash_bwd_work(*shape, causal, bd=bd))
         if bthd:
             return (dq[:, :tq].reshape(b, tq, h, d),
                     dk[:, :tk].reshape(b, tk, h, d),
@@ -781,7 +973,7 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_k=bk, seq_k=tk, seq_q=tq,
                           dropout_p=dropout_p, has_bias=has_bias,
-                          d_head=d, hpb=hpb, n_heads=h),
+                          d_head=d, hpb=hpb, n_heads=h, bd=bd),
         grid=(b * hg, tq_p // bq),
         in_specs=[
             seq_spec(bq, q_map),
@@ -799,16 +991,16 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
         out_shape=dq_struct,
         interpret=interpret,
         compiler_params=_grid_params(kv_bytes, kv_bytes),
-        name="flash_bwd_dq",
+        name="flash_bwd_dq" if bd is None else "bd_flash_bwd_dq",
     )(qr, kr, vr, dor, lse_r, delta, seed_a, bias_a)
-    note_kernel("flash_bwd_dq", *flash_bwd_work(*shape, causal,
-                                                matmuls=3))
+    note_kernel(prefix + "flash_bwd_dq",
+                *flash_bwd_work(*shape, causal, matmuls=3, bd=bd))
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq, seq_k=tk, seq_q=tq,
                           dropout_p=dropout_p, has_bias=has_bias,
-                          d_head=d, hpb=hpb, n_heads=h),
+                          d_head=d, hpb=hpb, n_heads=h, bd=bd),
         grid=(b * hg, tk_p // bk),
         in_specs=[
             seq_spec(tq_p, qfull_map),
@@ -834,10 +1026,10 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
         interpret=interpret,
         compiler_params=_grid_params(q_bytes, q_bytes, _row_bytes(tq_p),
                                      _row_bytes(tq_p)),
-        name="flash_bwd_dkv",
+        name="flash_bwd_dkv" if bd is None else "bd_flash_bwd_dkv",
     )(qr, kr, vr, dor, lse_r, delta, seed_a, bias_a)
-    note_kernel("flash_bwd_dkv", *flash_bwd_work(*shape, causal,
-                                                 matmuls=4))
+    note_kernel(prefix + "flash_bwd_dkv",
+                *flash_bwd_work(*shape, causal, matmuls=4, bd=bd))
 
     if bthd:
         return (dq[:, :tq].reshape(b, tq, h, d),
@@ -848,13 +1040,15 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
             dv[:, :tk].reshape(b, h, tk, d))
 
 
-def _bwd(causal, scale_arg, interpret, dropout_p, bthd, res, g):
+def _bwd(causal, scale_arg, interpret, dropout_p, bthd, block_diffusion,
+         res, g):
     import numpy as np
 
     q, k, v, seed, kv_bias, out, lse, scale = res
     dq, dk, dv = _flash_backward(q, k, v, seed, out, lse, g, scale,
                                  causal, dropout_p, interpret,
-                                 kv_bias=kv_bias, bthd=bthd)
+                                 kv_bias=kv_bias, bthd=bthd,
+                                 bd=block_diffusion)
     # seed is integer-valued: its cotangent is the symbolic-zero float0
     dseed = None if seed is None else \
         np.zeros(jnp.shape(jnp.asarray(seed)), jax.dtypes.float0)

@@ -24,3 +24,6 @@ from .ernie import (ErnieConfig, ErnieForPretraining, ErnieModel,  # noqa
 from .nemotron_h import (CausalLMOutput, NemotronHConfig,  # noqa: F401
                          NemotronHForCausalLM, balance_router_bias,
                          next_token_loss, routing_metrics)
+from .sdar_moe import (BlockDiffusionOutput, SdarMoeConfig,  # noqa: F401
+                       SdarMoeForCausalLM, block_diffusion_loss,
+                       block_diffusion_metrics)

@@ -166,6 +166,18 @@ class NemotronHForCausalLM(nn.Layer):
         self.lm_head = nn.Linear(c.hidden_size, c.vocab_size,
                                  weight_attr=w, bias_attr=False)
 
+    def router_bias_fit(self):
+        """What ``balance_router_bias`` asks of a model: the names of
+        its selection-bias buffers in the order of ``moe_expert_load``'s
+        rows, and the fit's schedule: forward passes, the first and the
+        last size of a move (a sigmoid score lies in (0, 1): 0.03
+        crosses the scores' spread in a few rounds, 0.001 is a training
+        step's move)."""
+        names = [f"layers.{i}.mixer.e_score_correction_bias" for i, kind
+                 in enumerate(self.config.hybrid_override_pattern)
+                 if kind == "E"]
+        return names, 48, 0.03, 0.001
+
     def forward(self, input_ids) -> CausalLMOutput:
         with jax.named_scope("pt.embed"):
             x = self.embeddings(input_ids)
@@ -212,25 +224,19 @@ def _balanced(bias, load, rate):
     return bias + rate * jnp.sign(jnp.mean(load) - load)
 
 
-# the fit's schedule: forward passes, and the first and last size of a
-# move (a sigmoid score lies in (0, 1): 0.03 crosses the scores' spread
-# in a few rounds, 0.001 is a training step's move)
-_FIT_ROUNDS, _FIT_FIRST, _FIT_LAST = 48, 0.03, 0.001
-
-
-def balance_router_bias(model: NemotronHForCausalLM, input_ids) -> float:
+def balance_router_bias(model, input_ids) -> float:
     """Fit every expert layer's ``e_score_correction_bias`` to
-    ``input_ids`` [B, S]: 48 forward passes, after each of which every
-    layer's bias makes one move of the balancing rule, the moves
-    shrinking geometrically from 0.03 to 0.001. It gives freshly
-    initialised (or loaded) weights the balance that a training run's
-    per-step moves reach after some thousands of steps. Writes the
-    model's buffers; returns the fullest expert over the mean, the
-    largest over the layers, after the last round."""
+    ``input_ids`` [B, S]: forward passes (48 for this module's model),
+    after each of which every layer's bias makes one move of the
+    balancing rule, the moves shrinking geometrically (from 0.03 to
+    0.001); the model's ``router_bias_fit()`` names the buffers and
+    gives the schedule. It gives freshly initialised (or loaded) weights
+    the balance that a training run's per-step moves reach after some
+    thousands of steps. Writes the model's buffers; returns the fullest
+    expert over the mean, the largest over the layers, after the last
+    round."""
     from ..nn.layer import functional_call
-    names = [f"layers.{i}.mixer.e_score_correction_bias" for i, kind
-             in enumerate(model.config.hybrid_override_pattern)
-             if kind == "E"]
+    names, rounds, first, last = model.router_bias_fit()
     if not names:
         return 1.0
 
@@ -247,9 +253,8 @@ def balance_router_bias(model: NemotronHForCausalLM, input_ids) -> float:
     model.eval()            # no recomputation, no per-step move
     try:
         params, buffers = model.param_dict(), model.buffer_dict()
-        for r in range(_FIT_ROUNDS):
-            rate = _FIT_FIRST * (_FIT_LAST / _FIT_FIRST) ** (
-                r / (_FIT_ROUNDS - 1))
+        for r in range(rounds):
+            rate = first * (last / first) ** (r / (rounds - 1))
             buffers, worst = one_round(params, buffers, input_ids,
                                        jnp.float32(rate))
     finally:

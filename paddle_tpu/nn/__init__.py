@@ -43,4 +43,5 @@ from .layers.transformer import (GroupedQueryAttention,
                                  TransformerDecoder,
                                  TransformerDecoderLayer,
                                  TransformerEncoder,
-                                 TransformerEncoderLayer)
+                                 TransformerEncoderLayer,
+                                 rotate_half_rope)

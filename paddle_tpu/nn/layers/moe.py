@@ -16,9 +16,10 @@ it stays for what it alone does, a layer whose experts GSPMD spreads
 over an ``ep`` mesh axis from sharding annotations
 (:func:`moe_param_rule`), where static capacity is what lets XLA insert
 the exchange. :class:`DroplessMoE` is the layer of today's sparse
-language models and of one expert-parallel rank: a sigmoid router with a
-selection-only bias, no capacity and no dropped pair, a shared expert,
-and a sort by expert with a grouped matmul over the experts held here.
+language models and of one expert-parallel rank: a sigmoid (or softmax)
+router with a selection-only bias, no capacity and no dropped pair, a
+shared expert, and a sort by expert with a grouped matmul over the
+experts held here, two-matrix ``relu^2`` or gated three-matrix ones.
 """
 
 from __future__ import annotations
@@ -168,6 +169,16 @@ class DroplessMoE(Layer):
     number of windows that hold a pair, not the pairs (PERF.md section
     6, PR 29, has the price and why it is paid).
 
+    Two options, each off by default (the layer then traces to what it
+    always did). ``score_func="softmax"`` scores by ``softmax(W_r x)``
+    over all the experts in sigmoid's place (selection, bias, the
+    division and the scale as above). ``gated=True`` makes an expert
+    three matrices, ``W_down (silu(W_gate x) * W_up x)``: ``w_in`` is
+    then ``[held, d_model, 2 d_expert]``, gate columns first, up columns
+    after, one operand so that a window's first product is one grouped
+    matmul as it is without the gate (``w_out`` is ``W_down``); the
+    shared expert, where there is one, is gated alike.
+
     Returns ``(out, stats)``; ``stats`` holds the scalars
     ``pairs_held`` (pairs on held experts in this call),
     ``load_max_over_mean`` (the fullest held expert over their mean),
@@ -187,8 +198,12 @@ class DroplessMoE(Layer):
                  expert_offset: int = 0,
                  routed_scaling_factor: float = 1.0,
                  norm_topk_prob: bool = True, weight_attr=None,
-                 out_weight_attr=None) -> None:
+                 out_weight_attr=None, score_func: str = "sigmoid",
+                 gated: bool = False) -> None:
         super().__init__()
+        if score_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"score_func {score_func!r}")
+        self.score_func, self.gated = score_func, gated
         held = num_experts if experts_held is None else experts_held
         if not (0 <= expert_offset and expert_offset + held <= num_experts
                 and 0 < top_k <= num_experts):
@@ -204,24 +219,35 @@ class DroplessMoE(Layer):
             weight_attr, I.XavierUniform(), (d_model, num_experts), dtype)
         self.register_buffer("e_score_correction_bias",
                              jnp.zeros((num_experts,), jnp.float32))
-        self.w_in = I.make_param(weight_attr, I.XavierUniform(),
-                                 (held, d_model, d_expert), dtype)
+        self.w_in = I.make_param(
+            weight_attr, I.XavierUniform(),
+            (held, d_model, d_expert * (2 if gated else 1)), dtype)
         self.w_out = I.make_param(out_weight_attr, I.XavierUniform(),
                                   (held, d_expert, d_model), dtype)
         self.has_shared = d_shared > 0
         if self.has_shared:
             from .common import Linear
-            self.shared_in = Linear(d_model, d_shared, weight_attr,
-                                    bias_attr=False)
+            self.shared_in = Linear(d_model, d_shared * (2 if gated
+                                                         else 1),
+                                    weight_attr, bias_attr=False)
             self.shared_out = Linear(d_shared, d_model, out_weight_attr,
                                      bias_attr=False)
 
+    def _act(self, h):
+        """An expert's activation on its first product [rows, width]."""
+        if not self.gated:
+            return jnp.square(jax.nn.relu(h))
+        gate, up = jnp.split(h, 2, axis=-1)
+        return jax.nn.silu(gate) * up
+
     def route(self, tokens):
         """(chosen experts [N, k] int32, their weights [N, k] float32)."""
-        scores = jax.nn.sigmoid(jnp.matmul(
+        logits = jnp.matmul(
             tokens.astype(jnp.float32),
             self.router_weight.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
+            precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits) if self.score_func == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
         _, chosen = jax.lax.top_k(
             scores + self.e_score_correction_bias, self.top_k)
         w = jnp.take_along_axis(scores, chosen, axis=-1)
@@ -249,8 +275,8 @@ class DroplessMoE(Layer):
             # what the window's products share (None off a TPU)
             tiles = maybe_group_tiles(sizes, rows)
         with jax.named_scope("pt.moe_experts"):
-            hidden = jnp.square(jax.nn.relu(maybe_grouped_matmul(
-                jnp.where(live[:, None], rows_in, 0), w_in, sizes, tiles)))
+            hidden = self._act(maybe_grouped_matmul(
+                jnp.where(live[:, None], rows_in, 0), w_in, sizes, tiles))
             out = (maybe_grouped_matmul(hidden, w_out, sizes, tiles)
                    * w[:, None].astype(hidden.dtype)).astype(jnp.float32)
         with jax.named_scope("pt.moe_route"):
@@ -340,8 +366,8 @@ class DroplessMoE(Layer):
         out = routed.astype(x.dtype)
         if self.has_shared:
             with jax.named_scope("pt.moe_shared"):
-                out = out + self.shared_out(jnp.square(jax.nn.relu(
-                    self.shared_in(tokens))))
+                out = out + self.shared_out(self._act(
+                    self.shared_in(tokens)))
         stats = {
             "pairs_held": pairs_held,
             "load_max_over_mean": jnp.max(held_load) * held / jnp.maximum(

@@ -141,6 +141,25 @@ class MultiHeadAttention(Layer):
         return self.out_proj(out.reshape(b, t, h * d))
 
 
+def rotate_half_rope(x, position_ids, theta: float):
+    """Rotary positions over the whole head, rotate-half form: ``x`` [B,
+    T, H, D] and ``position_ids`` [T] or [B, T] -> ``x cos + rotate_half(
+    x) sin`` with frequencies ``theta ** (-2 i / D)`` and ``rotate_half(
+    x) = [-x2, x1]`` over the head's two halves. Computed in float32 and
+    returned in ``x``'s dtype."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = jnp.asarray(position_ids, jnp.float32)[..., None] * freq
+    if angle.ndim == 2:
+        angle = angle[None]
+    cos = jnp.cos(angle)[:, :, None, :]                # [B | 1, T, 1, D/2]
+    sin = jnp.sin(angle)[:, :, None, :]
+    h = x.astype(jnp.float32)
+    x1, x2 = h[..., :half], h[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
 class GroupedQueryAttention(Layer):
     """Self-attention with ``num_heads`` query heads on ``num_kv_heads``
     key/value heads of ``head_dim`` (a KV head serves ``num_heads /
@@ -148,17 +167,35 @@ class GroupedQueryAttention(Layer):
     of its own, scale ``head_dim ** -0.5``. The KV heads are repeated
     to the query heads before the attention call, so the routed flash
     kernel sees plain multi-head operands in their native [B, T, H, D]
-    layout; the gradient of the repeat sums a group's heads."""
+    layout; the gradient of the repeat sums a group's heads.
+
+    Three options, each off by default (the layer then traces to what it
+    always did): ``qk_norm_eps`` puts an RMS norm with a gain of its own
+    over every q head and every k head (``q_norm`` / ``k_norm``);
+    ``rope_theta`` turns q and k by rotary positions, from the
+    ``position_ids`` the caller gives ``forward`` (two positions of the
+    input may share one; ``arange(T)`` without them); ``block_diffusion``
+    is the block length ``K`` of the block-diffusion training mask in
+    ``causal``'s place: the input is a noised copy of ``T / 2`` tokens
+    followed by the clean copy (``kernels.flash_attention``). The norm
+    and the turn are traced under the scope ``pt.attn_qk``."""
 
     def __init__(self, hidden_size: int, num_heads: int,
                  num_kv_heads: int, head_dim: int, causal: bool = True,
-                 weight_attr=None, out_weight_attr=None) -> None:
+                 weight_attr=None, out_weight_attr=None,
+                 qk_norm_eps: Optional[float] = None,
+                 rope_theta: Optional[float] = None,
+                 block_diffusion: Optional[int] = None) -> None:
         super().__init__()
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_kv_heads} KV heads do not divide "
                              f"{num_heads} query heads")
+        if block_diffusion is not None and causal:
+            raise ValueError("block_diffusion is a mask of its own: "
+                             "causal=False with it")
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
         self.head_dim, self.causal = head_dim, causal
+        self.rope_theta, self.block_diffusion = rope_theta, block_diffusion
         self.q_proj = Linear(hidden_size, num_heads * head_dim,
                              weight_attr, bias_attr=False)
         self.k_proj = Linear(hidden_size, num_kv_heads * head_dim,
@@ -167,22 +204,43 @@ class GroupedQueryAttention(Layer):
                              weight_attr, bias_attr=False)
         self.o_proj = Linear(num_heads * head_dim, hidden_size,
                              out_weight_attr, bias_attr=False)
+        self.has_qk_norm = qk_norm_eps is not None
+        if self.has_qk_norm:
+            from .norm import RMSNorm
+            self.q_norm = RMSNorm(head_dim, qk_norm_eps)
+            self.k_norm = RMSNorm(head_dim, qk_norm_eps)
 
-    def forward(self, x):
+    def _qk(self, q, k, position_ids):
+        """The options' work on q [B, T, H, D] and k [B, T, KV, D]."""
+        if not (self.has_qk_norm or self.rope_theta is not None):
+            return q, k
+        with jax.named_scope("pt.attn_qk"):
+            if self.has_qk_norm:
+                q, k = self.q_norm(q), self.k_norm(k)
+            if self.rope_theta is not None:
+                if position_ids is None:
+                    position_ids = jnp.arange(q.shape[1])
+                q = rotate_half_rope(q, position_ids, self.rope_theta)
+                k = rotate_half_rope(k, position_ids, self.rope_theta)
+        return q, k
+
+    def forward(self, x, position_ids=None):
         from ...kernels import maybe_flash_attention
         b, t, _ = x.shape
         rep = self.num_heads // self.num_kv_heads
         q = self.q_proj(x).reshape(b, t, self.num_heads, self.head_dim)
 
         def kv(proj):
-            heads = proj(x).reshape(b, t, self.num_kv_heads,
-                                    self.head_dim)
-            return jnp.repeat(heads, rep, axis=2)
+            return proj(x).reshape(b, t, self.num_kv_heads, self.head_dim)
 
+        q, k = self._qk(q, kv(self.k_proj), position_ids)
+        mask = None if self.block_diffusion is None \
+            else (t // 2, self.block_diffusion)
         out = maybe_flash_attention(
-            q, kv(self.k_proj), kv(self.v_proj), causal=self.causal,
+            q, jnp.repeat(k, rep, axis=2),
+            jnp.repeat(kv(self.v_proj), rep, axis=2), causal=self.causal,
             scale=self.head_dim ** -0.5, training=self.training,
-            layout="bthd")
+            layout="bthd", block_diffusion=mask)
         return self.o_proj(out.reshape(b, t, -1))
 
 

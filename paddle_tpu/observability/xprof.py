@@ -41,6 +41,9 @@ metrics are on, all at trace time and nothing per step:
   link notes itself, published as ``pt_qkv_grad_summed_sites``;
 - ``note_ssd_scan_kernel``: each Mamba-2 layer whose scan runs the fused
   kernels notes itself, published as ``pt_ssd_scan_kernel_sites``;
+- ``note_bd_attention``: each attention layer under the block-diffusion
+  mask whose products run the ``bd_flash_*`` kernels notes itself,
+  published as ``pt_bd_attention_sites``;
 - ``op_scopes(fn)``: on demand, the compiled program's
   ``{instruction name: op_name}``. A device profile names an operation
   by its HLO instruction; the ``jax.named_scope`` it was traced under
@@ -60,7 +63,8 @@ from . import metrics as _metrics
 
 __all__ = ["ProgramCardRegistry", "cards", "enabled", "harvest",
            "flops_of", "note_kernel", "note_dropout_mask",
-           "note_qkv_grad_summed", "note_ssd_scan_kernel", "kernel_notes",
+           "note_qkv_grad_summed", "note_ssd_scan_kernel",
+           "note_bd_attention", "kernel_notes",
            "op_scopes", "parse_op_names"]
 
 # Cost-analysis keys promoted onto the card top level when present.
@@ -234,20 +238,24 @@ def tracing(fn_name: str) -> Iterator[None]:
     noted (a retrace, or ``op_scopes`` lowering the entry point again,
     must not count a call site twice)."""
     outer = (getattr(_TLS, "notes", None), getattr(_TLS, "masked", None),
-             getattr(_TLS, "summed", None), getattr(_TLS, "scans", None))
+             getattr(_TLS, "summed", None), getattr(_TLS, "scans", None),
+             getattr(_TLS, "bd_sites", None))
     _TLS.notes = notes = []
     _TLS.masked = masked = []
     _TLS.summed = summed = []
     _TLS.scans = scans = []
+    _TLS.bd_sites = bd_sites = []
     try:
         yield
     finally:
-        _TLS.notes, _TLS.masked, _TLS.summed, _TLS.scans = outer
+        (_TLS.notes, _TLS.masked, _TLS.summed, _TLS.scans,
+         _TLS.bd_sites) = outer
         if outer[0] is not None:    # an entry point traced inside another
             outer[0].extend(notes)
             outer[1].extend(masked)
             outer[2].extend(summed)
             outer[3].extend(scans)
+            outer[4].extend(bd_sites)
         if _metrics.enabled():
             with _NOTES_LOCK:
                 _KERNEL_NOTES[fn_name] = notes
@@ -270,6 +278,11 @@ def tracing(fn_name: str) -> Iterator[None]:
                 "Mamba-2 layers whose chunked scan runs the fused Pallas "
                 "kernels, in the newest trace of the entry point").set(
                     len(scans), fn=fn_name)
+            _metrics.gauge(
+                "pt_bd_attention_sites",
+                "attention layers under the block-diffusion mask whose "
+                "products run the bd_flash kernels, in the newest trace "
+                "of the entry point").set(len(bd_sites), fn=fn_name)
 
 
 def note_kernel(name: str, flops: float, bytes_: float) -> None:
@@ -321,6 +334,16 @@ def note_ssd_scan_kernel() -> None:
     scans = getattr(_TLS, "scans", None)
     if scans is not None and _metrics.enabled():
         scans.append(1)
+
+
+def note_bd_attention() -> None:
+    """Called once per traced attention layer whose block-diffusion mask
+    the seam (``kernels.maybe_flash_attention``) sends to the
+    ``bd_flash_*`` kernels. A no-op unless metrics are on and a tracked
+    entry point is being traced."""
+    sites = getattr(_TLS, "bd_sites", None)
+    if sites is not None and _metrics.enabled():
+        sites.append(1)
 
 
 def kernel_notes(fn_name: str) -> List[KernelNote]:

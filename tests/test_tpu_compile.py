@@ -119,6 +119,81 @@ def test_flash_attention_causal_8192_positions_head_128_compiles(chip):
         assert kernel in text
 
 
+def test_flash_attention_under_the_block_diffusion_mask_compiles(chip):
+    """The block-diffusion decoder's attention (sdar_30b_a3b_ep8_s8k):
+    two sequences of 8192 tokens as 16,384 positions each, 32 heads of
+    128, the mask computed in the kernels. The dk/dv kernel keeps q, dO
+    and two float32 columns of 16,384 rows resident, double-buffered:
+    48 MiB, inside the 100 it may ask for. No [2L, 2L] array exists."""
+    from paddle_tpu.kernels.flash_attention import flash_attention
+
+    @jax.named_scope("pt.attn")     # as a model calls it: the Mosaic
+    def loss(q, k, v):              # calls are named after the kernels
+        out = flash_attention(q, k, v, bthd=True,
+                              block_diffusion=(8192, 4))
+        return jnp.sum(out.astype(jnp.float32))
+
+    qkv = ((2, 16384, 32, 128), jnp.bfloat16)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), chip, qkv, qkv,
+                    qkv)
+    for kernel in ("bd_flash_fwd", "bd_flash_bwd_dq", "bd_flash_bwd_dkv"):
+        assert len(_custom_calls(text, kernel)) == 1, kernel
+    assert not _custom_calls(text, "flash_fwd")
+    assert "16384,16384]" not in text
+
+
+def test_a_block_diffusion_layers_gradient_runs_its_kernels_whole(
+        chip, monkeypatch):
+    """One layer of the block-diffusion decoder at the cell's widths
+    (attention with q/k norm and rotary positions under the mask, then a
+    softmax router over 16 held gated experts), recomputed in its
+    backward pass as the model runs it, steered onto the kernels' side
+    of the seams here (the process sees the CPU): the ``bd_flash``
+    kernels and the grouped matmuls of the gated experts (``w_gate`` and
+    ``w_up`` one [16, 2048, 1536] operand) compile, the forward kernels
+    run twice, and no [2L, 2L] array is in the optimized program."""
+    import json
+    import os
+
+    from paddle_tpu import kernels
+    from paddle_tpu.models.sdar_moe import SdarMoeBlock, SdarMoeConfig
+    from paddle_tpu.nn.layer import functional_call
+    monkeypatch.setattr(kernels, "_on_tpu", lambda: True)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "sdar_30b_a3b.json")) as f:
+        cfg = json.load(f)["model"]
+    block = SdarMoeBlock(SdarMoeConfig(**cfg))
+    block.to(dtype="bfloat16")
+    block.train()
+    params, buffers = block.param_dict(), block.buffer_dict()
+    length = 8192
+    pos = jnp.arange(2 * length, dtype=jnp.int32) % length
+
+    def loss(p, x):
+        def layer(h):
+            y, stats = functional_call(block, p, buffers, h, pos)
+            return y, stats["pairs_held"]
+        y, held = jax.checkpoint(layer)(x)
+        return jnp.sum(y.astype(jnp.float32)), held
+
+    abstract = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=chip), params)
+    x = jax.ShapeDtypeStruct((2, 2 * length, cfg["hidden_size"]),
+                             jnp.bfloat16, sharding=chip)
+    compiled = jax.jit(jax.grad(loss, (0, 1), has_aux=True)).lower(
+        abstract, x).compile()
+    text = compiled.as_text()
+    assert len(_custom_calls(text, "bd_flash_fwd")) == 2
+    assert len(_custom_calls(text, "bd_flash_bwd_dq")) == 1
+    assert len(_custom_calls(text, "bd_flash_bwd_dkv")) == 1
+    assert _custom_calls(text, "moe_gmm") and _custom_calls(text, "moe_tgmm")
+    assert "ragged-dot" not in text
+    assert "16384,16384]" not in text
+    # what one layer's backward pass keeps beside the state: under 4 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
+
+
 def test_grouped_matmul_kernels_of_held_pairs_compile(chip):
     """One window of the routed experts at the hybrid decoder's widths
     (12288 rows, 2688 x 1856, 8 held experts), forward and both
