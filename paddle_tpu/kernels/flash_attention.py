@@ -26,10 +26,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.random import fmix32 as _fmix32
+from ..observability import xprof
 from ..observability.xprof import note_kernel
 
 # 512 tiles measured fastest on chip (r5 d64 train sweep, v5e:
@@ -520,10 +522,18 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
     )(qr, kr, vr, _seed_arr(seed), _bias_arr(kv_bias, b, tk, tk_p))
     note_kernel(_BD_PREFIX * (bd is not None) + "flash_fwd", *flash_fwd_work(
         b, h, tq, tk, d, q.dtype.itemsize, causal, bd=bd))
+    # what `nn.recompute_layer` keeps of a recomputed layer: the backward
+    # kernels read both, and making either again is this whole call. The
+    # output is named as the kernel wrote it (kept under the caller's
+    # [.., H, D] view, XLA copied it into that layout and out of it
+    # again: 17 ms a step of the block-diffusion cell), the statistics
+    # as [B, H, Tq] (the kernel's own array holds hpb lanes in 128).
+    out = checkpoint_name(out, "flash_out")
     # lse -> [B, H, Tq]: head = group*hpb + half, so the trailing half
     # dim interleaves back via a (tiny, h*tq fp32) transpose
     lse_pub = lse[:, :tq, :].reshape(b, hg, tq, hpb)
-    lse_pub = jnp.moveaxis(lse_pub, 3, 2).reshape(b, h, tq)
+    lse_pub = checkpoint_name(
+        jnp.moveaxis(lse_pub, 3, 2).reshape(b, h, tq), "flash_lse")
     if bthd:
         return out[:, :tq].reshape(b, tq, h, d), lse_pub
     return out[:, :tq].reshape(b, h, tq, d), lse_pub
@@ -570,8 +580,11 @@ def flash_attention(q, k, v, causal: bool = False,
                          "seed (vary it per step)")
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    out, _ = _flash_forward(q, k, v, seed, scale, causal, dropout_p,
-                            interpret, kv_bias, bthd, block_diffusion)
+    # the trace `nn.recompute_layer` makes of a layer is not the one a
+    # gradient runs: that is `_fwd`'s, which notes the call
+    with xprof.unnoted(when=xprof.in_layer_primal()):
+        out, _ = _flash_forward(q, k, v, seed, scale, causal, dropout_p,
+                                interpret, kv_bias, bthd, block_diffusion)
     return out
 
 

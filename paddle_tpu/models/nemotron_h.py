@@ -10,6 +10,11 @@ published instance: 52 layers of hidden size 2688, 128 experts).
 rank's share: the router scores all ``n_routed_experts_total``, and the
 experts ``[expert_offset, expert_offset + n_routed_experts)`` add their
 part (``nn.DroplessMoE``). ``vocab_size`` likewise is the rows held.
+
+With ``recompute="layer"`` a training step keeps every layer's input,
+a ``*`` layer's flash result and an ``E`` layer's routing plan
+(``nn.recompute_layer``), and makes the rest of the layer again in the
+backward pass; an ``M`` layer keeps its input alone.
 """
 
 from __future__ import annotations
@@ -64,7 +69,9 @@ class NemotronHConfig:
     head_dim: int = 128
     layer_norm_epsilon: float = 1e-5
     initializer_range: float = 0.02
-    # "layer": jax.checkpoint round every layer while training
+    # "layer": while training, every layer is recomputed in the
+    # backward pass from its input, but for the flash kernel's result
+    # and the routing plan, which are kept (nn.recompute_layer)
     recompute: str = "none"
 
     def __post_init__(self) -> None:
@@ -189,12 +196,7 @@ class NemotronHForCausalLM(nn.Layer):
         loads = []
         rate = self.config.router_bias_update_rate if self.training else 0
         for layer in self.layers:
-            # a closure of this call's own: jax.checkpoint keeps what it
-            # traced by function and shapes, and the layer reads its
-            # parameters from the call that traces it (functional_call),
-            # so a second trace at the same shapes must not find the first
-            x, stats = (jax.checkpoint(lambda h, _layer=layer: _layer(h))
-                        if remat else layer)(x)
+            x, stats = (nn.recompute_layer(layer) if remat else layer)(x)
             if stats is None:
                 continue
             held = held + stats["pairs_held"]

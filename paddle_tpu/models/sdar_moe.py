@@ -16,6 +16,11 @@ x0_i) / t_i``, the label at the same position (``block_diffusion_loss``).
 ``num_experts`` counts the experts this instance HOLDS; with
 ``num_experts_total`` larger it is one expert-parallel rank's share, as
 ``NemotronHConfig`` has it. ``vocab_size`` likewise is the rows held.
+
+With ``recompute="layer"`` a training step keeps every layer's input,
+its attention's flash result and its routing plan
+(``nn.recompute_layer``), and makes the rest of the layer again in the
+backward pass.
 """
 
 from __future__ import annotations
@@ -56,7 +61,9 @@ class SdarMoeConfig:
     # the id a noised token is replaced by: the last row held
     mask_token_id: Optional[int] = None
     initializer_range: float = 0.02
-    # "layer": jax.checkpoint round every layer while training
+    # "layer": while training, every layer is recomputed in the
+    # backward pass from its input, but for the flash kernel's result
+    # and the routing plan, which are kept (nn.recompute_layer)
     recompute: str = "none"
 
     def __post_init__(self) -> None:
@@ -166,10 +173,8 @@ class SdarMoeForCausalLM(nn.Layer):
         loads = []
         rate = c.router_bias_update_rate if self.training else 0
         for layer in self.layers:
-            # a closure of this call's own, as in NemotronHForCausalLM
-            x, stats = (jax.checkpoint(
-                lambda h, p, _layer=layer: _layer(h, p))
-                if remat else layer)(x, position_ids)
+            x, stats = (nn.recompute_layer(layer) if remat
+                        else layer)(x, position_ids)
             held = held + stats["pairs_held"]
             dropped = dropped + stats["pairs_dropped"]
             windows_run = windows_run + stats["windows_run"]

@@ -30,6 +30,7 @@ from .layers.loss import (BCELoss, BCEWithLogitsLoss, CTCLoss,
                           SmoothL1Loss, TripletMarginLoss)
 from .layers.moe import (DroplessMoE, MoELayer,  # noqa: F401
                          moe_param_rule)
+from .layers.recompute import recompute_layer  # noqa: F401
 from .layers.ssm import Mamba2Mixer  # noqa: F401
 from .decode import (BasicDecoder, BeamSearchDecoder,  # noqa: F401
                      DecodeHelper, Decoder, dynamic_decode,

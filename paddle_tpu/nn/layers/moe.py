@@ -24,12 +24,12 @@ experts held here, two-matrix ``relu^2`` or gated three-matrix ones.
 
 from __future__ import annotations
 
-import contextlib
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ...core.dtype import get_default_dtype
 from ...observability import xprof
@@ -190,7 +190,14 @@ class DroplessMoE(Layer):
     ``expert_load`` [num_experts], the pairs this call's tokens sent to
     each expert the router scores: what the aux-loss-free balancing
     rule reads to move ``e_score_correction_bias`` (the layer itself
-    never writes the buffer)."""
+    never writes the buffer).
+
+    The routing plan carries names: ``moe_chosen`` (the chosen experts
+    [N, k]), ``moe_order`` (the pairs sorted by expert) and ``moe_load``
+    (``expert_load``), all int32. Inside ``nn.recompute_layer`` the
+    backward pass reads the kept three and runs no second ``top_k``,
+    sort or count; the router's matmul, the scores and the weights it
+    recomputes, since their gradient needs them."""
 
     def __init__(self, d_model: int, d_expert: int, num_experts: int,
                  top_k: int, d_shared: int = 0,
@@ -250,6 +257,7 @@ class DroplessMoE(Layer):
             else jax.nn.softmax(logits, axis=-1)
         _, chosen = jax.lax.top_k(
             scores + self.e_score_correction_bias, self.top_k)
+        chosen = checkpoint_name(chosen, "moe_chosen")
         w = jnp.take_along_axis(scores, chosen, axis=-1)
         if self.norm_topk_prob:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
@@ -311,8 +319,7 @@ class DroplessMoE(Layer):
             # recomputation: nothing after the mixer reads its result,
             # so its products are dead code and not call sites that run
             # (without a checkpoint it is the one forward, and notes)
-            with xprof.unnoted() if primal_traced \
-                    else contextlib.nullcontext():
+            with xprof.unnoted(when=bool(primal_traced)):
                 return routed(*args), args
 
         @jax.named_scope("pt.moe_route")
@@ -354,9 +361,12 @@ class DroplessMoE(Layer):
             # absent experts sort last, into a group no matmul visits
             key = jnp.where((local >= 0) & (local < held), local,
                             held).reshape(-1)
-            order = jnp.pad(jnp.argsort(key), (0, windows * rows - total))
-            load = jnp.bincount(chosen.reshape(-1),
-                                length=self.num_experts).astype(jnp.int32)
+            order = checkpoint_name(
+                jnp.pad(jnp.argsort(key), (0, windows * rows - total)),
+                "moe_order")
+            load = checkpoint_name(
+                jnp.bincount(chosen.reshape(-1), length=self.num_experts)
+                .astype(jnp.int32), "moe_load")
             held_load = load[self.expert_offset:self.expert_offset + held]
             ends = jnp.cumsum(held_load)
             pairs_held = ends[-1]

@@ -178,7 +178,12 @@ class GroupedQueryAttention(Layer):
     is the block length ``K`` of the block-diffusion training mask in
     ``causal``'s place: the input is a noised copy of ``T / 2`` tokens
     followed by the clean copy (``kernels.flash_attention``). The norm
-    and the turn are traced under the scope ``pt.attn_qk``."""
+    and the turn are traced under the scope ``pt.attn_qk``.
+
+    Where the flash kernel runs, its output and row statistics carry the
+    names ``flash_out`` and ``flash_lse``: inside ``nn.recompute_layer``
+    the backward pass reads the kept pair and does not run the forward
+    kernel again (the projections, the norm and the turn it recomputes)."""
 
     def __init__(self, hidden_size: int, num_heads: int,
                  num_kv_heads: int, head_dim: int, causal: bool = True,

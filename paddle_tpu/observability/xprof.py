@@ -44,6 +44,9 @@ metrics are on, all at trace time and nothing per step:
 - ``note_bd_attention``: each attention layer under the block-diffusion
   mask whose products run the ``bd_flash_*`` kernels notes itself,
   published as ``pt_bd_attention_sites``;
+- ``note_remat_kept``: each named result that a layer's recomputation
+  keeps (``nn.recompute_layer``'s policy) notes its bytes, published as
+  ``pt_remat_kept_sites`` / ``pt_remat_kept_bytes``;
 - ``op_scopes(fn)``: on demand, the compiled program's
   ``{instruction name: op_name}``. A device profile names an operation
   by its HLO instruction; the ``jax.named_scope`` it was traced under
@@ -64,7 +67,7 @@ from . import metrics as _metrics
 __all__ = ["ProgramCardRegistry", "cards", "enabled", "harvest",
            "flops_of", "note_kernel", "note_dropout_mask",
            "note_qkv_grad_summed", "note_ssd_scan_kernel",
-           "note_bd_attention", "kernel_notes",
+           "note_bd_attention", "note_remat_kept", "kernel_notes",
            "op_scopes", "parse_op_names"]
 
 # Cost-analysis keys promoted onto the card top level when present.
@@ -239,23 +242,25 @@ def tracing(fn_name: str) -> Iterator[None]:
     must not count a call site twice)."""
     outer = (getattr(_TLS, "notes", None), getattr(_TLS, "masked", None),
              getattr(_TLS, "summed", None), getattr(_TLS, "scans", None),
-             getattr(_TLS, "bd_sites", None))
+             getattr(_TLS, "bd_sites", None), getattr(_TLS, "kept", None))
     _TLS.notes = notes = []
     _TLS.masked = masked = []
     _TLS.summed = summed = []
     _TLS.scans = scans = []
     _TLS.bd_sites = bd_sites = []
+    _TLS.kept = kept = []
     try:
         yield
     finally:
         (_TLS.notes, _TLS.masked, _TLS.summed, _TLS.scans,
-         _TLS.bd_sites) = outer
+         _TLS.bd_sites, _TLS.kept) = outer
         if outer[0] is not None:    # an entry point traced inside another
             outer[0].extend(notes)
             outer[1].extend(masked)
             outer[2].extend(summed)
             outer[3].extend(scans)
             outer[4].extend(bd_sites)
+            outer[5].extend(kept)
         if _metrics.enabled():
             with _NOTES_LOCK:
                 _KERNEL_NOTES[fn_name] = notes
@@ -283,6 +288,16 @@ def tracing(fn_name: str) -> Iterator[None]:
                 "attention layers under the block-diffusion mask whose "
                 "products run the bd_flash kernels, in the newest trace "
                 "of the entry point").set(len(bd_sites), fn=fn_name)
+            _metrics.gauge(
+                "pt_remat_kept_sites",
+                "named results that recomputed layers keep across the "
+                "backward pass instead of making them again, in the "
+                "newest trace of the entry point").set(
+                    len(kept), fn=fn_name)
+            _metrics.gauge(
+                "pt_remat_kept_bytes",
+                "bytes of those results in one call of the entry "
+                "point").set(sum(kept), fn=fn_name)
 
 
 def note_kernel(name: str, flops: float, bytes_: float) -> None:
@@ -295,13 +310,17 @@ def note_kernel(name: str, flops: float, bytes_: float) -> None:
 
 
 @contextlib.contextmanager
-def unnoted() -> Iterator[None]:
-    """Kernels traced inside note nothing: for a trace whose calls the
-    caller knows the program will not run (a ``custom_vjp``'s forward
-    rule traced again by ``jax.checkpoint``'s recomputation, where
-    nothing reads its result). A noted site that never runs would make
-    a kernel's share of the peak unreadable."""
-    was, _TLS.notes = getattr(_TLS, "notes", None), None
+def unnoted(when: bool = True) -> Iterator[None]:
+    """Kernels traced inside note nothing (``when`` false: they note as
+    ever): for a trace whose calls the caller knows the program will not
+    run (a ``custom_vjp``'s forward rule traced again by
+    ``jax.checkpoint``'s recomputation, where nothing reads its result;
+    the function itself traced inside ``layer_primal()``). A noted site
+    that never runs would make a kernel's share of the peak
+    unreadable."""
+    was = getattr(_TLS, "notes", None)
+    if when:
+        _TLS.notes = None
     try:
         yield
     finally:
@@ -344,6 +363,34 @@ def note_bd_attention() -> None:
     sites = getattr(_TLS, "bd_sites", None)
     if sites is not None and _metrics.enabled():
         sites.append(1)
+
+
+def note_remat_kept(bytes_: int) -> None:
+    """Called by ``nn.recompute_layer``'s policy once per named result
+    it answers is kept, with the result's bytes. A no-op unless metrics
+    are on and a tracked entry point is being traced."""
+    kept = getattr(_TLS, "kept", None)
+    if kept is not None and _metrics.enabled():
+        kept.append(int(bytes_))
+
+
+@contextlib.contextmanager
+def layer_primal() -> Iterator[None]:
+    """Entered by ``nn.recompute_layer`` round the trace
+    ``jax.checkpoint`` makes of a layer. A gradient does not run that
+    trace of a ``custom_vjp`` function inside: it runs the function's
+    forward rule, traced later and outside this context, and where the
+    rule's results are kept, once. A kernel that both traces note
+    traces this one under ``unnoted(when=in_layer_primal())``."""
+    was, _TLS.layer_primal = in_layer_primal(), True
+    try:
+        yield
+    finally:
+        _TLS.layer_primal = was
+
+
+def in_layer_primal() -> bool:
+    return getattr(_TLS, "layer_primal", False)
 
 
 def kernel_notes(fn_name: str) -> List[KernelNote]:

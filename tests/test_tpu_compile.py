@@ -147,15 +147,16 @@ def test_a_block_diffusion_layers_gradient_runs_its_kernels_whole(
     """One layer of the block-diffusion decoder at the cell's widths
     (attention with q/k norm and rotary positions under the mask, then a
     softmax router over 16 held gated experts), recomputed in its
-    backward pass as the model runs it, steered onto the kernels' side
-    of the seams here (the process sees the CPU): the ``bd_flash``
-    kernels and the grouped matmuls of the gated experts (``w_gate`` and
-    ``w_up`` one [16, 2048, 1536] operand) compile, the forward kernels
-    run twice, and no [2L, 2L] array is in the optimized program."""
+    backward pass as the model runs it (``nn.recompute_layer``), steered
+    onto the kernels' side of the seams here (the process sees the CPU):
+    the ``bd_flash`` kernels and the grouped matmuls of the gated
+    experts (``w_gate`` and ``w_up`` one [16, 2048, 1536] operand)
+    compile, the forward kernel runs once (its result is kept), and no
+    [2L, 2L] array is in the optimized program."""
     import json
     import os
 
-    from paddle_tpu import kernels
+    from paddle_tpu import kernels, nn
     from paddle_tpu.models.sdar_moe import SdarMoeBlock, SdarMoeConfig
     from paddle_tpu.nn.layer import functional_call
     monkeypatch.setattr(kernels, "_on_tpu", lambda: True)
@@ -174,7 +175,7 @@ def test_a_block_diffusion_layers_gradient_runs_its_kernels_whole(
         def layer(h):
             y, stats = functional_call(block, p, buffers, h, pos)
             return y, stats["pairs_held"]
-        y, held = jax.checkpoint(layer)(x)
+        y, held = nn.recompute_layer(layer)(x)
         return jnp.sum(y.astype(jnp.float32)), held
 
     abstract = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -184,7 +185,7 @@ def test_a_block_diffusion_layers_gradient_runs_its_kernels_whole(
     compiled = jax.jit(jax.grad(loss, (0, 1), has_aux=True)).lower(
         abstract, x).compile()
     text = compiled.as_text()
-    assert len(_custom_calls(text, "bd_flash_fwd")) == 2
+    assert len(_custom_calls(text, "bd_flash_fwd")) == 1
     assert len(_custom_calls(text, "bd_flash_bwd_dq")) == 1
     assert len(_custom_calls(text, "bd_flash_bwd_dkv")) == 1
     assert _custom_calls(text, "moe_gmm") and _custom_calls(text, "moe_tgmm")
