@@ -263,7 +263,12 @@ def _enable_metrics_changed(value) -> None:
     # keep the observability module's cached fast-path bool in sync
     # (lazy import: observability imports this module)
     from .observability import metrics as _obs_metrics
+    was = _obs_metrics.enabled()
     _obs_metrics.set_enabled(bool(value))
+    if was and not value:
+        # the step timeline is taken down and says what it saw
+        from .observability import tracer as _obs_tracer
+        _obs_tracer.tracer().timeline_off()
 
 
 define_flag("enable_metrics", False,

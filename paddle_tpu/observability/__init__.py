@@ -145,7 +145,8 @@ def observe_traced(name: str, value: Any, kind: str = "gauge") -> None:
 
 
 def export_all(path: Optional[str] = None) -> Dict[str, str]:
-    """Write the host chrome trace + snapshots under ``path`` (default
+    """Write the host chrome trace, the step timeline
+    (``step_timeline.jsonl``) + snapshots under ``path`` (default
     FLAGS_trace_dir); returns written paths. Emits both the JSON
     snapshot (``metrics.json``: metrics + recompile + program cards +
     native stats) and the Prometheus text exposition (``metrics.prom``)
@@ -156,7 +157,8 @@ def export_all(path: Optional[str] = None) -> Dict[str, str]:
         from ..flags import GLOBAL_FLAGS
         path = GLOBAL_FLAGS.get("trace_dir") or "/tmp/pt_trace"
     os.makedirs(path, exist_ok=True)
-    out = {"trace": get_tracer().export(path)}
+    out = {"trace": get_tracer().export(path),
+           "timeline": get_tracer().export_timeline(path)}
     goodput_ledger().publish()
     snap = {"metrics": registry().snapshot(),
             "recompile": recompile_tracker().snapshot(),
@@ -176,7 +178,8 @@ def export_all(path: Optional[str] = None) -> Dict[str, str]:
 
 
 def reset_all() -> None:
-    """Clear metrics, spans, recompile records, program cards, anomaly
+    """Clear metrics, spans and the step timeline (stopping its watcher
+    thread), recompile records, program cards, anomaly
     state, the goodput ledger, the flight buffer, the request-span /
     seq-timeline / step-record rings, the fleet aggregator store, the
     tsdb sample ring (stopping its sampler thread), the SLO alert

@@ -5,7 +5,7 @@ exception, a wedged collective killed by a watchdog — the logs usually
 show *that* it died, not what the process was doing in the seconds
 before. The flight recorder answers that without a rerun: a lock-cheap
 in-process ring buffer keeps the last ``FLAGS_flight_buffer_events``
-structured events (step markers, recompiles, anomalies, ledger
+structured events (recompiles, anomalies, ledger
 transitions, straggler flags, elastic restarts), and installed
 signal/atexit/excepthook hooks dump it as ``flight_<ts>.jsonl`` under
 ``FLAGS_trace_dir`` together with a final metrics snapshot when the
@@ -18,8 +18,11 @@ lock — no serialization, no I/O. Dumps reuse :mod:`rotation` so
 repeated crashes keep only the newest two files.
 
 The dump file is line-parseable: a ``flight_header`` record first,
-one record per buffered event, and a closing ``final_metrics`` record
-carrying the registry + goodput snapshots.
+one record per buffered event, the step markers (the newest
+``step_record``s of the tracer's step timeline, one a dispatch of a
+train entry point: its host phases, its completion on the device, its
+own counters) and a closing ``final_metrics`` record carrying the
+registry + goodput snapshots.
 """
 
 from __future__ import annotations
@@ -40,6 +43,8 @@ from . import rotation as _rotation
 __all__ = ["FlightRecorder", "recorder", "record", "install", "dump"]
 
 _DEFAULT_CAPACITY = 512
+# the newest records of the tracer's step timeline that a dump carries
+_STEP_RECORDS = 64
 
 
 def _capacity() -> int:
@@ -135,10 +140,15 @@ class FlightRecorder:
             pass
         final = {"kind": "final_metrics", "ts_unix": time.time()}
         final.update(snap)
+        # the step markers: what the train entry points were doing last
+        # (phases, completion, the step's own counters), one a dispatch
+        from . import tracer as _tracer
+        steps = [dict(r, kind="step_record") for r in
+                 _tracer.tracer().timeline(last=_STEP_RECORDS)]
         try:
             os.makedirs(directory, exist_ok=True)
             with open(path, "w") as f:
-                for rec in [header] + events + [final]:
+                for rec in [header] + events + steps + [final]:
                     f.write(json.dumps(rec, default=str) + "\n")
         except OSError:
             return ""

@@ -31,6 +31,7 @@ import warnings
 from typing import Any, Callable, Dict, List, Optional
 
 from . import metrics as _metrics
+from . import tracer as _tracer
 from . import xprof as _xprof
 
 __all__ = ["RecompileTracker", "FunctionRecord", "tracker",
@@ -124,6 +125,10 @@ class FunctionRecord:
                 else:
                     threshold = None
         self._tls.traced = True
+        # the step timeline's pt/host/compile event of this trace:
+        # begun here, where tracing starts, ended by on_call
+        self._tls.compile_event = _tracer.tracer().host_event_begin(
+            _tracer.COMPILE_EVENT, what="trace", of=self.name)
         _metrics.counter(
             "jit_traces_total",
             "jit traces (recompilations) per function", always=True
@@ -233,10 +238,18 @@ class FunctionRecord:
             args, kwargs = kept["avals"]
             return kept["jitted"].lower(*args, **kwargs).compile()
 
-    def on_call(self, dt_s: float) -> bool:
-        """Classify the finished dispatch; returns True when it traced."""
+    def on_call(self, t0_ns: int) -> bool:
+        """Classify the dispatch that began at ``t0_ns``
+        (``time.perf_counter_ns``) and has just returned; returns True
+        when it traced. Only then is the clock read again."""
         traced = getattr(self._tls, "traced", False)
         self._tls.traced = False
+        dt_s = 0.0
+        if traced:
+            dt_s = (time.perf_counter_ns() - t0_ns) / 1e9
+            _tracer.tracer().host_event_end(
+                getattr(self._tls, "compile_event", None))
+            self._tls.compile_event = None
         with self._lock:
             self.calls += 1
             if traced:
@@ -281,9 +294,13 @@ class _InstrumentedJit:
             # call is not misclassified as a compile
             rec._tls.traced = False
             return self._jitted(*args, **kwargs)
-        t0 = time.perf_counter()
+        # inside a train entry point's dispatch phase the call is
+        # timed from that phase's own stamp
+        t0 = _tracer.tracer().dispatch_began_ns()
+        if t0 is None:
+            t0 = time.perf_counter_ns()
         out = self._jitted(*args, **kwargs)
-        traced = rec.on_call(time.perf_counter() - t0)
+        traced = rec.on_call(t0)
         if traced:
             rec.keep_call(self, args, kwargs)
             self._maybe_harvest(rec)

@@ -234,6 +234,7 @@ class ShardedTrainStep:
         from ..static import _defer_probes_default
         self._defer_probes = _defer_probes_default()
         self._pending_signals = []
+        self._dispatches = 0    # the step number of the timeline's records
         self.lr_scale = 1.0
 
         params = model.param_dict()
@@ -454,13 +455,12 @@ class ShardedTrainStep:
         # model-forward kwargs ride the batch like args (same contract
         # as TrainStep — e.g. BERT's masked_positions); their leaves
         # shard per batch_spec when shardable, else replicate
-        from ..observability import metrics as _obs_metrics
-        from ..observability import span as _obs_span
-        from ..static import inject_fault_mults
+        from ..static import inject_fault_mults, step_phases
 
-        # the entry point's host spans, as TrainStep's
+        # the step's record and host phases, as TrainStep's
         # (docs/observability.md)
-        with _obs_span("pt/train_step/make_batch"):
+        phases = step_phases(self, self._span_name)
+        with phases.phase("make_batch"):
             batch = inject_host_lr(
                 {"args": args, "labels": as_label_tuple(labels),
                  "kwargs": kwargs},
@@ -473,15 +473,13 @@ class ShardedTrainStep:
         # ask jax.sharding.get_abstract_mesh() which mesh it runs under
         # — kernels/_per_shard wraps each Mosaic kernel in a shard_map
         # over it, since GSPMD cannot partition one
-        with _obs_span("pt/train_step/dispatch", fn=self._span_name), \
+        with phases.phase("dispatch", fn=self._span_name), \
                 jax.sharding.set_mesh(self.mesh):
             self.state, metrics = self._jitted(self.state, batch)
-        if _obs_metrics.enabled():
-            _obs_metrics.counter("optimizer_steps_total",
-                                 "optimizer update steps applied").inc()
+        phases.dispatched(metrics)
         verdict = metrics.pop("_pt_nonfinite", None)
         if verdict is not None:
-            with _obs_span("pt/train_step/drain"):
+            with phases.phase("drain"):
                 self._pending_signals.append(verdict)
                 self.flush_signals(block=False)
         return metrics

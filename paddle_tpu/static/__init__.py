@@ -406,6 +406,16 @@ def _wire_param_meta(model, optimizer) -> None:
     if meta:
         optimizer.set_param_meta(meta)
 
+def step_phases(entry, fn: str):
+    """One dispatch of the train entry point ``entry`` (``TrainStep``,
+    ``ShardedTrainStep``): its own count of calls goes up by one and is
+    the step number of the record that the tracer opens for ``fn``, the
+    program dispatched. The caller opens the phases on what this
+    returns and hands it the step's metrics (``dispatched``)."""
+    entry._dispatches += 1
+    return _obs.get_tracer().step(fn, entry._dispatches)
+
+
 class TrainStep:
     """Compile model+loss+optimizer into one donated-state XLA program.
 
@@ -442,6 +452,7 @@ class TrainStep:
         # executable can be written to / read from FLAGS_compile_cache_dir
         self._defer_probes = _defer_probes_default()
         self._pending_signals = []
+        self._dispatches = 0    # the step number of the timeline's records
         # host-LR rescale applied on divergence-rollback re-entry
         # (FLAGS_rollback_lr_factor); changing it retraces once
         self.lr_scale = 1.0
@@ -580,21 +591,21 @@ class TrainStep:
             batch["lr_scale"] = jnp.float32(self.lr_scale)
         return batch
 
-    # Host spans of the whole entry point (docs/observability.md): on a
-    # profile's clock through TraceAnnotation, so a device-idle gap is
-    # charged to batch building, to the dispatch (host batch transfer
-    # included) or to the drain, which reads device buffers. One early
-    # return each while metrics are off.
+    # One record a dispatch in the tracer's step timeline, its three
+    # host phases the spans pt/train_step/{make_batch,dispatch,drain}
+    # on a profile's clock (docs/observability.md): a device-idle gap
+    # is charged to batch building, to the dispatch (host batch
+    # transfer included) or to the drain, which reads device buffers.
+    # One cached-bool check a call while metrics are off.
 
     def __call__(self, *args, labels=(), **kwargs):
-        with _obs.span("pt/train_step/make_batch"):
+        phases = step_phases(self, self._span_name)
+        with phases.phase("make_batch"):
             batch = self._make_batch(args, labels, kwargs)
-        with _obs.span("pt/train_step/dispatch", fn=self._span_name):
+        with phases.phase("dispatch", fn=self._span_name):
             self.state, metrics = self._jitted(self.state, batch)
-        if _obs.enabled():
-            _obs.counter("optimizer_steps_total",
-                         "optimizer update steps applied").inc()
-        with _obs.span("pt/train_step/drain"):
+        phases.dispatched(metrics)
+        with phases.phase("drain"):
             return self._drain_signals(metrics)
 
     def run_steps(self, *args, labels=(), **kwargs):
@@ -605,21 +616,17 @@ class TrainStep:
         scheduler's live value is held constant across the K steps of
         one dispatch (scheduler granularity becomes K steps)."""
         from ..parallel.spmd import host_lr_of
-        with _obs.span("pt/train_step/make_batch"):
+        phases = step_phases(self, self._span_name + ".multi")
+        with phases.phase("make_batch"):
             batch = {"args": args, "labels": as_label_tuple(labels),
                      "kwargs": kwargs}
             lr = host_lr_of(self.optimizer)
             lr = None if lr is None else jnp.float32(lr)
-        with _obs.span("pt/train_step/dispatch",
-                       fn=self._span_name + ".multi"):
+        with phases.phase("dispatch", fn=self._span_name + ".multi"):
             self.state, metrics = self._jitted_multi(self.state, batch,
                                                      lr)
-        if _obs.enabled():
-            k = next((int(a.shape[0]) for a in jax.tree.leaves(batch)
-                      if getattr(a, "ndim", 0)), 1)
-            _obs.counter("optimizer_steps_total",
-                         "optimizer update steps applied").inc(k)
-        with _obs.span("pt/train_step/drain"):
+        phases.dispatched(metrics, stacked=True)
+        with phases.phase("drain"):
             return self._drain_signals(metrics)
 
     # -- persistent-cache probe drain ------------------------------------

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 
 __all__ = ["get_include", "get_lib", "enable_compile_cache",
            "apply_compile_cache_flag", "compile_cache_stats"]
@@ -92,13 +93,38 @@ def _on_cache_event(event: str, **kw) -> None:
         _CACHE_STATS["misses"] += 1
 
 
+# jax.monitoring's duration events that the step timeline keeps as
+# pt/host/compile (observability/tracer.py), by what each one is
+_COMPILE_DURATIONS = {
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+
+
+def _on_duration_event(event: str, duration_secs: float, **kw) -> None:
+    what = _COMPILE_DURATIONS.get(event)
+    if what is None:
+        return
+    # the event is recorded as it ends, on the thread that compiled
+    from .observability import tracer as _tracer
+    end = time.perf_counter_ns()
+    _tracer.tracer().host_event(
+        _tracer.COMPILE_EVENT, end - int(duration_secs * 1e9), end,
+        what=what)
+
+
 def _install_cache_listener() -> None:
+    """The process's one listener on jax.monitoring: the cache's hit
+    and miss counts, and the compiles' durations for the step timeline
+    (kept only while metrics are on)."""
     global _LISTENER_INSTALLED
     with _LISTENER_LOCK:
         if _LISTENER_INSTALLED:
             return
         from jax import monitoring
         monitoring.register_event_listener(_on_cache_event)
+        monitoring.register_event_duration_secs_listener(
+            _on_duration_event)
         _LISTENER_INSTALLED = True
 
 

@@ -442,6 +442,9 @@ def test_trace_report_on_fit_output(metrics_on, tmp_path, capsys):
     # named after the step)
     for phase in ("make_batch", "dispatch", "drain"):
         assert f"pt/train_step/{phase}" in out
+    # and the step timeline's slowest-step table
+    assert "step timeline: slowest steps" in out
+    assert "TrainStep(_MLP) slowest of" in out
     assert "hapi_step_time_seconds" in out
 
 

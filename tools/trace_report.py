@@ -8,7 +8,9 @@ Usage:
 TRACE_DIR (default: FLAGS_trace_dir or /tmp/pt_trace) is what
 ``paddle_tpu.observability.export_all()`` / ``hapi.Model.fit`` with
 FLAGS_trace_dir wrote: ``host_trace.json`` (chrome traceEvents) and
-``metrics.json`` (metrics + recompile snapshot). With ``--xla`` (or
+``metrics.json`` (metrics + recompile snapshot), and
+``step_timeline.jsonl`` (the train entry points' step records and host
+events: its slowest steps are tabled). With ``--xla`` (or
 when XLA ``*.trace.json.gz`` files sit under TRACE_DIR, e.g. a
 jax.profiler capture into the same directory), device op events join
 the same table prefixed ``xla::`` and the device-op category rollup is
@@ -95,6 +97,44 @@ def _print_metrics_snapshot(trace_dir: str) -> None:
             print(f"  {k:<52} {native[k]}")
 
 
+def _print_step_timeline(trace_dir: str, top: int = 5) -> None:
+    """The slowest steps of each train entry point in
+    ``step_timeline.jsonl`` (the tracer's step timeline): the longest
+    intervals between two completions against the median, then what
+    the timeline knows of the slowest one."""
+    import statistics
+
+    from paddle_tpu.observability import tracer as pt_tracer
+    path = os.path.join(trace_dir, "step_timeline.jsonl")
+    if not os.path.exists(path):
+        return
+    with open(path) as f:
+        rows = [json.loads(line) for line in f if line.strip()]
+    events = [r for r in rows if r["kind"] == "event"]
+    by_fn = {}
+    for r in rows:
+        if r["kind"] == "step":
+            by_fn.setdefault(r["fn"], []).append(r)
+    if by_fn:
+        print("\n== step timeline: slowest steps ==")
+    for fn in sorted(by_fn):
+        records = by_fn[fn]
+        intervals = pt_tracer.done_intervals(records)
+        if not intervals:
+            print(f"  {fn}: {len(records)} record(s), no two completed "
+                  "steps to compare")
+            continue
+        median = statistics.median(iv for _, iv in intervals)
+        print(f"  {fn}: {len(records)} records, median interval "
+              f"{median / 1e6:.3f} ms")
+        print(f"    {'step':>8} {'interval ms':>12} {'excess ms':>10}")
+        for i, iv in sorted(intervals, key=lambda x: -x[1])[:top]:
+            print(f"    {records[i]['step']:>8} {iv / 1e6:>12.3f} "
+                  f"{(iv - median) / 1e6:>10.3f}")
+        print("    " + pt_tracer.format_slowest_step(
+            pt_tracer.slowest_step(records, events)))
+
+
 def report(trace_dir: str, xla: str = "", top: int = 30) -> int:
     host_events, host_path = _load_host_events(trace_dir)
     summary = {}
@@ -133,6 +173,7 @@ def report(trace_dir: str, xla: str = "", top: int = 30) -> int:
     print(trace_agg.format_span_table(summary, top=top,
                                       title="merged span summary"))
     _print_metrics_snapshot(trace_dir)
+    _print_step_timeline(trace_dir)
     return 0
 
 
