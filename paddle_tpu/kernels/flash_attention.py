@@ -198,10 +198,28 @@ def bd_allowed(q_pos, k_pos, seq: int, length: int, block: int):
     return jnp.logical_or(k_same == same, k_before < upto)
 
 
-def _bd_tile_valid(r0, block_q: int, c0, block_k: int, seq: int, bd):
-    q_pos = r0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-    k_pos = c0 + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-    return bd_allowed(q_pos, k_pos, seq, *bd)
+def _tile_positions(r0, block_q: int, c0, block_k: int):
+    """Full-tile (query, key) positions: what the plain and causal masks
+    compare, and what the dropout hash counts by."""
+    q_pos = r0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    k_pos = c0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    return q_pos, k_pos
+
+
+def _tile_valid(r0, block_q: int, c0, block_k: int, seq_k: int,
+                causal: bool, causal_offset: int, bd):
+    """[BQ, BK] bool: the pairs of an edge tile that are allowed and
+    real. The block-diffusion rule from the tile's edge vectors, the
+    tail and the causal masks from full-tile iotas."""
+    if bd is not None:
+        q_pos = r0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+        k_pos = c0 + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+        return bd_allowed(q_pos, k_pos, seq_k, *bd)
+    q_pos, k_pos = _tile_positions(r0, block_q, c0, block_k)
+    valid = k_pos < seq_k                              # tail-block mask
+    if causal:
+        valid = jnp.logical_and(valid, q_pos + causal_offset >= k_pos)
+    return valid
 
 
 def bd_key_tiles(r0, block_q: int, block_k: int, length: int, block: int):
@@ -256,17 +274,229 @@ def bd_query_tiles(c0, block_k: int, block_q: int, length: int,
         has_ck, jnp.maximum(c_hi - c_lo, 0), 0)
 
 
-def _bd_tile_of(t, runs):
-    """The ``t``-th tile of the two runs ``bd_*_tiles`` gave."""
-    n_lo, n_cnt, c_lo, _ = runs
-    return jnp.where(t < n_cnt, n_lo + t, c_lo + t - n_cnt)
-
-
 def bd_allowed_pairs(length: int, block: int) -> int:
     """Allowed (query, key) pairs of one sequence: ``L^2 + K L`` (noisy
     to noisy ``K L``, noisy to clean ``L (L - K) / 2``, clean to clean
     ``L (L + K) / 2``)."""
     return length * length + block * length
+
+
+# -- a visited tile's kind ------------------------------------------------------
+#
+# A tile whose every (query, key) pair is allowed and real needs no mask:
+# the loop body that walks it builds no position, compares nothing and
+# selects nothing. The two predicates say so from scalars alone (Python
+# ints for the census, int32 scalars in a kernel), and the walks below
+# cut every run of visited tiles into its whole tiles and its edges. To
+# call a whole tile an edge costs its mask; to call an edge whole is a
+# wrong gradient: a run of whole tiles is one on whose two ends the
+# predicate itself holds (`_cut_run`), whatever the closed form said.
+
+def bd_tile_whole(r0, block_q: int, c0, block_k: int, seq: int,
+                  length: int, block: int):
+    """Whether the block-diffusion rule allows every pair of the tile of
+    queries ``[r0, r0 + block_q)`` and keys ``[c0, c0 + block_k)``, all
+    of them real: clean keys, queries of one half, and the keys' last
+    block before the first query's ``upto`` (`bd_allowed`'s own terms,
+    on the tile's corner)."""
+    r1, c1 = r0 + block_q, c0 + block_k
+    last = (c1 - 1 - length) // block           # the keys' last block
+    noisy = (r1 <= length) & (last < r0 // block)
+    clean = (r0 >= length) & (r1 <= seq) & (last <= (r0 - length) // block)
+    return (c0 >= length) & (c1 <= seq) & (noisy | clean)
+
+
+def tile_whole(r0, c0, block_k: int, seq_k: int, causal: bool,
+               causal_offset: int = 0):
+    """The same for the plain and the causal walks: no key of the tile
+    past ``seq_k`` and, under the causal mask, its last key at or before
+    its first query plus ``causal_offset``. (A padded query row is not
+    masked by either walk: it is sliced off, and its dO is zero.)"""
+    c1 = c0 + block_k
+    if not causal:
+        return c1 <= seq_k
+    return (c1 <= seq_k) & (c1 - 1 <= r0 + causal_offset)
+
+
+def _cut_run(lo, cnt, w_lo, w_hi, whole):
+    """The run of tiles ``[lo, lo + cnt)`` cut at the whole tiles
+    ``[w_lo, w_hi)`` a closed form found in it: ``(whole run, [the
+    edges before, the edges after])``, each ``(first, count)``. The
+    whole tiles of a run are an interval (every term of the predicates
+    is monotone along a run), so where ``whole(tile)`` holds on both
+    ends it holds between them; where it does not, the run is all
+    edges."""
+    hi = lo + cnt
+    w_lo = jnp.clip(w_lo, lo, hi)
+    w_hi = jnp.clip(w_hi, w_lo, hi)
+    ok = (w_hi > w_lo) & whole(w_lo) & whole(w_hi - 1)
+    w_lo, w_hi = jnp.where(ok, w_lo, lo), jnp.where(ok, w_hi, lo)
+    return (w_lo, w_hi - w_lo), [(lo, w_lo - lo), (w_hi, hi - w_hi)]
+
+
+# the backward kernels walk the noisy diagonal in sub-tiles of this many
+# rows and keys
+_SUB = 128
+
+
+def _bd_aligned(block_q: int, block_k: int, seq_k: int, bd) -> bool:
+    """Square tiles, halves of whole tiles and tiles of whole blocks: a
+    query tile then visits its own noisy tile, a run of whole clean
+    tiles and one clean edge, and the counts are static."""
+    length, block = bd
+    return (block_q == block_k and length % block_q == 0
+            and block_q % block == 0 and seq_k == 2 * length)
+
+
+def _bd_subtiled(block_q: int, block_k: int, seq_k: int, bd) -> bool:
+    """Whether the dq and dk/dv kernels walk the noisy diagonal tile as
+    `_SUB` x `_SUB` problems on its own diagonal, a quarter of its work
+    at tiles of 512: aligned tiles of whole sub-tiles, blocks that
+    divide a sub-tile. (The forward kernel walks it whole under its
+    mask: in sub-tiles its row statistics cost more than the tile,
+    0.8 ms a call on the chip at PR 38.)"""
+    return (_bd_aligned(block_q, block_k, seq_k, bd)
+            and block_q % _SUB == 0 and block_q > _SUB
+            and _SUB % bd[1] == 0)
+
+
+def _key_walk(r0, block_q: int, block_k: int, seq_q: int, seq_k: int,
+              causal: bool, bd):
+    """The key tiles that the queries ``[r0, r0 + block_q)`` visit, by
+    kind: ``(whole, edges, diagonal)``, three lists of ``(first,
+    count)`` runs; ``diagonal`` is the noisy diagonal tile under the
+    aligned block-diffusion walk (`_bd_aligned`), its count a bool. The
+    forward and the dq kernels walk it, and the census counts it."""
+    if bd is not None and _bd_aligned(block_q, block_k, seq_k, bd):
+        half = bd[0] // block_q           # tiles a half
+        i = r0 // block_q
+        own = jnp.where(i < half, i, i - half)
+        # the clean tiles before its own whole, its own clean tile an
+        # edge; a noisy query tile's noisy keys are its own tile
+        return [(half, own)], [(half + own, 1)], [(i, i < half)]
+    if bd is not None:
+        length, block = bd
+        n_lo, n_cnt, c_lo, c_cnt = bd_key_tiles(r0, block_q, block_k, *bd)
+        # clean keys before the first query's `upto`, in whole tiles
+        upto = jnp.where(r0 < length, r0 // block,
+                         (r0 - length) // block + 1)
+        run, edges = _cut_run(
+            c_lo, c_cnt, -(-length // block_k),
+            jnp.minimum(length + upto * block, seq_k) // block_k,
+            lambda j: bd_tile_whole(r0, block_q, j * block_k, block_k,
+                                    seq_k, length, block))
+        return [run], [(n_lo, n_cnt)] + edges, []
+    num_k = -(-seq_k // block_k)
+    if not causal:           # static: every tile but one that holds a tail
+        return [(0, seq_k // block_k)], [(seq_k // block_k,
+                                          num_k - seq_k // block_k)], []
+    # bottom-right alignment: query i sees the keys [0, i + offset]; only
+    # the tiles that meet the block's visible range are visited
+    offset = seq_k - seq_q
+    if offset == 0 and block_q == block_k:
+        # square tiles on the diagonal: one edge a query tile, its own
+        return [(0, r0 // block_k)], [(r0 // block_k, 1)], []
+    upper = jnp.clip((r0 + block_q - 1 + offset) // block_k + 1, 1, num_k)
+    run, edges = _cut_run(
+        0, upper, 0, jnp.minimum(r0 + offset + 1, seq_k) // block_k,
+        lambda j: tile_whole(r0, j * block_k, block_k, seq_k, True, offset))
+    return [run], edges, []
+
+
+def _query_walk(c0, block_k: int, block_q: int, num_q: int, seq_k: int,
+                causal: bool, offset: int, bd):
+    """The query tiles that see the keys ``[c0, c0 + block_k)``, by kind,
+    as `_key_walk` gives the key tiles: the dk/dv kernel's walk over the
+    ``num_q`` tiles of the padded queries. Where ``diagonal``'s count is
+    true the keys are noisy, seen by their own query tile and no other,
+    and the other runs are not to be walked."""
+    if bd is not None and _bd_aligned(block_q, block_k, seq_k, bd):
+        half = bd[0] // block_q           # tiles a half
+        j = c0 // block_k
+        own = j - half
+        # a noisy key tile is seen by its own query tile alone (the
+        # diagonal); a clean one by the noisy and the clean tile of its
+        # index under a mask, and by every later tile of both halves whole
+        later = half - own - 1
+        return [(own + 1, later), (half + own + 1, later)], \
+            [(own, 1), (half + own, 1)], [(j, j < half)]
+    if bd is not None:
+        length, block = bd
+        n_lo, n_cnt, c_lo, c_cnt = bd_query_tiles(c0, block_k, block_q, *bd)
+        last = (c0 + block_k - 1 - length) // block   # the keys' last block
+
+        def whole(i):
+            return bd_tile_whole(i * block_q, block_q, c0, block_k, seq_k,
+                                 length, block)
+        # the noisy rows of later blocks, the clean rows of its own and
+        # later ones, each in whole tiles of one half
+        noisy, n_edges = _cut_run(
+            n_lo, n_cnt, ((last + 1) * block + block_q - 1) // block_q,
+            length // block_q, whole)
+        clean, c_edges = _cut_run(
+            c_lo, c_cnt, (length + last * block + block_q - 1) // block_q,
+            seq_k // block_q, whole)
+        return [noisy, clean], n_edges + c_edges, []
+    if not causal:
+        if seq_k % block_k == 0:
+            return [(0, num_q)], [], []
+        tail = c0 + block_k > seq_k          # the last key tile's program
+        return [(0, jnp.where(tail, 0, num_q))], \
+            [(0, jnp.where(tail, num_q, 0))], []
+    if offset == 0 and block_q == block_k:
+        first = c0 // block_q
+        return [(first + 1, num_q - first - 1)], [(first, 1)], []
+    # first q block whose last visible key reaches this k block:
+    # q_pos + offset >= c0  =>  q_pos >= c0 - offset
+    lower = jnp.clip((c0 - offset) // block_q, 0, num_q)
+    run, edges = _cut_run(
+        lower, num_q - lower,
+        (c0 + block_k - 1 - offset + block_q - 1) // block_q, num_q,
+        lambda i: tile_whole(i * block_q, c0, block_k, seq_k, True, offset))
+    return [run], edges, []
+
+
+def _walk(runs, body, carry):
+    """``body(tile, carry)`` over the tiles of ``runs``, in their order:
+    one ``fori_loop``; none where the runs are empty by their static
+    counts, or single tiles by them (a loop round one tile costs a
+    program half a tile's time)."""
+    runs = [r for r in runs if not (isinstance(r[1], int) and r[1] == 0)]
+    if not runs:
+        return carry
+    if all(isinstance(cnt, int) and cnt == 1 for _, cnt in runs):
+        for lo, _ in runs:                      # single tiles: no loop
+            carry = body(lo, carry)
+        return carry
+
+    def tile_of(t):
+        tile, base = runs[0][0] + t, runs[0][1]
+        for lo, cnt in runs[1:]:      # the last run that starts at or before t
+            tile = jnp.where(t < base, tile, lo + t - base)
+            base = base + cnt
+        return tile
+
+    return jax.lax.fori_loop(0, sum(cnt for _, cnt in runs),
+                             lambda t, c: body(tile_of(t), c), carry)
+
+
+@functools.lru_cache(maxsize=None)
+def flash_tile_census(tq: int, tk: int, bq: int, bk: int,
+                      causal: bool = False, bd=None):
+    """``(visited, whole, diagonal)``: the key tiles that one head and
+    sequence's forward call visits, those of them walked without a mask,
+    and those walked as noisy diagonal sub-tiles. From static shapes, by
+    the walk the kernels themselves run."""
+    visited = whole = diagonal = 0
+    with jax.ensure_compile_time_eval():
+        for r0 in range(0, tq, bq):
+            runs, edges, diag = _key_walk(r0, bq, bk, tq, tk, causal, bd)
+            whole += sum(int(cnt) for _, cnt in runs)
+            diagonal += sum(int(cnt) for _, cnt in diag)
+            visited += sum(int(cnt) for _, cnt in runs + edges + diag)
+    if bd is None or not _bd_subtiled(bq, bk, tk, bd):
+        diagonal = 0            # walked whole under its mask, as an edge
+    return visited, whole, diagonal
 
 
 def _dropout_keep(seed, g, q_pos, k_pos, dropout_p: float):
@@ -309,31 +539,22 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bias_ref, o_ref,
     q2 = q_ref[0].astype(jnp.float32) * scale        # [BQ, hpb*D]
     block_q = q2.shape[0]
     g = pl.program_id(0)
-    i_q = pl.program_id(1)
-
-    num_k = pl.cdiv(seq_k, block_k)
+    r0 = pl.program_id(1) * block_q
     # bottom-right causal alignment (matches the XLA reference and the
     # backward): query i attends keys [0, i + seq_k - seq_q]
     causal_offset = seq_k - seq_q
 
-    def body(j, carry):
+    def body(j, carry, masked):
         accs, ms, ls = carry
         k2 = k_ref[0, pl.ds(j * block_k, block_k), :] \
             .astype(jnp.float32)
         v2 = v_ref[0, pl.ds(j * block_k, block_k), :] \
             .astype(jnp.float32)
-        if bd is not None:
-            valid = _bd_tile_valid(i_q * block_q, block_q, j * block_k,
-                                   block_k, seq_k, bd)
-        else:
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            valid = k_pos < seq_k                      # tail-block mask
-            q_pos = i_q * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            if causal:
-                valid = jnp.logical_and(valid,
-                                        q_pos + causal_offset >= k_pos)
+        valid = _tile_valid(r0, block_q, j * block_k, block_k, seq_k,
+                            causal, causal_offset, bd) if masked else None
+        if dropout_p > 0.0:                            # the hash's own
+            q_pos, k_pos = _tile_positions(r0, block_q, j * block_k,
+                                           block_k)
         bias = bias_ref[0, :, pl.ds(j * block_k, block_k)] \
             if has_bias else None
         new = ([], [], [])
@@ -345,7 +566,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bias_ref, o_ref,
             if has_bias:
                 # [1, BK] additive key bias (this batch row) broadcasts
                 s = s + bias
-            s = jnp.where(valid, s, _NEG_INF)
+            if masked:
+                s = jnp.where(valid, s, _NEG_INF)
             m_cur = jnp.max(s, axis=-1, keepdims=True)  # [BQ, 1]
             m_new = jnp.maximum(ms[half], m_cur)
             p = jnp.exp(s - m_new)
@@ -373,24 +595,18 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bias_ref, o_ref,
                for _ in range(hpb))
     l0 = tuple(jnp.zeros((block_q, 1), jnp.float32)
                for _ in range(hpb))
-    if bd is not None:
-        # only the tiles that hold an allowed pair: a run of noisy key
-        # tiles, then a run of clean ones
-        runs = bd_key_tiles(i_q * block_q, block_q, block_k, *bd)
-        accs, m_fin, l_fin = jax.lax.fori_loop(
-            0, runs[1] + runs[3],
-            lambda t, carry: body(_bd_tile_of(t, runs), carry),
-            (acc0, m0, l0))
-    else:
-        if causal:
-            # only scan K blocks that intersect this Q block's visible
-            # range
-            max_k = (i_q + 1) * block_q - 1 + causal_offset
-            upper = jnp.clip(max_k // block_k + 1, 1, num_k)
-        else:
-            upper = num_k
-        accs, m_fin, l_fin = jax.lax.fori_loop(0, upper, body,
-                                               (acc0, m0, l0))
+    # the noisy diagonal first (it makes the carry or leaves it), the
+    # whole tiles without a mask, then the edges under theirs
+    whole, edges, diag = _key_walk(r0, block_q, block_k, seq_q, seq_k,
+                                   causal, bd)
+    carry = (acc0, m0, l0)
+    for tile, noisy in diag:
+        carry = jax.lax.cond(
+            noisy, functools.partial(body, tile, masked=True),
+            lambda c: c, carry)
+    carry = _walk(whole, functools.partial(body, masked=False), carry)
+    accs, m_fin, l_fin = _walk(edges, functools.partial(body, masked=True),
+                               carry)
     outs, lses = [], []
     for half in range(hpb):
         safe_l = jnp.maximum(l_fin[half], 1e-30)
@@ -522,6 +738,7 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
     )(qr, kr, vr, _seed_arr(seed), _bias_arr(kv_bias, b, tk, tk_p))
     note_kernel(_BD_PREFIX * (bd is not None) + "flash_fwd", *flash_fwd_work(
         b, h, tq, tk, d, q.dtype.itemsize, causal, bd=bd))
+    xprof.note_flash_tiles(*flash_tile_census(tq, tk, bq, bk, causal, bd))
     # what `nn.recompute_layer` keeps of a recomputed layer: the backward
     # kernels read both, and making either again is this whole call. The
     # output is named as the kernel wrote it (kept under the caller's
@@ -609,19 +826,23 @@ def _fwd(q, k, v, causal, scale, interpret, dropout_p, seed, kv_bias,
 
 
 def _grad_core(q_h, k_h, v_h, do_h, lse_col, delta_col, valid, bias,
-               seed_ref, head_id, q_pos, k_pos, *, scale: float,
-               dropout_p: float, has_bias: bool):
+               seed_ref, head_id, q_pos, k_pos, *, dropout_p: float,
+               has_bias: bool):
     """The backward's shared per-head-slab math — ONE home for the
     s/bias/mask/p/dp/dropout/dsc chain so the scanning kernels and the
-    fused single-block kernel cannot diverge. Returns ``(p_v, dsc)``:
-    ``p_v`` is the dropped+rescaled probs (dv's operand), ``dsc`` the
-    score cotangent (dq's and dk's operand)."""
+    fused single-block kernel cannot diverge. ``q_h`` is the query
+    times ``scale`` in float32, as the forward made it, so ``s`` is the
+    forward's bit for bit; ``valid`` is ``None`` on a whole tile.
+    Returns ``(p_v, dsc)``: ``p_v`` is the dropped+rescaled probs (dv's
+    operand), ``dsc`` the cotangent of ``s`` (dk's operand against the
+    scaled query; dq's against k, its sum scaled once by the caller)."""
     s = jax.lax.dot_general(
         q_h, k_h, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale      # [BQ, BK]
+        preferred_element_type=jnp.float32)              # [BQ, BK]
     if has_bias:
         s = s + bias
-    s = jnp.where(valid, s, _NEG_INF)
+    if valid is not None:
+        s = jnp.where(valid, s, _NEG_INF)
     p = jnp.exp(s - lse_col)                             # probs, 0 at -inf
     dp = jax.lax.dot_general(
         do_h, v_h, (((1,), (1,)), ((), ())),
@@ -636,7 +857,7 @@ def _grad_core(q_h, k_h, v_h, do_h, lse_col, delta_col, valid, bias,
         dp = jnp.where(keep, dp / inv, 0.0)
     else:
         p_v = p
-    dsc = p * (dp - delta_col) * scale
+    dsc = p * (dp - delta_col)
     return p_v, dsc
 
 
@@ -645,34 +866,24 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    causal: bool, block_k: int, seq_k: int, seq_q: int,
                    dropout_p: float, has_bias: bool, d_head: int,
                    hpb: int, n_heads: int, bd=None):
-    q2 = q_ref[0].astype(jnp.float32)                  # [BQ, hpb*D]
+    q2 = q_ref[0].astype(jnp.float32) * scale          # [BQ, hpb*D]
     do2 = do_ref[0].astype(jnp.float32)                # [BQ, hpb*D]
     lse2 = lse_ref[0]                                  # [BQ, hpb] f32
     delta2 = delta_ref[0]                              # [BQ, hpb] f32
     block_q = q2.shape[0]
     g = pl.program_id(0)
-    i_q = pl.program_id(1)
-    num_k = pl.cdiv(seq_k, block_k)
+    r0 = pl.program_id(1) * block_q
     causal_offset = seq_k - seq_q
 
-    def body(j, dq_accs):
+    def body(j, dq_accs, masked):
         k2 = k_ref[0, pl.ds(j * block_k, block_k), :] \
             .astype(jnp.float32)
         v2 = v_ref[0, pl.ds(j * block_k, block_k), :] \
             .astype(jnp.float32)
-        if bd is not None:
-            valid = _bd_tile_valid(i_q * block_q, block_q, j * block_k,
-                                   block_k, seq_k, bd)
-            q_pos = k_pos = None           # dropout's, which bd has not
-        else:
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            valid = k_pos < seq_k
-            q_pos = i_q * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            if causal:
-                valid = jnp.logical_and(valid,
-                                        q_pos + causal_offset >= k_pos)
+        valid = _tile_valid(r0, block_q, j * block_k, block_k, seq_k,
+                            causal, causal_offset, bd) if masked else None
+        q_pos, k_pos = _tile_positions(r0, block_q, j * block_k, block_k) \
+            if dropout_p > 0.0 else (None, None)       # the hash's own
         bias = bias_ref[0, :, pl.ds(j * block_k, block_k)] \
             if has_bias else None
         out = []
@@ -683,28 +894,48 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 lse2[:, half:half + 1], delta2[:, half:half + 1],
                 valid, bias, seed_ref,
                 _head_id(g, half, hpb, n_heads), q_pos, k_pos,
-                scale=scale, dropout_p=dropout_p, has_bias=has_bias)
+                dropout_p=dropout_p, has_bias=has_bias)
             out.append(dq_accs[half] + jax.lax.dot_general(
                 dsc, k2[:, sl], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32))
         return tuple(out)
 
-    if causal:
-        max_k = (i_q + 1) * block_q - 1 + causal_offset
-        upper = jnp.clip(max_k // block_k + 1, 1, num_k)
-    else:
-        upper = num_k
+    def diagonal(j, dq_accs):
+        """The noisy diagonal tile in sub-tiles (`_bd_subtiled`: one head
+        a program): `_SUB` rows see `_SUB` keys, the tile's own
+        diagonal."""
+        new = []
+        for lo in range(0, block_q, _SUB):
+            rows = slice(lo, lo + _SUB)
+            c0 = j * block_k + lo
+            k2 = k_ref[0, pl.ds(c0, _SUB), :].astype(jnp.float32)
+            v2 = v_ref[0, pl.ds(c0, _SUB), :].astype(jnp.float32)
+            valid = _tile_valid(r0 + lo, _SUB, c0, _SUB, seq_k, False, 0,
+                                bd)
+            _, dsc = _grad_core(
+                q2[rows], k2, v2, do2[rows], lse2[rows], delta2[rows],
+                valid, None, seed_ref, None, None, None, dropout_p=0.0,
+                has_bias=False)
+            new.append(dq_accs[0][rows] + jax.lax.dot_general(
+                dsc, k2, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        return (jnp.concatenate(new, axis=0),)
+
     dq0 = tuple(jnp.zeros((block_q, d_head), jnp.float32)
                 for _ in range(hpb))
-    if bd is not None:
-        runs = bd_key_tiles(i_q * block_q, block_q, block_k, *bd)
-        dqs = jax.lax.fori_loop(
-            0, runs[1] + runs[3],
-            lambda t, acc: body(_bd_tile_of(t, runs), acc), dq0)
-    else:
-        dqs = jax.lax.fori_loop(0, upper, body, dq0)
-    dq_ref[0] = (jnp.concatenate(dqs, axis=1) if hpb > 1 else dqs[0]) \
-        .astype(dq_ref.dtype)
+    whole, edges, diag = _key_walk(r0, block_q, block_k, seq_q, seq_k,
+                                   causal, bd)
+    dqs = dq0
+    own = diagonal if bd is not None and _bd_subtiled(
+        block_q, block_k, seq_k, bd) else functools.partial(body, masked=True)
+    for tile, noisy in diag:     # first, as the forward's
+        dqs = jax.lax.cond(noisy, functools.partial(own, tile),
+                           lambda c: c, dqs)
+    dqs = _walk(whole, functools.partial(body, masked=False), dqs)
+    dqs = _walk(edges, functools.partial(body, masked=True), dqs)
+    # s = (q * scale) k^T: the scale of dq, once on its [BQ, D] sum
+    dq_ref[0] = ((jnp.concatenate(dqs, axis=1) if hpb > 1 else dqs[0])
+                 * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -719,32 +950,23 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     v2 = v_ref[0].astype(jnp.float32)                  # [BK, hpb*D]
     block_k = k2.shape[0]
     g = pl.program_id(0)
-    j_k = pl.program_id(1)
-    seq_q_pad = q_ref.shape[1]
-    num_q = seq_q_pad // block_q
+    c0 = pl.program_id(1) * block_k
+    num_q = q_ref.shape[1] // block_q
     causal_offset = seq_k - seq_q
 
-    def body(i, carry):
+    def body(i, carry, masked):
         dk_accs, dv_accs = carry
+        # the query times scale, as the forward's: dk = dsc^T (q * scale)
         q2 = q_ref[0, pl.ds(i * block_q, block_q), :] \
-            .astype(jnp.float32)
+            .astype(jnp.float32) * scale
         do2 = do_ref[0, pl.ds(i * block_q, block_q), :] \
             .astype(jnp.float32)
         lse2 = lse_ref[0, pl.ds(i * block_q, block_q), :]  # [BQ, hpb]
         delta2 = delta_ref[0, pl.ds(i * block_q, block_q), :]
-        if bd is not None:
-            valid = _bd_tile_valid(i * block_q, block_q, j_k * block_k,
-                                   block_k, seq_k, bd)
-            q_pos = k_pos = None           # dropout's, which bd has not
-        else:
-            k_pos = j_k * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            valid = k_pos < seq_k
-            q_pos = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            if causal:
-                valid = jnp.logical_and(valid,
-                                        q_pos + causal_offset >= k_pos)
+        valid = _tile_valid(i * block_q, block_q, c0, block_k, seq_k,
+                            causal, causal_offset, bd) if masked else None
+        q_pos, k_pos = _tile_positions(i * block_q, block_q, c0, block_k) \
+            if dropout_p > 0.0 else (None, None)       # the hash's own
         new_dk, new_dv = [], []
         for half in range(hpb):
             sl = slice(half * d_head, (half + 1) * d_head)
@@ -755,7 +977,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 lse2[:, half:half + 1], delta2[:, half:half + 1],
                 valid, bias_ref[0] if has_bias else None, seed_ref,
                 _head_id(g, half, hpb, n_heads), q_pos, k_pos,
-                scale=scale, dropout_p=dropout_p, has_bias=has_bias)
+                dropout_p=dropout_p, has_bias=has_bias)
             new_dv.append(dv_accs[half] + jax.lax.dot_general(
                 p_v, do2[:, sl], (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32))        # [BK, D]
@@ -764,23 +986,50 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 preferred_element_type=jnp.float32))        # [BK, D]
         return tuple(new_dk), tuple(new_dv)
 
-    if causal:
-        # first q block whose last visible key reaches this k block:
-        # q_pos + offset >= j*BK  =>  q_pos >= j*BK - offset
-        lower = jnp.clip((j_k * block_k - causal_offset) // block_q,
-                         0, num_q)
-    else:
-        lower = 0
+    def diagonal(i, carry):
+        """A noisy key tile's own query tile in sub-tiles: `_SUB` keys
+        are seen by the `_SUB` queries on the tile's diagonal."""
+        (dk,), (dv,) = carry
+        new_dk, new_dv = [], []
+        for lo in range(0, block_k, _SUB):
+            keys = slice(lo, lo + _SUB)
+            r0 = i * block_q + lo
+            q2 = q_ref[0, pl.ds(r0, _SUB), :].astype(jnp.float32) * scale
+            do2 = do_ref[0, pl.ds(r0, _SUB), :].astype(jnp.float32)
+            valid = _tile_valid(r0, _SUB, c0 + lo, _SUB, seq_k, False, 0,
+                                bd)
+            p_v, dsc = _grad_core(
+                q2, k2[keys], v2[keys], do2,
+                lse_ref[0, pl.ds(r0, _SUB), :],
+                delta_ref[0, pl.ds(r0, _SUB), :], valid, None, seed_ref,
+                None, None, None, dropout_p=0.0, has_bias=False)
+            new_dv.append(dv[keys] + jax.lax.dot_general(
+                p_v, do2, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+            new_dk.append(dk[keys] + jax.lax.dot_general(
+                dsc, q2, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        return ((jnp.concatenate(new_dk, axis=0),),
+                (jnp.concatenate(new_dv, axis=0),))
+
     zeros = tuple(jnp.zeros((block_k, d_head), jnp.float32)
                   for _ in range(hpb))
-    if bd is not None:
-        runs = bd_query_tiles(j_k * block_k, block_k, block_q, *bd)
-        dks, dvs = jax.lax.fori_loop(
-            0, runs[1] + runs[3],
-            lambda t, carry: body(_bd_tile_of(t, runs), carry),
-            (zeros, zeros))
+    whole, edges, diag = _query_walk(c0, block_k, block_q, num_q, seq_k,
+                                     causal, causal_offset, bd)
+
+    def seen_by_others():
+        carry = _walk(whole, functools.partial(body, masked=False),
+                      (zeros, zeros))
+        return _walk(edges, functools.partial(body, masked=True), carry)
+
+    if diag:          # a noisy key tile: its own query tile and no other
+        (tile, noisy), = diag
+        own = diagonal if _bd_subtiled(block_q, block_k, seq_k, bd) \
+            else functools.partial(body, masked=True)
+        dks, dvs = jax.lax.cond(
+            noisy, lambda: own(tile, (zeros, zeros)), seen_by_others)
     else:
-        dks, dvs = jax.lax.fori_loop(lower, num_q, body, (zeros, zeros))
+        dks, dvs = seen_by_others()
     dk_ref[0] = (jnp.concatenate(dks, axis=1) if hpb > 1 else dks[0]) \
         .astype(dk_ref.dtype)
     dv_ref[0] = (jnp.concatenate(dvs, axis=1) if hpb > 1 else dvs[0]) \
@@ -800,7 +1049,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     set of DMAs, no duplicated softmax/mask/dropout work. The r5 b16
     profile put the flash custom-calls at 11.8 ms/step (20.6%), so
     the duplicated backward half is real step time."""
-    q2 = q_ref[0].astype(jnp.float32)                  # [BQ, hpb*D]
+    q2 = q_ref[0].astype(jnp.float32) * scale          # [BQ, hpb*D]
     k2 = k_ref[0].astype(jnp.float32)                  # [BK, hpb*D]
     v2 = v_ref[0].astype(jnp.float32)
     do2 = do_ref[0].astype(jnp.float32)
@@ -808,16 +1057,13 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     delta2 = delta_ref[0]
     block_q, block_k = q2.shape[0], k2.shape[0]
     g = pl.program_id(0)
-    if bd is not None:
-        valid = _bd_tile_valid(0, block_q, 0, block_k, seq_k, bd)
-        q_pos = k_pos = None               # dropout's, which bd has not
-    else:
-        k_pos = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        valid = k_pos < seq_k
-        q_pos = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        if causal:
-            valid = jnp.logical_and(
-                valid, q_pos + (seq_k - seq_q) >= k_pos)
+    # the one tile is whole, statically, where no key is padding and no
+    # mask is asked for
+    valid = None if bd is None and not causal and seq_k == block_k else \
+        _tile_valid(0, block_q, 0, block_k, seq_k, causal, seq_k - seq_q,
+                    bd)
+    q_pos, k_pos = _tile_positions(0, block_q, 0, block_k) \
+        if dropout_p > 0.0 else (None, None)           # the hash's own
     dqs, dks, dvs = [], [], []
     for half in range(hpb):
         sl = slice(half * d_head, (half + 1) * d_head)
@@ -826,7 +1072,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             lse2[:, half:half + 1], delta2[:, half:half + 1],
             valid, bias_ref[0] if has_bias else None, seed_ref,
             _head_id(g, half, hpb, n_heads), q_pos, k_pos,
-            scale=scale, dropout_p=dropout_p, has_bias=has_bias)
+            dropout_p=dropout_p, has_bias=has_bias)
         dvs.append(jax.lax.dot_general(
             p_v, do2[:, sl], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32))           # [BK, D]
@@ -835,7 +1081,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32))           # [BK, D]
         dqs.append(jax.lax.dot_general(
             dsc, k2[:, sl], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32))           # [BQ, D]
+            preferred_element_type=jnp.float32) * scale)   # [BQ, D]
     cat = (lambda xs: jnp.concatenate(xs, axis=1)) if hpb > 1 \
         else (lambda xs: xs[0])
     dq_ref[0] = cat(dqs).astype(dq_ref.dtype)

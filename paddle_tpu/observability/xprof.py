@@ -44,6 +44,12 @@ metrics are on, all at trace time and nothing per step:
 - ``note_bd_attention``: each attention layer under the block-diffusion
   mask whose products run the ``bd_flash_*`` kernels notes itself,
   published as ``pt_bd_attention_sites``;
+- ``note_flash_tiles``: each flash attention forward call site notes
+  the key tiles one head and sequence of it visits, those of them it
+  walks without a mask and those it walks as noisy diagonal sub-tiles
+  (``kernels.flash_attention.flash_tile_census``), published summed as
+  ``pt_flash_tiles_visited`` / ``pt_flash_tiles_whole`` /
+  ``pt_flash_tiles_diagonal``;
 - ``note_remat_kept``: each named result that a layer's recomputation
   keeps (``nn.recompute_layer``'s policy) notes its bytes, published as
   ``pt_remat_kept_sites`` / ``pt_remat_kept_bytes``;
@@ -67,7 +73,8 @@ from . import metrics as _metrics
 __all__ = ["ProgramCardRegistry", "cards", "enabled", "harvest",
            "flops_of", "note_kernel", "note_dropout_mask",
            "note_qkv_grad_summed", "note_ssd_scan_kernel",
-           "note_bd_attention", "note_remat_kept", "kernel_notes",
+           "note_bd_attention", "note_flash_tiles", "note_remat_kept",
+           "kernel_notes",
            "op_scopes", "parse_op_names"]
 
 # Cost-analysis keys promoted onto the card top level when present.
@@ -242,18 +249,20 @@ def tracing(fn_name: str) -> Iterator[None]:
     must not count a call site twice)."""
     outer = (getattr(_TLS, "notes", None), getattr(_TLS, "masked", None),
              getattr(_TLS, "summed", None), getattr(_TLS, "scans", None),
-             getattr(_TLS, "bd_sites", None), getattr(_TLS, "kept", None))
+             getattr(_TLS, "bd_sites", None), getattr(_TLS, "kept", None),
+             getattr(_TLS, "tiles", None))
     _TLS.notes = notes = []
     _TLS.masked = masked = []
     _TLS.summed = summed = []
     _TLS.scans = scans = []
     _TLS.bd_sites = bd_sites = []
     _TLS.kept = kept = []
+    _TLS.tiles = tiles = []
     try:
         yield
     finally:
         (_TLS.notes, _TLS.masked, _TLS.summed, _TLS.scans,
-         _TLS.bd_sites, _TLS.kept) = outer
+         _TLS.bd_sites, _TLS.kept, _TLS.tiles) = outer
         if outer[0] is not None:    # an entry point traced inside another
             outer[0].extend(notes)
             outer[1].extend(masked)
@@ -261,6 +270,7 @@ def tracing(fn_name: str) -> Iterator[None]:
             outer[3].extend(scans)
             outer[4].extend(bd_sites)
             outer[5].extend(kept)
+            outer[6].extend(tiles)
         if _metrics.enabled():
             with _NOTES_LOCK:
                 _KERNEL_NOTES[fn_name] = notes
@@ -288,6 +298,22 @@ def tracing(fn_name: str) -> Iterator[None]:
                 "attention layers under the block-diffusion mask whose "
                 "products run the bd_flash kernels, in the newest trace "
                 "of the entry point").set(len(bd_sites), fn=fn_name)
+            _metrics.gauge(
+                "pt_flash_tiles_visited",
+                "key tiles that the flash attention forward call sites "
+                "visit, a head and sequence each, summed over the sites "
+                "of the newest trace of the entry point").set(
+                    sum(t[0] for t in tiles), fn=fn_name)
+            _metrics.gauge(
+                "pt_flash_tiles_whole",
+                "those of the visited tiles walked without a mask, "
+                "every pair of them allowed and real").set(
+                    sum(t[1] for t in tiles), fn=fn_name)
+            _metrics.gauge(
+                "pt_flash_tiles_diagonal",
+                "those of the visited tiles walked as noisy diagonal "
+                "sub-tiles under the block-diffusion mask").set(
+                    sum(t[2] for t in tiles), fn=fn_name)
             _metrics.gauge(
                 "pt_remat_kept_sites",
                 "named results that recomputed layers keep across the "
@@ -363,6 +389,17 @@ def note_bd_attention() -> None:
     sites = getattr(_TLS, "bd_sites", None)
     if sites is not None and _metrics.enabled():
         sites.append(1)
+
+
+def note_flash_tiles(visited: int, whole: int, diagonal: int) -> None:
+    """Called beside a flash attention forward call's ``note_kernel``
+    with the census of its tile walk, and silent where that is
+    (``unnoted``). A no-op unless metrics are on and a tracked entry
+    point is being traced."""
+    tiles = getattr(_TLS, "tiles", None)
+    if tiles is not None and getattr(_TLS, "notes", None) is not None \
+            and _metrics.enabled():
+        tiles.append((int(visited), int(whole), int(diagonal)))
 
 
 def note_remat_kept(bytes_: int) -> None:

@@ -2,8 +2,10 @@
 mode on the CPU, against dense masked attention written here: forward
 and the three gradients, at lengths that are and are not whole tiles, at
 block lengths 4 and 32; the tiles the kernels walk against the tiles
-that hold an allowed pair; the FLOPs a call site notes against a count
-of the dense rule's true entries and the benchmark's closed form."""
+that hold an allowed pair, and the tiles they walk without a mask
+against the tiles the dense rule allows whole; the FLOPs a call site
+notes against a count of the dense rule's true entries and the
+benchmark's closed form."""
 
 import jax
 import jax.numpy as jnp
@@ -75,14 +77,108 @@ def test_no_tile_without_an_allowed_pair_is_walked_and_none_is_missed(
             i * tile:(i + 1) * tile, first:first + tile].any()]
 
 
+# blocks that straddle tiles, and the geometry of the noisy diagonal's
+# sub-tiles (tiles of 512, a block that divides 128)
+WALKS = SHAPES + [(64, 4, 16), (48, 6, 16), (1024, 4, 512)]
+
+
+def _tiles(mask, tile):
+    """{(query tile, key tile): its [tile, tile] cut of the dense rule}"""
+    n = -(-mask.shape[0] // tile)
+    return {(i, j): mask[i * tile:(i + 1) * tile, j * tile:(j + 1) * tile]
+            for i in range(n) for j in range(n)}
+
+
+def _whole(cut, tile):
+    return cut.shape == (tile, tile) and bool(cut.all())
+
+
+@pytest.mark.parametrize("length,block,tile", WALKS)
+def test_a_whole_tile_is_one_the_dense_rule_allows_whole(length, block,
+                                                         tile):
+    """Never true where the dense tile has a false entry or a padded
+    position; and where the halves are whole tiles, true on every tile
+    of clean keys the rule allows whole (a tile of noisy keys inside one
+    block is walked as an edge)."""
+    mask, total = dense_rule(length, block), 2 * length
+    for (i, j), cut in _tiles(mask, tile).items():
+        got = bool(fa.bd_tile_whole(i * tile, tile, j * tile, tile, total,
+                                    length, block))
+        assert not got or _whole(cut, tile), (i, j)
+        if length % tile == 0 and j * tile >= length:
+            assert got == _whole(cut, tile), (i, j)
+
+
+def _runs(runs):
+    return [t for lo, n in runs for t in range(int(lo), int(lo) + int(n))]
+
+
+@pytest.mark.parametrize("length,block,tile", WALKS)
+def test_the_walks_cut_their_runs_into_whole_tiles_and_edges(length, block,
+                                                             tile):
+    """What the three kernels loop over: the whole runs hold only tiles
+    the dense rule allows whole, whole runs and edges together are the
+    tiles that hold an allowed pair, each once; the census is their
+    count."""
+    mask, total = dense_rule(length, block), 2 * length
+    tiles, n = _tiles(mask, tile), -(-total // tile)
+    visited = whole_count = diagonal = 0
+    for t in range(n):
+        whole, edges, diag = fa._key_walk(t * tile, tile, tile, total, total,
+                                    False, (length, block))
+        assert all(_whole(tiles[t, j], tile) for j in _runs(whole))
+        assert sorted(_runs(whole) + _runs(edges) + _runs(diag)) == [
+            j for j in range(n) if tiles[t, j].any()]
+        assert all(j == t and t * tile < length for j in _runs(diag))
+        visited += len(_runs(whole) + _runs(edges) + _runs(diag))
+        whole_count += len(_runs(whole))
+        diagonal += len(_runs(diag))
+        if length % tile == 0:  # no whole clean tile is walked as an edge
+            assert not any(_whole(tiles[t, j], tile) for j in _runs(edges)
+                           if j * tile >= length)
+        whole, edges, diag = fa._query_walk(t * tile, tile, tile, n, total,
+                                            False, 0, (length, block))
+        seers = [i for i in range(n) if tiles[i, t].any()]
+        if _runs(diag):     # a noisy key tile of the aligned walk: its own
+            assert _runs(diag) == seers == [t] and t * tile < length
+            continue        # query tile and no other; the rest is not run
+        assert all(_whole(tiles[i, t], tile) for i in _runs(whole))
+        assert sorted(_runs(whole) + _runs(edges)) == seers
+        if length % tile == 0 and t * tile >= length:
+            assert not any(_whole(tiles[i, t], tile) for i in _runs(edges))
+    got = fa.flash_tile_census(total, total, min(tile, total),
+                               min(tile, total), False, (length, block))
+    # the noisy diagonal is a kind of its own where the walk is aligned
+    # (halves of whole tiles, tiles of whole blocks); the census counts
+    # it where it is walked in sub-tiles (tiles of 512, a block that
+    # divides 128)
+    assert diagonal == (length // tile if length % tile == 0
+                        and tile % block == 0 else 0)
+    assert got == (visited, whole_count,
+                   diagonal if tile == 512 and length >= 512 else 0)
+
+
+def test_the_census_of_the_cell_from_the_closed_walk_alone():
+    """2 x 8192 positions in tiles of 512, blocks of 4: a head and
+    sequence visits 288 tiles, 240 of them whole (no 16,384^2 array
+    here)."""
+    assert fa.flash_tile_census(16384, 16384, 512, 512, False,
+                                (8192, 4)) == (288, 240, 16)
+    # the hybrid decoder's causal walk, and BERT's one tile
+    assert fa.flash_tile_census(8192, 8192, 512, 512, True) == (136, 120, 0)
+    assert fa.flash_tile_census(512, 512, 512, 512) == (1, 1, 0)
+
+
 @pytest.mark.parametrize("bthd", [False, True], ids=["bhtd", "bthd"])
-@pytest.mark.parametrize("length,block,tile", SHAPES)
+@pytest.mark.parametrize("length,block,tile",
+                         SHAPES + [(64, 4, 16), (1024, 4, 512)])
 def test_forward_and_the_three_gradients(length, block, tile, bthd,
                                          monkeypatch):
     monkeypatch.setattr(fa, "BLOCK_Q", tile)
     monkeypatch.setattr(fa, "BLOCK_K", tile)
     rng = np.random.default_rng(length + block)
-    q, k, v = (jnp.asarray(rng.normal(size=(2, 2, 2 * length, 128
+    batch = 1 if length > 512 else 2         # the interpreter's time
+    q, k, v = (jnp.asarray(rng.normal(size=(batch, 2, 2 * length, 128
                                             if bthd else 8)),
                            jnp.float32) for _ in range(3))
     mask = jnp.asarray(dense_rule(length, block))

@@ -757,3 +757,198 @@ def test_fused_single_block_backward_matches_scanning(rng):
     for gf, gs, name in zip(g_fused, g_scan, "qkv"):
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gs),
                                    rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+# -- the tile walk: whole tiles without a mask, edges under theirs -------------
+#
+# The scanning kernels walk a run of whole tiles through a body that
+# builds no mask and the edges (the causal diagonal, a tile with a
+# padded tail) through the masked one. A mask wrong by a tile shows here
+# and hardly anywhere else: the cells' comparisons see it only over a
+# sequence's first tiles.
+
+def _dense_attention(q, k, v, causal, bias=None, keep=None, pd=0.0):
+    """[B, H, T, D] dense reference, bottom-right causal alignment."""
+    tq, tk = q.shape[2], k.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / (q.shape[-1] ** 0.5)
+    if bias is not None:
+        s = s + bias[:, None, None, :]
+    if causal:
+        allowed = (jnp.arange(tq)[:, None] + (tk - tq)
+                   >= jnp.arange(tk)[None, :])
+        s = jnp.where(allowed, s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    if keep is not None:
+        p = jnp.where(keep, p / (1 - pd), 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+@pytest.mark.parametrize("bthd", [False, True], ids=["bhtd", "bthd"])
+@pytest.mark.parametrize("with_bias", [False, True], ids=["nobias", "bias"])
+@pytest.mark.parametrize("tq,tk,causal", [
+    (128, 128, False), (128, 128, True), (112, 112, False),
+    (112, 112, True), (64, 160, False), (64, 160, True), (160, 96, False)])
+def test_tile_walk_matches_dense(rng, monkeypatch, tq, tk, causal,
+                                 with_bias, bthd):
+    """Several tiles a side, with and without a tail (``tk % 32``),
+    queries shorter and longer than keys, a key bias on whole tiles
+    too: output and the three gradients against dense attention."""
+    from paddle_tpu.kernels import flash_attention as fa
+    monkeypatch.setattr(fa, "BLOCK_Q", 32)
+    monkeypatch.setattr(fa, "BLOCK_K", 32)
+    b, h, d = 2, 2, 64
+    q = jnp.asarray(rng.standard_normal((b, h, tq, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((b, h, tk, d)), jnp.float32)
+            for _ in range(2))
+    w = jnp.asarray(rng.standard_normal((b, h, tq, d)), jnp.float32)
+    bias = None
+    if with_bias:       # keys hidden in the middle of whole tiles
+        hidden = (np.arange(tk)[None, :] % 7 == np.array([[1], [4]]))
+        hidden[:, 0] = False
+        bias = jnp.asarray(np.where(hidden, -1e30, 0.0), jnp.float32)
+    swap = (lambda x: jnp.moveaxis(x, 1, 2)) if bthd else (lambda x: x)
+
+    def kernel(q_, k_, v_):
+        out = fa.flash_attention(swap(q_), swap(k_), swap(v_), causal, None,
+                                 True, 0.0, None, bias, bthd)
+        return jnp.sum(swap(out) * w), swap(out)
+
+    def dense(q_, k_, v_):
+        out = _dense_attention(q_, k_, v_, causal, bias)
+        return jnp.sum(out * w), out
+
+    (_, got), got_g = jax.value_and_grad(kernel, (0, 1, 2), True)(q, k, v)
+    (_, want), want_g = jax.value_and_grad(dense, (0, 1, 2), True)(q, k, v)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    for a, c, name in zip(got_g, want_g, "qkv"):
+        np.testing.assert_allclose(a, c, rtol=2e-3, atol=2e-3,
+                                   err_msg="d" + name)
+
+
+@pytest.mark.parametrize("tq,tk,causal", [
+    (128, 128, False), (128, 128, True), (100, 100, False),
+    (100, 100, True), (32, 96, True), (96, 32, False), (64, 64, True)])
+def test_tile_census_counts_the_dense_rule(tq, tk, causal):
+    """Visited: the tiles that hold an allowed pair. Whole: those of
+    them with every pair allowed and no padded key."""
+    from paddle_tpu.kernels import flash_attention as fa
+    tile = 32
+    allowed = np.ones((tq, tk), bool)
+    if causal:
+        allowed = (np.arange(tq)[:, None] + (tk - tq)
+                   >= np.arange(tk)[None, :])
+    visited = whole = 0
+    for i in range(0, tq, tile):
+        for j in range(0, tk, tile):
+            cut = allowed[i:i + tile, j:j + tile]
+            visited += bool(cut.any())
+            whole += bool(cut.all() and j + tile <= tk)
+            assert bool(fa.tile_whole(i, j, tile, tk, causal, tk - tq)) \
+                == bool(cut.all() and j + tile <= tk), (i, j)
+    assert fa.flash_tile_census(tq, tk, tile, tile, causal) == (
+        visited, whole, 0)
+
+
+def _np_fmix32(x):
+    x = np.asarray(x, np.uint32)
+    x = x ^ (x >> np.uint32(16))
+    x = x * np.uint32(0x85EBCA6B)
+    x = x ^ (x >> np.uint32(13))
+    x = x * np.uint32(0xC2B2AE35)
+    return x ^ (x >> np.uint32(16))
+
+
+def _hashed_keep(seed, head_id, tq, tk, pd):
+    """The keep mask of one head as PR 28 fixed it: a hash of (seed,
+    head, query position, key position), written here in numpy."""
+    with np.errstate(over="ignore"):
+        h = _np_fmix32(np.uint32(seed) ^ _np_fmix32(
+            np.uint32(head_id) + np.uint32(0x9E3779B9)))
+        u = _np_fmix32(np.arange(tq, dtype=np.uint32)[:, None] + h)
+        bits = _np_fmix32(u ^ (np.arange(tk, dtype=np.uint32)[None, :]
+                               * np.uint32(0x9E3779B9)))
+    return bits >= np.uint32(min(int(pd * 4294967296.0), 4294967295))
+
+
+# sha256 of the packed keep mask that the parent of PR 38 drew for the
+# case below (`_two_heads_a_program_keep` run on its tree)
+_PARENT_KEEP_SHA256 = \
+    "ca77bb3bf7ce739eef28de0cadcb7ace9b57f8abec4afc60396247f7fa571d47"
+
+
+def _two_heads_a_program_keep(fa, tile):
+    """The keep mask two 64-wide heads a program draw at seed 20260905,
+    by the v = I trick: [B, H, T, T] bool."""
+    b, t, h, d, pd = 2, 64, 4, 64, 0.1
+    rng = np.random.default_rng(7)
+    q, k = (jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
+            for _ in range(2))
+    eye = jnp.broadcast_to(jnp.eye(t, dtype=jnp.float32)[:, None, :],
+                           (b, t, h, t))
+    orig = fa.BLOCK_Q, fa.BLOCK_K
+    fa.BLOCK_Q, fa.BLOCK_K = tile, tile
+    try:
+        out = fa.flash_attention(q, k, eye, False, None, True, pd,
+                                 jnp.asarray(20260905, jnp.int32), None,
+                                 True)
+    finally:
+        fa.BLOCK_Q, fa.BLOCK_K = orig
+    return np.moveaxis(np.asarray(out) != 0.0, 2, 1)
+
+
+@pytest.mark.parametrize("tile", [32, 512], ids=["scanning", "one_tile"])
+def test_dropout_mask_is_bitwise_the_parents(tile):
+    """Whole tiles build no position for a mask, and the hash still
+    counts by its own: two heads a program (BERT's layout), the mask
+    equal bit for bit to the hash written out in numpy and to what the
+    parent commit drew."""
+    import hashlib
+
+    from paddle_tpu.kernels import flash_attention as fa
+    keep = _two_heads_a_program_keep(fa, tile)
+    b, h, t = keep.shape[:3]
+    want = np.stack([np.stack([
+        _hashed_keep(20260905, bi * h + hi, t, t, 0.1) for hi in range(h)])
+        for bi in range(b)])
+    np.testing.assert_array_equal(keep, want)
+    assert hashlib.sha256(np.packbits(keep).tobytes()).hexdigest() \
+        == _PARENT_KEEP_SHA256
+
+
+def test_two_heads_a_program_dropout_gradients_match_dense(rng):
+    """BERT's case on the scanning kernels: two 64-wide heads a program,
+    dropout 0.1, several tiles: output and gradients against dense
+    attention under the same (hashed) mask."""
+    from paddle_tpu.kernels import flash_attention as fa
+    b, t, h, d, pd = 2, 96, 2, 64, 0.1
+    q, k, v, w = (jnp.asarray(rng.standard_normal((b, h, t, d)),
+                              jnp.float32) for _ in range(4))
+    seed = 99
+    keep = jnp.asarray(np.stack([np.stack([
+        _hashed_keep(seed, bi * h + hi, t, t, pd) for hi in range(h)])
+        for bi in range(b)]))
+    swap = lambda x: jnp.moveaxis(x, 1, 2)                # noqa: E731
+    orig = fa.BLOCK_Q, fa.BLOCK_K
+    fa.BLOCK_Q, fa.BLOCK_K = 32, 32
+    try:
+        def kernel(q_, k_, v_):
+            out = fa.flash_attention(swap(q_), swap(k_), swap(v_), False,
+                                     None, True, pd,
+                                     jnp.asarray(seed, jnp.int32), None,
+                                     True)
+            return jnp.sum(swap(out) * w), swap(out)
+
+        def dense(q_, k_, v_):
+            out = _dense_attention(q_, k_, v_, False, keep=keep, pd=pd)
+            return jnp.sum(out * w), out
+
+        (_, got), got_g = jax.value_and_grad(kernel, (0, 1, 2), True)(
+            q, k, v)
+        (_, want), want_g = jax.value_and_grad(dense, (0, 1, 2), True)(
+            q, k, v)
+    finally:
+        fa.BLOCK_Q, fa.BLOCK_K = orig
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    for a, c, name in zip(got_g, want_g, "qkv"):
+        np.testing.assert_allclose(a, c, rtol=2e-3, atol=2e-3,
+                                   err_msg="d" + name)

@@ -704,3 +704,43 @@ def test_hapi_fit_trains_it_like_any_model():
         DataLoader(TensorDataset([ids[:, :-1], ids[:, 1:]]), batch_size=4),
         epochs=4, verbose=0)
     assert history["loss"][-1] < history["loss"][0] - 0.3
+
+
+def test_a_step_on_the_flash_kernels_publishes_the_census_of_its_tile_walk(
+        monkeypatch):
+    """With the attention layer on the causal flash kernels (interpreted
+    here), the three trace-time gauges of the step are the census of
+    one head and sequence's forward walk: a recomputed layer keeps the
+    kernel's result, so its one forward site counts once."""
+    import functools
+
+    from paddle_tpu import kernels
+    from paddle_tpu import observability as obs
+    from paddle_tpu.kernels import flash_attention as fa
+    monkeypatch.setattr(kernels, "_on_tpu", lambda: True)
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=True))
+    monkeypatch.setattr(fa, "BLOCK_Q", 8)
+    monkeypatch.setattr(fa, "BLOCK_K", 8)
+    saved = pt.get_flags(["flash_attention_min_seq",
+                          "flash_attention_min_seq_train"])
+    obs.reset_all()
+    pt.set_flags({"enable_metrics": True, "flash_attention_min_seq": 1,
+                  "flash_attention_min_seq_train": 1})
+    try:
+        step = TrainStep(build(recompute="layer", head_dim=128,
+                               num_attention_heads=2,
+                               num_key_value_heads=1),
+                         pt.optimizer.AdamW(1e-3), next_token_loss,
+                         extra_metrics=routing_metrics())
+        ids, labels = batch()
+        assert np.isfinite(float(step(ids, labels=(labels,))["loss"]))
+        fn = step._span_name
+        one = fa.flash_tile_census(SEQ, SEQ, 8, 8, True)
+        assert one == (6, 3, 0)        # 21 positions in tiles of 8
+        for kind, count in zip(("visited", "whole", "diagonal"), one):
+            assert obs.gauge("pt_flash_tiles_" + kind).value(fn=fn) \
+                == count, kind
+    finally:
+        pt.set_flags({"enable_metrics": False, **saved})
+        obs.reset_all()

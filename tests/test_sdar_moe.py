@@ -320,3 +320,42 @@ def test_input_must_be_two_copies_of_whole_blocks():
         model(np.zeros((1, 2 * 18), np.int32))
     with pytest.raises(ValueError, match="whole"):
         model(np.zeros((1, 41), np.int32))
+
+
+def test_a_step_on_the_kernels_publishes_the_census_of_its_tile_walk(
+        monkeypatch):
+    """With the seam on the ``bd_flash_*`` kernels (interpreted here),
+    the three trace-time gauges of the step are the census of one head
+    and sequence's forward walk, summed over its layers' one forward
+    call site each."""
+    import functools
+
+    from paddle_tpu import kernels
+    from paddle_tpu import observability as obs
+    from paddle_tpu.kernels import flash_attention as fa
+    monkeypatch.setattr(kernels, "_on_tpu", lambda: True)
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=True))
+    monkeypatch.setattr(fa, "BLOCK_Q", 16)
+    monkeypatch.setattr(fa, "BLOCK_K", 16)
+    obs.reset_all()
+    pt.set_flags({"enable_metrics": True})
+    try:
+        step = TrainStep(build(recompute="layer", head_dim=128,
+                               num_attention_heads=2,
+                               num_key_value_heads=1),
+                         pt.optimizer.AdamW(1e-3),
+                         models.block_diffusion_loss,
+                         extra_metrics=block_diffusion_metrics())
+        ids, labels, t = batch(seq=32)
+        assert np.isfinite(float(step(ids, labels=(labels, t))["loss"]))
+        fn = step._span_name
+        assert obs.gauge("pt_bd_attention_sites").value(fn=fn) == 2
+        one = fa.flash_tile_census(64, 64, 16, 16, False, (32, 4))
+        assert one[1] > 0 and one[0] > one[1]
+        for kind, count in zip(("visited", "whole", "diagonal"), one):
+            assert obs.gauge("pt_flash_tiles_" + kind).value(fn=fn) \
+                == CFG["num_hidden_layers"] * count, kind
+    finally:
+        pt.set_flags({"enable_metrics": False})
+        obs.reset_all()
