@@ -109,6 +109,18 @@ def select_update(found_inf, updated, current):
         lambda u, c: jnp.where(found_inf, c, u), updated, current)
 
 
+def guarded_update(found_inf, update, current):
+    """``current`` where the step saw a non-finite gradient, else
+    ``update()``, as one conditional under ``pt.guard``: the optimizer's
+    state is written in place or left as it is. ``select_update`` over
+    ``update()`` gives the same values, but XLA may order a leaf's
+    select after the in-place write of its new state and then copies
+    the old master and moments first (2.4 GB of a 600M-parameter step,
+    ROADMAP A2(d)); here no select reads the old state."""
+    with jax.named_scope("pt.guard"):
+        return jax.lax.cond(found_inf, lambda: current, update)
+
+
 class GradScaler:
     """Dynamic loss scaling (ref: loss_scaler.py:27 AmpScaler;
     update rule: update_loss_scaling op — incr every

@@ -349,7 +349,7 @@ def maybe_flash_attention(q, k, v, mask=None, scale: Optional[float] = None,
                 seed = seed + shard * jnp.int32(0x3C6EF35F)
             kw = dict(seed=seed, causal=causal, scale=scale,
                       dropout_p=drop, kv_bias=kv_bias)
-            if bthd and not bthd_supported(d, q.shape[2]):
+            if bthd and not bthd_supported(d, q.shape[2], v.shape[-1]):
                 # geometry the BTHD block tiling can't express (e.g.
                 # d=32, odd local head count): still flash, via the
                 # transpose layout

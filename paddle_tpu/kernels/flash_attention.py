@@ -62,39 +62,46 @@ def _block_sizes(tq: int, tk: int):
 
 
 def flash_fwd_work(b: int, h: int, tq: int, tk: int, d: int,
-                   itemsize: int, causal: bool = False, bd=None):
+                   itemsize: int, causal: bool = False, bd=None,
+                   d_v: Optional[int] = None):
     """(FLOPs, HBM bytes) one forward call must do: the two matmuls
-    QK^T and PV (``4*B*H*Tq*Tk*D``, half of it under a causal mask,
-    ``4*B*H*D*(L^2 + K*L)`` under the block-diffusion mask ``bd = (L,
-    K)``: the allowed pairs, never the tiles visited); q, k, v read and
-    the output written once, plus the f32 logsumexp row."""
-    flops = 4.0 * b * h * tq * tk * d
+    QK^T at the q/k width ``d`` and PV at the value width ``d_v`` (``d``
+    unless given): ``2*B*H*Tq*Tk*(d + d_v)``, half of it under a causal
+    mask, ``2*B*H*(d + d_v)*(L^2 + K*L)`` under the block-diffusion mask
+    ``bd = (L, K)``: the allowed pairs, never the tiles visited); q, k,
+    v read and the output written once, plus the f32 logsumexp row."""
+    d_v = d if d_v is None else d_v
+    flops = 2.0 * b * h * tq * tk * (d + d_v)
     if bd is not None:
-        flops = 4.0 * b * h * d * bd_allowed_pairs(*bd)
+        flops = 2.0 * b * h * (d + d_v) * bd_allowed_pairs(*bd)
     elif causal:
         flops /= 2
-    return flops, float(b * h * (2 * tq + 2 * tk) * d * itemsize
-                        + 4 * b * h * tq)
+    return flops, float(b * h * ((tq + tk) * d + (tk + tq) * d_v)
+                        * itemsize + 4 * b * h * tq)
 
 
 def flash_bwd_work(b: int, h: int, tq: int, tk: int, d: int,
                    itemsize: int, causal: bool = False,
-                   matmuls: int = 5, bd=None):
+                   matmuls: int = 5, bd=None, d_v: Optional[int] = None):
     """(FLOPs, HBM bytes) one backward call must do. The recompute
-    backward runs five matmuls — S = QK^T, dP = dO V^T, dV = P^T dO,
-    dQ = dS K, dK = dS^T Q: ``10*B*H*Tq*Tk*D`` — in the fused
-    single-block kernel; split in two, the dq kernel runs three of them
-    (S, dP, dQ) and the dk/dv kernel four (S, dP, dV, dK). Bytes: q, k,
-    v, dO read, the two f32 row vectors (lse, delta), and the
-    gradients this call writes."""
-    flops = 2.0 * matmuls * b * h * tq * tk * d
+    backward runs five matmuls — S = QK^T, dQ = dS K and dK = dS^T Q at
+    the q/k width ``d``, dP = dO V^T and dV = P^T dO at the value width
+    ``d_v`` (``d`` unless given): ``2*B*H*Tq*Tk*(3 d + 2 d_v)`` — in the
+    fused single-block kernel; split in two, the dq kernel runs three of
+    them (S, dP, dQ: ``2 d + d_v``) and the dk/dv kernel four (S, dP,
+    dV, dK: ``2 d + 2 d_v``). Bytes: q, k, v, dO read, the two f32 row
+    vectors (lse, delta), and the gradients this call writes."""
+    d_v = d if d_v is None else d_v
+    width = {5: 3 * d + 2 * d_v, 3: 2 * d + d_v, 4: 2 * d + 2 * d_v}
+    flops = 2.0 * b * h * tq * tk * width[matmuls]
     if bd is not None:      # the allowed pairs, as in flash_fwd_work
-        flops = 2.0 * matmuls * b * h * d * bd_allowed_pairs(*bd)
+        flops = 2.0 * b * h * width[matmuls] * bd_allowed_pairs(*bd)
     elif causal:
         flops /= 2
-    written = {5: tq + 2 * tk, 3: tq, 4: 2 * tk}[matmuls]
-    return flops, float(b * h * (2 * tq + 2 * tk + written) * d
-                        * itemsize + 8 * b * h * tq)
+    written = {5: (tq + tk) * d + tk * d_v, 3: tq * d,
+               4: tk * (d + d_v)}[matmuls]
+    return flops, float(b * h * ((tq + tk) * d + (tk + tq) * d_v
+                                 + written) * itemsize + 8 * b * h * tq)
 
 
 def _heads_per_block(d: int, h: int) -> int:
@@ -111,11 +118,15 @@ def _heads_per_block(d: int, h: int) -> int:
     return 1 if d % 128 == 0 else 2
 
 
-def bthd_supported(d: int, h: int) -> bool:
+def bthd_supported(d: int, h: int, d_v: Optional[int] = None) -> bool:
     """Whether the transpose-free [B, T, H, D] layout can ride the
     kernel for this geometry — the single home of the tiling rule
-    (_heads_per_block gates on it)."""
-    return d % 128 == 0 or ((2 * d) % 128 == 0 and h % 2 == 0)
+    (_heads_per_block gates on it). Heads share a program by the q/k
+    width ``d``; the value width ``d_v`` (``d`` unless given) must then
+    fill whole 128-lane blocks with the same heads."""
+    if not (d % 128 == 0 or ((2 * d) % 128 == 0 and h % 2 == 0)):
+        return False
+    return d_v is None or (d_v * (1 if d % 128 == 0 else 2)) % 128 == 0
 
 
 # Both grid dims of every flash kernel — (batch*heads, block index) —
@@ -530,12 +541,15 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bias_ref, o_ref,
                       lse_ref, *, scale: float, causal: bool,
                       block_k: int, seq_k: int, seq_q: int,
                       dropout_p: float, has_bias: bool, d_head: int,
-                      hpb: int, n_heads: int, bd=None):
+                      hpb: int, n_heads: int, bd=None,
+                      d_v: Optional[int] = None):
     # refs carry hpb heads side-by-side in the minor dim ([BQ, hpb*D]):
     # hpb == 1 is the classic one-head-per-program layout; hpb == 2
     # packs head PAIRS so the [B, T, H, D] layout's d=64 slabs form a
     # 128-lane block (Mosaic's minor-dim tiling floor). Each half is an
-    # independent attention problem sharing the same K-scan.
+    # independent attention problem sharing the same K-scan. v and the
+    # output carry the same heads at the value width d_v ([BK, hpb*d_v]).
+    d_v = d_head if d_v is None else d_v
     q2 = q_ref[0].astype(jnp.float32) * scale        # [BQ, hpb*D]
     block_q = q2.shape[0]
     g = pl.program_id(0)
@@ -560,6 +574,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bias_ref, o_ref,
         new = ([], [], [])
         for half in range(hpb):
             sl = slice(half * d_head, (half + 1) * d_head)
+            sl_v = slice(half * d_v, (half + 1) * d_v)
             s = jax.lax.dot_general(
                 q2[:, sl], k2[:, sl], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)    # [BQ, BK]
@@ -582,14 +597,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bias_ref, o_ref,
                     q_pos, k_pos, dropout_p)
                 p = jnp.where(keep, p, 0.0)
             acc = accs[half] * alpha + jax.lax.dot_general(
-                p, v2[:, sl], (((1,), (0,)), ((), ())),
+                p, v2[:, sl_v], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             new[0].append(acc)
             new[1].append(m_new)
             new[2].append(l_new)
         return tuple(new[0]), tuple(new[1]), tuple(new[2])
 
-    acc0 = tuple(jnp.zeros((block_q, d_head), jnp.float32)
+    acc0 = tuple(jnp.zeros((block_q, d_v), jnp.float32)
                  for _ in range(hpb))
     m0 = tuple(jnp.full((block_q, 1), _NEG_INF, jnp.float32)
                for _ in range(hpb))
@@ -660,6 +675,7 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
     else:
         b, h, tq, d = q.shape
         tk = k.shape[2]
+    d_v = v.shape[-1]       # the value and output width; q's and k's d
     if bd is not None:
         _bd_check(bd, tq, tk)
     bq, bk = _block_sizes(tq, tk)
@@ -687,16 +703,23 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
         q_spec = pl.BlockSpec((1, bq, hpb * d),
                               lambda g, i: (g // hg, i, g % hg),
                               memory_space=pltpu.VMEM)
-        kv_spec = pl.BlockSpec((1, tk_p, hpb * d),
-                               lambda g, i: (g // hg, 0, g % hg),
-                               memory_space=pltpu.VMEM)
-        out_struct = jax.ShapeDtypeStruct((b, tq_p, h * d), q.dtype)
+        kv_spec, v_spec, o_spec = (
+            pl.BlockSpec((1, rows, hpb * w), imap, memory_space=pltpu.VMEM)
+            for rows, w, imap in (
+                (tk_p, d, lambda g, i: (g // hg, 0, g % hg)),
+                (tk_p, d_v, lambda g, i: (g // hg, 0, g % hg)),
+                (bq, d_v, lambda g, i: (g // hg, i, g % hg))))
+        out_struct = jax.ShapeDtypeStruct((b, tq_p, h * d_v), q.dtype)
     else:
         q_spec = pl.BlockSpec((1, bq, d), lambda g, i: (g, i, 0),
                               memory_space=pltpu.VMEM)
-        kv_spec = pl.BlockSpec((1, tk_p, d), lambda g, i: (g, 0, 0),
-                               memory_space=pltpu.VMEM)
-        out_struct = jax.ShapeDtypeStruct((b * h, tq_p, d), q.dtype)
+        kv_spec, v_spec, o_spec = (
+            pl.BlockSpec((1, rows, w), imap, memory_space=pltpu.VMEM)
+            for rows, w, imap in (
+                (tk_p, d, lambda g, i: (g, 0, 0)),
+                (tk_p, d_v, lambda g, i: (g, 0, 0)),
+                (bq, d_v, lambda g, i: (g, i, 0))))
+        out_struct = jax.ShapeDtypeStruct((b * h, tq_p, d_v), q.dtype)
     grid = (b * hg, tq_p // bq)
     has_bias = kv_bias is not None
     # bias rows are per batch element: block index g // hg (hg static)
@@ -706,21 +729,21 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
                                causal=causal, block_k=bk, seq_k=tk,
                                seq_q=tq, dropout_p=dropout_p,
                                has_bias=has_bias, d_head=d, hpb=hpb,
-                               n_heads=h, bd=bd)
+                               n_heads=h, bd=bd, d_v=d_v)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             q_spec,
             kv_spec,
-            kv_spec,
+            v_spec,
             pl.BlockSpec((1, 1), lambda g, i: (0, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, tk_p), bias_map,
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
-            q_spec,
+            o_spec,
             # lse as [b*hg, tq, hpb]: a trailing dim equal to the array
             # dim satisfies Mosaic's (8,128) block tiling rule, which a
             # 2-D (1, bq) block does not
@@ -732,12 +755,12 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
             jax.ShapeDtypeStruct((b * hg, tq_p, hpb), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_grid_params(
-            *[tk_p * hpb * d * k.dtype.itemsize] * 2),
+        compiler_params=_grid_params(tk_p * hpb * d * k.dtype.itemsize,
+                                     tk_p * hpb * d_v * v.dtype.itemsize),
         name="flash_fwd" if bd is None else "bd_flash_fwd",
     )(qr, kr, vr, _seed_arr(seed), _bias_arr(kv_bias, b, tk, tk_p))
     note_kernel(_BD_PREFIX * (bd is not None) + "flash_fwd", *flash_fwd_work(
-        b, h, tq, tk, d, q.dtype.itemsize, causal, bd=bd))
+        b, h, tq, tk, d, q.dtype.itemsize, causal, bd=bd, d_v=d_v))
     xprof.note_flash_tiles(*flash_tile_census(tq, tk, bq, bk, causal, bd))
     # what `nn.recompute_layer` keeps of a recomputed layer: the backward
     # kernels read both, and making either again is this whole call. The
@@ -752,8 +775,8 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
     lse_pub = checkpoint_name(
         jnp.moveaxis(lse_pub, 3, 2).reshape(b, h, tq), "flash_lse")
     if bthd:
-        return out[:, :tq].reshape(b, tq, h, d), lse_pub
-    return out[:, :tq].reshape(b, h, tq, d), lse_pub
+        return out[:, :tq].reshape(b, tq, h, d_v), lse_pub
+    return out[:, :tq].reshape(b, h, tq, d_v), lse_pub
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 9, 10))
@@ -865,9 +888,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    seed_ref, bias_ref, dq_ref, *, scale: float,
                    causal: bool, block_k: int, seq_k: int, seq_q: int,
                    dropout_p: float, has_bias: bool, d_head: int,
-                   hpb: int, n_heads: int, bd=None):
+                   hpb: int, n_heads: int, bd=None,
+                   d_v: Optional[int] = None):
+    d_v = d_head if d_v is None else d_v               # v's and dO's
     q2 = q_ref[0].astype(jnp.float32) * scale          # [BQ, hpb*D]
-    do2 = do_ref[0].astype(jnp.float32)                # [BQ, hpb*D]
+    do2 = do_ref[0].astype(jnp.float32)                # [BQ, hpb*d_v]
     lse2 = lse_ref[0]                                  # [BQ, hpb] f32
     delta2 = delta_ref[0]                              # [BQ, hpb] f32
     block_q = q2.shape[0]
@@ -889,8 +914,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         out = []
         for half in range(hpb):
             sl = slice(half * d_head, (half + 1) * d_head)
+            sl_v = slice(half * d_v, (half + 1) * d_v)
             _, dsc = _grad_core(
-                q2[:, sl], k2[:, sl], v2[:, sl], do2[:, sl],
+                q2[:, sl], k2[:, sl], v2[:, sl_v], do2[:, sl_v],
                 lse2[:, half:half + 1], delta2[:, half:half + 1],
                 valid, bias, seed_ref,
                 _head_id(g, half, hpb, n_heads), q_pos, k_pos,
@@ -942,12 +968,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     seed_ref, bias_ref, dk_ref, dv_ref, *, scale: float,
                     causal: bool, block_q: int, seq_k: int, seq_q: int,
                     dropout_p: float, has_bias: bool, d_head: int,
-                    hpb: int, n_heads: int, bd=None):
+                    hpb: int, n_heads: int, bd=None,
+                    d_v: Optional[int] = None):
+    d_v = d_head if d_v is None else d_v               # v's, dO's, dv's
     # Padded-q correctness: dO and delta are zero-padded, so a padded
     # query row contributes p^T@dO = 0 to dv and p*(0-0) = 0 to dk —
     # no explicit q-validity mask is needed.
     k2 = k_ref[0].astype(jnp.float32)                  # [BK, hpb*D]
-    v2 = v_ref[0].astype(jnp.float32)                  # [BK, hpb*D]
+    v2 = v_ref[0].astype(jnp.float32)                  # [BK, hpb*d_v]
     block_k = k2.shape[0]
     g = pl.program_id(0)
     c0 = pl.program_id(1) * block_k
@@ -970,17 +998,18 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         new_dk, new_dv = [], []
         for half in range(hpb):
             sl = slice(half * d_head, (half + 1) * d_head)
+            sl_v = slice(half * d_v, (half + 1) * d_v)
             # this kernel's k block is fixed, so the BlockSpec already
             # delivered exactly the [1, BK] bias slice for j_k
             p_v, dsc = _grad_core(
-                q2[:, sl], k2[:, sl], v2[:, sl], do2[:, sl],
+                q2[:, sl], k2[:, sl], v2[:, sl_v], do2[:, sl_v],
                 lse2[:, half:half + 1], delta2[:, half:half + 1],
                 valid, bias_ref[0] if has_bias else None, seed_ref,
                 _head_id(g, half, hpb, n_heads), q_pos, k_pos,
                 dropout_p=dropout_p, has_bias=has_bias)
             new_dv.append(dv_accs[half] + jax.lax.dot_general(
-                p_v, do2[:, sl], (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))        # [BK, D]
+                p_v, do2[:, sl_v], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))        # [BK, d_v]
             new_dk.append(dk_accs[half] + jax.lax.dot_general(
                 dsc, q2[:, sl], (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32))        # [BK, D]
@@ -1014,12 +1043,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     zeros = tuple(jnp.zeros((block_k, d_head), jnp.float32)
                   for _ in range(hpb))
+    zeros_v = zeros if d_v == d_head else tuple(
+        jnp.zeros((block_k, d_v), jnp.float32) for _ in range(hpb))
     whole, edges, diag = _query_walk(c0, block_k, block_q, num_q, seq_k,
                                      causal, causal_offset, bd)
 
     def seen_by_others():
         carry = _walk(whole, functools.partial(body, masked=False),
-                      (zeros, zeros))
+                      (zeros, zeros_v))
         return _walk(edges, functools.partial(body, masked=True), carry)
 
     if diag:          # a noisy key tile: its own query tile and no other
@@ -1027,7 +1058,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         own = diagonal if _bd_subtiled(block_q, block_k, seq_k, bd) \
             else functools.partial(body, masked=True)
         dks, dvs = jax.lax.cond(
-            noisy, lambda: own(tile, (zeros, zeros)), seen_by_others)
+            noisy, lambda: own(tile, (zeros, zeros_v)), seen_by_others)
     else:
         dks, dvs = seen_by_others()
     dk_ref[0] = (jnp.concatenate(dks, axis=1) if hpb > 1 else dks[0]) \
@@ -1040,7 +1071,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       seed_ref, bias_ref, dq_ref, dk_ref, dv_ref, *,
                       scale: float, causal: bool, seq_k: int,
                       seq_q: int, dropout_p: float, has_bias: bool,
-                      d_head: int, hpb: int, n_heads: int, bd=None):
+                      d_head: int, hpb: int, n_heads: int, bd=None,
+                      d_v: Optional[int] = None):
     """Single-block backward: when BOTH padded sequences fit one tile
     (tq_p == bq and tk_p == bk — e.g. BERT's T=512 with 512-tiles),
     the dq and dkv kernels' scans each degenerate to one iteration
@@ -1049,6 +1081,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     set of DMAs, no duplicated softmax/mask/dropout work. The r5 b16
     profile put the flash custom-calls at 11.8 ms/step (20.6%), so
     the duplicated backward half is real step time."""
+    d_v = d_head if d_v is None else d_v               # v's, dO's, dv's
     q2 = q_ref[0].astype(jnp.float32) * scale          # [BQ, hpb*D]
     k2 = k_ref[0].astype(jnp.float32)                  # [BK, hpb*D]
     v2 = v_ref[0].astype(jnp.float32)
@@ -1067,15 +1100,16 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dqs, dks, dvs = [], [], []
     for half in range(hpb):
         sl = slice(half * d_head, (half + 1) * d_head)
+        sl_v = slice(half * d_v, (half + 1) * d_v)
         p_v, dsc = _grad_core(
-            q2[:, sl], k2[:, sl], v2[:, sl], do2[:, sl],
+            q2[:, sl], k2[:, sl], v2[:, sl_v], do2[:, sl_v],
             lse2[:, half:half + 1], delta2[:, half:half + 1],
             valid, bias_ref[0] if has_bias else None, seed_ref,
             _head_id(g, half, hpb, n_heads), q_pos, k_pos,
             dropout_p=dropout_p, has_bias=has_bias)
         dvs.append(jax.lax.dot_general(
-            p_v, do2[:, sl], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32))           # [BK, D]
+            p_v, do2[:, sl_v], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32))           # [BK, d_v]
         dks.append(jax.lax.dot_general(
             dsc, q2[:, sl], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32))           # [BK, D]
@@ -1099,6 +1133,7 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
     else:
         b, h, tq, d = q.shape
         tk = k.shape[2]
+    d_v = v.shape[-1]       # v's, dO's and dv's width; q's and k's d
     bq, bk = _block_sizes(tq, tk)
     tq_p = pl.cdiv(tq, bq) * bq
     tk_p = pl.cdiv(tk, bk) * bk
@@ -1113,8 +1148,8 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
             return jnp.pad(x, ((0, 0), (0, tp - t), (0, 0))) \
                 if tp != t else x
 
-        def seq_spec(blk, imap):
-            return pl.BlockSpec((1, blk, hpb * d), imap,
+        def seq_spec(blk, imap, w=d):
+            return pl.BlockSpec((1, blk, hpb * w), imap,
                                 memory_space=pltpu.VMEM)
 
         q_map = lambda g_, i: (g_ // hg, i, g_ % hg)      # noqa: E731
@@ -1123,7 +1158,7 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
         qfull_map = lambda g_, j: (g_ // hg, 0, g_ % hg)  # noqa: E731
         dq_struct = jax.ShapeDtypeStruct((b, tq_p, h * d), q.dtype)
         dk_struct = jax.ShapeDtypeStruct((b, tk_p, h * d), k.dtype)
-        dv_struct = jax.ShapeDtypeStruct((b, tk_p, h * d), v.dtype)
+        dv_struct = jax.ShapeDtypeStruct((b, tk_p, h * d_v), v.dtype)
         # delta/lse ride as [b*hg, tq, hpb] (head = group*hpb + half):
         # [b, tq, h] -> that layout is a tiny fp32 transpose
         # (b*h*tq elements), not activation-scale traffic
@@ -1137,8 +1172,8 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
             return jnp.pad(x, ((0, 0), (0, tp - t), (0, 0))) \
                 if tp != t else x
 
-        def seq_spec(blk, imap):
-            return pl.BlockSpec((1, blk, d), imap,
+        def seq_spec(blk, imap, w=d):
+            return pl.BlockSpec((1, blk, w), imap,
                                 memory_space=pltpu.VMEM)
 
         q_map = lambda g_, i: (g_, i, 0)                # noqa: E731
@@ -1147,7 +1182,7 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
         qfull_map = lambda g_, j: (g_, 0, 0)            # noqa: E731
         dq_struct = jax.ShapeDtypeStruct((b * h, tq_p, d), q.dtype)
         dk_struct = jax.ShapeDtypeStruct((b * h, tk_p, d), k.dtype)
-        dv_struct = jax.ShapeDtypeStruct((b * h, tk_p, d), v.dtype)
+        dv_struct = jax.ShapeDtypeStruct((b * h, tk_p, d_v), v.dtype)
         delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1).reshape(b * h, tq, 1)
 
@@ -1178,6 +1213,9 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
     bias_map = (lambda g_, i: (g_ // hg, 0, 0)) if has_bias else \
         (lambda g_, i: (0, 0, 0))
     shape = (b, h, tq, tk, d, q.dtype.itemsize)     # for the work notes
+    kernel_kw = dict(scale=scale, causal=causal, seq_k=tk, seq_q=tq,
+                     dropout_p=dropout_p, has_bias=has_bias, d_head=d,
+                     hpb=hpb, n_heads=h, bd=bd, d_v=d_v)
     prefix = _BD_PREFIX * (bd is not None)
     row_spec = pl.BlockSpec((1, bq, hpb), lambda g_, i: (g_, i, 0),
                             memory_space=pltpu.VMEM)
@@ -1189,16 +1227,13 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
         # _bwd_fused_kernel) — the two-kernel path would recompute
         # identical s/p/dp
         dq, dk, dv = pl.pallas_call(
-            functools.partial(_bwd_fused_kernel, scale=scale,
-                              causal=causal, seq_k=tk, seq_q=tq,
-                              dropout_p=dropout_p, has_bias=has_bias,
-                              d_head=d, hpb=hpb, n_heads=h, bd=bd),
+            functools.partial(_bwd_fused_kernel, **kernel_kw),
             grid=(b * hg, 1),
             in_specs=[
                 seq_spec(bq, q_map),
                 seq_spec(bk, kblk_map),
-                seq_spec(bk, kblk_map),
-                seq_spec(bq, q_map),
+                seq_spec(bk, kblk_map, d_v),
+                seq_spec(bq, q_map, d_v),
                 row_spec,
                 row_spec,
                 pl.BlockSpec((1, 1), lambda g_, i: (0, 0),
@@ -1209,7 +1244,7 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
             out_specs=[
                 seq_spec(bq, q_map),
                 seq_spec(bk, kblk_map),
-                seq_spec(bk, kblk_map),
+                seq_spec(bk, kblk_map, d_v),
             ],
             out_shape=[dq_struct, dk_struct, dv_struct],
             interpret=interpret,
@@ -1217,28 +1252,21 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
             name="flash_bwd" if bd is None else "bd_flash_bwd",
         )(qr, kr, vr, dor, lse_r, delta, seed_a, bias_a)
         note_kernel(prefix + "flash_bwd",
-                    *flash_bwd_work(*shape, causal, bd=bd))
-        if bthd:
-            return (dq[:, :tq].reshape(b, tq, h, d),
-                    dk[:, :tk].reshape(b, tk, h, d),
-                    dv[:, :tk].reshape(b, tk, h, d))
-        return (dq[:, :tq].reshape(b, h, tq, d),
-                dk[:, :tk].reshape(b, h, tk, d),
-                dv[:, :tk].reshape(b, h, tk, d))
+                    *flash_bwd_work(*shape, causal, bd=bd, d_v=d_v))
+        return _from_flat(bthd, (b, h, tq, tk, d, d_v), dq, dk, dv)
     # one program's resident whole-sequence operands, for _grid_params
-    kv_bytes = tk_p * hpb * d * k.dtype.itemsize
+    k_bytes = tk_p * hpb * d * k.dtype.itemsize
+    v_bytes = tk_p * hpb * d_v * v.dtype.itemsize
     q_bytes = tq_p * hpb * d * q.dtype.itemsize
+    do_bytes = tq_p * hpb * d_v * g.dtype.itemsize
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_k=bk, seq_k=tk, seq_q=tq,
-                          dropout_p=dropout_p, has_bias=has_bias,
-                          d_head=d, hpb=hpb, n_heads=h, bd=bd),
+        functools.partial(_bwd_dq_kernel, block_k=bk, **kernel_kw),
         grid=(b * hg, tq_p // bq),
         in_specs=[
             seq_spec(bq, q_map),
             seq_spec(tk_p, kv_map),
-            seq_spec(tk_p, kv_map),
-            seq_spec(bq, q_map),
+            seq_spec(tk_p, kv_map, d_v),
+            seq_spec(bq, q_map, d_v),
             row_spec,
             row_spec,
             pl.BlockSpec((1, 1), lambda g_, i: (0, 0),
@@ -1249,23 +1277,20 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
         out_specs=seq_spec(bq, q_map),
         out_shape=dq_struct,
         interpret=interpret,
-        compiler_params=_grid_params(kv_bytes, kv_bytes),
+        compiler_params=_grid_params(k_bytes, v_bytes),
         name="flash_bwd_dq" if bd is None else "bd_flash_bwd_dq",
     )(qr, kr, vr, dor, lse_r, delta, seed_a, bias_a)
     note_kernel(prefix + "flash_bwd_dq",
-                *flash_bwd_work(*shape, causal, matmuls=3, bd=bd))
+                *flash_bwd_work(*shape, causal, matmuls=3, bd=bd, d_v=d_v))
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=bq, seq_k=tk, seq_q=tq,
-                          dropout_p=dropout_p, has_bias=has_bias,
-                          d_head=d, hpb=hpb, n_heads=h, bd=bd),
+        functools.partial(_bwd_dkv_kernel, block_q=bq, **kernel_kw),
         grid=(b * hg, tk_p // bk),
         in_specs=[
             seq_spec(tq_p, qfull_map),
             seq_spec(bk, kblk_map),
-            seq_spec(bk, kblk_map),
-            seq_spec(tq_p, qfull_map),
+            seq_spec(bk, kblk_map, d_v),
+            seq_spec(tq_p, qfull_map, d_v),
             rowfull_spec,
             rowfull_spec,
             pl.BlockSpec((1, 1), lambda g_, j: (0, 0),
@@ -1279,24 +1304,30 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
         ],
         out_specs=[
             seq_spec(bk, kblk_map),
-            seq_spec(bk, kblk_map),
+            seq_spec(bk, kblk_map, d_v),
         ],
         out_shape=[dk_struct, dv_struct],
         interpret=interpret,
-        compiler_params=_grid_params(q_bytes, q_bytes, _row_bytes(tq_p),
+        compiler_params=_grid_params(q_bytes, do_bytes, _row_bytes(tq_p),
                                      _row_bytes(tq_p)),
         name="flash_bwd_dkv" if bd is None else "bd_flash_bwd_dkv",
     )(qr, kr, vr, dor, lse_r, delta, seed_a, bias_a)
     note_kernel(prefix + "flash_bwd_dkv",
-                *flash_bwd_work(*shape, causal, matmuls=4, bd=bd))
+                *flash_bwd_work(*shape, causal, matmuls=4, bd=bd, d_v=d_v))
+    return _from_flat(bthd, (b, h, tq, tk, d, d_v), dq, dk, dv)
 
+
+def _from_flat(bthd: bool, dims, dq, dk, dv):
+    """The backward kernels' padded flat results in the caller's
+    layout: dq and dk at the q/k width, dv at the value width."""
+    b, h, tq, tk, d, d_v = dims
     if bthd:
         return (dq[:, :tq].reshape(b, tq, h, d),
                 dk[:, :tk].reshape(b, tk, h, d),
-                dv[:, :tk].reshape(b, tk, h, d))
+                dv[:, :tk].reshape(b, tk, h, d_v))
     return (dq[:, :tq].reshape(b, h, tq, d),
             dk[:, :tk].reshape(b, h, tk, d),
-            dv[:, :tk].reshape(b, h, tk, d))
+            dv[:, :tk].reshape(b, h, tk, d_v))
 
 
 def _bwd(causal, scale_arg, interpret, dropout_p, bthd, block_diffusion,

@@ -27,3 +27,4 @@ from .nemotron_h import (CausalLMOutput, NemotronHConfig,  # noqa: F401
 from .sdar_moe import (BlockDiffusionOutput, SdarMoeConfig,  # noqa: F401
                        SdarMoeForCausalLM, block_diffusion_loss,
                        block_diffusion_metrics)
+from .kimi_linear import KimiLinearConfig, KimiLinearForCausalLM  # noqa

@@ -35,15 +35,17 @@ from .norm import RMSNorm
 __all__ = ["Mamba2Mixer", "ssd_chunked_scan", "causal_depthwise_conv"]
 
 
-def causal_depthwise_conv(x, weight, bias):
+def causal_depthwise_conv(x, weight, bias=None):
     """``y[t] = bias + sum_k weight[k] * x[t - (K-1) + k]`` per channel,
-    zeros before the sequence starts. x [B, L, C], weight [K, C]."""
+    zeros before the sequence starts. x [B, L, C], weight [K, C]; no
+    bias term where ``bias`` is None."""
     k = weight.shape[0]
     length = x.shape[1]
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     out = bias
     for i in range(k):
-        out = out + padded[:, i:i + length] * weight[i]
+        term = padded[:, i:i + length] * weight[i]
+        out = term if out is None else out + term
     return out
 
 

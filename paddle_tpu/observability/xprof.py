@@ -50,6 +50,9 @@ metrics are on, all at trace time and nothing per step:
   (``kernels.flash_attention.flash_tile_census``), published summed as
   ``pt_flash_tiles_visited`` / ``pt_flash_tiles_whole`` /
   ``pt_flash_tiles_diagonal``;
+- ``note_kda_chunks``: each Kimi Delta Attention layer notes the
+  chunks its gated delta rule walks (sequences x chunks a sequence),
+  published summed as ``pt_kda_chunks``;
 - ``note_remat_kept``: each named result that a layer's recomputation
   keeps (``nn.recompute_layer``'s policy) notes its bytes, published as
   ``pt_remat_kept_sites`` / ``pt_remat_kept_bytes``;
@@ -74,6 +77,7 @@ __all__ = ["ProgramCardRegistry", "cards", "enabled", "harvest",
            "flops_of", "note_kernel", "note_dropout_mask",
            "note_qkv_grad_summed", "note_ssd_scan_kernel",
            "note_bd_attention", "note_flash_tiles", "note_remat_kept",
+           "note_kda_chunks",
            "kernel_notes",
            "op_scopes", "parse_op_names"]
 
@@ -250,7 +254,7 @@ def tracing(fn_name: str) -> Iterator[None]:
     outer = (getattr(_TLS, "notes", None), getattr(_TLS, "masked", None),
              getattr(_TLS, "summed", None), getattr(_TLS, "scans", None),
              getattr(_TLS, "bd_sites", None), getattr(_TLS, "kept", None),
-             getattr(_TLS, "tiles", None))
+             getattr(_TLS, "tiles", None), getattr(_TLS, "kda", None))
     _TLS.notes = notes = []
     _TLS.masked = masked = []
     _TLS.summed = summed = []
@@ -258,11 +262,12 @@ def tracing(fn_name: str) -> Iterator[None]:
     _TLS.bd_sites = bd_sites = []
     _TLS.kept = kept = []
     _TLS.tiles = tiles = []
+    _TLS.kda = kda = []
     try:
         yield
     finally:
         (_TLS.notes, _TLS.masked, _TLS.summed, _TLS.scans,
-         _TLS.bd_sites, _TLS.kept, _TLS.tiles) = outer
+         _TLS.bd_sites, _TLS.kept, _TLS.tiles, _TLS.kda) = outer
         if outer[0] is not None:    # an entry point traced inside another
             outer[0].extend(notes)
             outer[1].extend(masked)
@@ -271,6 +276,7 @@ def tracing(fn_name: str) -> Iterator[None]:
             outer[4].extend(bd_sites)
             outer[5].extend(kept)
             outer[6].extend(tiles)
+            outer[7].extend(kda)
         if _metrics.enabled():
             with _NOTES_LOCK:
                 _KERNEL_NOTES[fn_name] = notes
@@ -314,6 +320,12 @@ def tracing(fn_name: str) -> Iterator[None]:
                 "those of the visited tiles walked as noisy diagonal "
                 "sub-tiles under the block-diffusion mask").set(
                     sum(t[2] for t in tiles), fn=fn_name)
+            _metrics.gauge(
+                "pt_kda_chunks",
+                "chunks that the Kimi Delta Attention layers' gated delta "
+                "rule walks (layers x sequences x chunks a sequence) in "
+                "the newest trace of the entry point").set(
+                    sum(kda), fn=fn_name)
             _metrics.gauge(
                 "pt_remat_kept_sites",
                 "named results that recomputed layers keep across the "
@@ -409,6 +421,15 @@ def note_remat_kept(bytes_: int) -> None:
     kept = getattr(_TLS, "kept", None)
     if kept is not None and _metrics.enabled():
         kept.append(int(bytes_))
+
+
+def note_kda_chunks(chunks: int) -> None:
+    """Called once per traced Kimi Delta Attention layer with the chunks
+    its gated delta rule walks in one call. A no-op unless metrics are
+    on and a tracked entry point is being traced."""
+    kda = getattr(_TLS, "kda", None)
+    if kda is not None and _metrics.enabled():
+        kda.append(int(chunks))
 
 
 @contextlib.contextmanager

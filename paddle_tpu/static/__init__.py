@@ -470,10 +470,42 @@ class TrainStep:
         # pipeline shows up as jit_traces_total{fn=...} growth + a
         # storm warning instead of a silent 100x slowdown
         self._span_name = f"TrainStep({type(model).__name__})"
+        # the skip-step guard selects between the updated and the old
+        # state; where the compiler finds that program too large for the
+        # device, the update runs inside a conditional instead (_call)
+        self._guard_in_place = False
+        self._jit()
+
+    def _jit(self) -> None:
         self._jitted = _obs.instrumented_jit(
             self._step, self._span_name, donate_argnums=(0,))
         self._jitted_multi = _obs.instrumented_jit(
             self._multi, self._span_name + ".multi", donate_argnums=(0,))
+
+    def _call(self, jitted: str, *args):
+        """Run a compiled step. A select over the updated and the old
+        state lets XLA copy the old master and moments before their
+        in-place write (2.4 GB of a 600M-parameter step, ROADMAP A2(d));
+        when that program does not fit the device, the compiler refuses
+        it before any buffer is donated, and the step is traced again
+        with the update inside one conditional (``amp.guarded_update``),
+        which writes the state in place. The select stays the default:
+        the conditional's operand layouts cost the hybrid decoder 2% of
+        its step (PERF.md section 6, PR 39)."""
+        try:
+            return getattr(self, jitted)(*args)
+        except jax.errors.JaxRuntimeError as e:
+            if (self._guard_in_place or "RESOURCE_EXHAUSTED" not in str(e)
+                    or not (self._skip_guard or self.scaler is not None)
+                    or any(x.is_deleted() for x in jax.tree.leaves(
+                        self.state) if isinstance(x, jax.Array))):
+                raise
+        warnings.warn(f"{self._span_name}: the skip-step guard's select "
+                      f"does not fit the device; the optimizer's update "
+                      f"runs inside a conditional instead")
+        self._guard_in_place = True
+        self._jit()
+        return getattr(self, jitted)(*args)
 
     def _step(self, state, batch):
         import contextlib
@@ -539,16 +571,24 @@ class TrainStep:
             base = lr if lr is not None else resolve_lr(
                 self.optimizer.learning_rate, state["opt"]["step"] + 1)
             lr = base * batch["lr_scale"]
-        new_params, new_opt = self.optimizer.apply_gradients(
-            params, grads, state["opt"], lr_override=lr)
+        def update():
+            return self.optimizer.apply_gradients(
+                params, grads, state["opt"], lr_override=lr)
+
+        if found_inf is not None and self._guard_in_place:
+            new_params, new_opt = _amp.guarded_update(
+                found_inf, update, (params, state["opt"]))
+        else:
+            new_params, new_opt = update()
         if found_inf is not None:
             # skip-step: discard the whole update in-graph — params,
             # optimizer slots (incl. the step counter, matching the
             # reference's update_loss_scaling) and buffer stats
-            new_params = _amp.select_update(found_inf, new_params,
-                                            params)
-            new_opt = _amp.select_update(found_inf, new_opt,
-                                         state["opt"])
+            if not self._guard_in_place:
+                new_params = _amp.select_update(found_inf, new_params,
+                                                params)
+                new_opt = _amp.select_update(found_inf, new_opt,
+                                             state["opt"])
             new_buffers = _amp.select_update(found_inf, new_buffers,
                                              buffers)
             if self._defer_probes and _obs.enabled():
@@ -603,7 +643,7 @@ class TrainStep:
         with phases.phase("make_batch"):
             batch = self._make_batch(args, labels, kwargs)
         with phases.phase("dispatch", fn=self._span_name):
-            self.state, metrics = self._jitted(self.state, batch)
+            self.state, metrics = self._call("_jitted", self.state, batch)
         phases.dispatched(metrics)
         with phases.phase("drain"):
             return self._drain_signals(metrics)
@@ -623,8 +663,8 @@ class TrainStep:
             lr = host_lr_of(self.optimizer)
             lr = None if lr is None else jnp.float32(lr)
         with phases.phase("dispatch", fn=self._span_name + ".multi"):
-            self.state, metrics = self._jitted_multi(self.state, batch,
-                                                     lr)
+            self.state, metrics = self._call("_jitted_multi", self.state,
+                                             batch, lr)
         phases.dispatched(metrics, stacked=True)
         with phases.phase("drain"):
             return self._drain_signals(metrics)

@@ -21,7 +21,8 @@ import jax.numpy as jnp
 import paddle_tpu as pt
 from paddle_tpu import io as io_mod
 from paddle_tpu import observability as obs
-from paddle_tpu.amp import GradScaler, all_finite, select_update
+from paddle_tpu.amp import (GradScaler, all_finite, guarded_update,
+                            select_update)
 from paddle_tpu.static import TrainStep
 from paddle_tpu.testing import faults
 
@@ -86,6 +87,95 @@ def test_select_update_keeps_current_on_inf():
     assert int(kept["s"]) == 4
     applied = select_update(jnp.asarray(False), new, old)
     np.testing.assert_array_equal(np.asarray(applied["a"]), 1.0)
+
+
+@pytest.mark.parametrize("found_inf", [True, False])
+def test_guarded_update_runs_or_keeps(found_inf):
+    old = {"a": jnp.zeros(3), "s": jnp.asarray(4)}
+    out = jax.jit(lambda f: guarded_update(
+        f, lambda: {"a": jnp.ones(3), "s": jnp.asarray(5)}, old))(
+        jnp.asarray(found_inf))
+    np.testing.assert_array_equal(np.asarray(out["a"]),
+                                  0.0 if found_inf else 1.0)
+    assert int(out["s"]) == (4 if found_inf else 5)
+
+
+def _adamw_bf16_step(guard=True, in_place=False):
+    pt.set_flags({"skip_nonfinite_steps": guard})
+    try:
+        pt.seed(3)
+        net = pt.nn.Linear(4, 2)
+        net.to(dtype="bfloat16")
+        step = TrainStep(net, pt.optimizer.AdamW(1e-2),
+                         lambda o, y: pt.nn.functional.cross_entropy(
+                             o.astype(jnp.float32), y))
+    finally:
+        pt.set_flags({"skip_nonfinite_steps": True})
+    if in_place:
+        step._guard_in_place = True
+        step._jit()
+    return step
+
+
+@pytest.mark.parametrize("in_place", [False, True])
+def test_guarded_step_equals_unguarded_on_finite_steps(in_place):
+    """Either form of the guard changes what XLA may copy, not a value:
+    AdamW over bf16 parameters with f32 masters, guard on and off,
+    agrees bit for bit after clean steps."""
+    def trained(step):
+        x, y = _data()
+        for _ in range(3):
+            step(x.astype(jnp.bfloat16), labels=y)
+        return jax.tree.map(np.asarray, (step.state["params"],
+                                         step.state["opt"]))
+
+    on = trained(_adamw_bf16_step(in_place=in_place))
+    off = trained(_adamw_bf16_step(guard=False))
+    jax.tree.map(np.testing.assert_array_equal, on, off)
+
+
+def test_guard_runs_in_place_where_the_select_does_not_fit():
+    """The compiler's out-of-memory verdict on the select's program,
+    given before any buffer is donated, makes the step trace the update
+    inside a conditional; the skipped step still keeps every leaf."""
+    step = _adamw_bf16_step()
+    refused = []
+    real = step._jitted
+
+    def refuse(*args):
+        refused.append(1)
+        step._jitted = real
+        raise jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran "
+            "out of memory in memory space hbm.")
+
+    step._jitted = refuse
+    x, y = _data()
+    with pytest.warns(UserWarning, match="conditional"):
+        step(x.astype(jnp.bfloat16), labels=y)
+    assert refused == [1] and step._guard_in_place
+    assert "cond" in str(jax.make_jaxpr(step._step)(
+        step.state, step._make_batch((x.astype(jnp.bfloat16),), y, {})))
+    kept = jax.tree.map(np.asarray, (step.state["params"],
+                                     step.state["opt"]))
+    xp, yp = _data(poison=True)
+    step(xp.astype(jnp.bfloat16), labels=yp)
+    jax.tree.map(np.testing.assert_array_equal, kept,
+                 jax.tree.map(np.asarray, (step.state["params"],
+                                           step.state["opt"])))
+
+
+def test_guard_does_not_retry_other_errors():
+    step = _adamw_bf16_step()
+
+    def fail(*args):
+        raise jax.errors.JaxRuntimeError("INTERNAL: something else")
+
+    step._jitted = fail
+    x, y = _data()
+    with pytest.raises(jax.errors.JaxRuntimeError, match="INTERNAL"):
+        step(x.astype(jnp.bfloat16), labels=y)
+    assert not step._guard_in_place
 
 
 # ---------------------------------------------------------------------------

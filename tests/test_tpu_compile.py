@@ -476,3 +476,113 @@ def test_encoder_layer_on_one_chip_has_its_kernels_and_no_exchange(chip):
     text = _encoder_layer_step_text(on_mesh=False)
     assert "all-reduce" not in text
     _assert_four_kernels(text)
+
+
+# The accepted cells' flash kernels (BERT's one tile with dropout, the
+# hybrid decoder's causal 8192 positions, the block-diffusion decoder's
+# 16,384), as the PR 38 tree lowered them for a v5e: the sha256 of each
+# Mosaic module's text without source locations. The value width must
+# leave them as they were when v is as wide as q; a change to these
+# kernels changes these digests, and the accepted cells' programs with
+# them.
+_ACCEPTED = {
+    "bert": {
+        "flash_fwd": "ea2d6094799bea0d6e6973dbddd94ce0"
+                     "3c8000c359145d1d43adb0e3cbcda621",
+        "flash_bwd": "2bf583091f06fabc59b787ab884cb204"
+                     "f6c03baca442c82bfb3aaf9555106d76"},
+    "causal": {
+        "flash_fwd": "fce1d5fd03b5e101b36e8a82c9fd96d9"
+                     "54451060cdc89bdaa68f70f575a7a665",
+        "flash_bwd_dq": "a652d6432ea0663e3e4ccdcdeeeb546b"
+                        "b1374a13309a318d31d350b9b3dbb48b",
+        "flash_bwd_dkv": "498d384d3d8b2cf7e7295b2612b0a84e"
+                         "42ae5e0ddcd23235b158629f4178e81d"},
+    "bd": {
+        "bd_flash_fwd": "76c25fcc52f20c7a8f9e368c1ad2132d"
+                        "08e1a0af4e5f1bc85b3a5f4b5fb3ad11",
+        "bd_flash_bwd_dq": "a6e2bec7a413fbc78a625ed1d8c3c3af"
+                           "5bab4dba117a76e24dd6b0ed3064b5d7",
+        "bd_flash_bwd_dkv": "6fcd53f8efbc3ebe348fbc9fffe611e4"
+                            "fdafd25994dd98dc999575edcfde99fe"},
+}
+
+
+def _mosaic_texts(fn, chip, *shapes):
+    """{kernel name: its Mosaic module's text, source locations left
+    out} of ``fn`` lowered for ``chip``."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir as jmlir
+    from jax._src.lib import tpu as tpu_dialect
+    from jax._src.lib.mlir import ir
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(fn).lower(*args).as_text()
+    out = {}
+    for body in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text):
+        with jmlir.make_ir_context() as ctx:
+            tpu_dialect.register_dialect(ctx)
+            ctx.allow_unregistered_dialects = True
+            module = ir.Module.parse(base64.b64decode(body))
+            asm = module.operation.get_asm(enable_debug_info=False)
+        out[re.match(r"module @(\w+)", asm).group(1)] = asm
+    return out
+
+
+def _flash_grad(**kw):
+    from paddle_tpu.kernels.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, bthd=True, **kw)
+                       .astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("cell", sorted(_ACCEPTED))
+def test_accepted_cells_flash_kernels_are_unchanged(chip, cell):
+    """The value width (``d_v``) leaves the kernels of the accepted cells,
+    where v is as wide as q and k, as they were: their lowered text is
+    the PR 38 tree's."""
+    import hashlib
+
+    bf = jnp.bfloat16
+    fn, shape = {
+        "bert": (_flash_grad(dropout_p=0.1, seed=jnp.ones((1, 1), jnp.int32)),
+                 (32, 512, 12, 64)),
+        "causal": (_flash_grad(causal=True), (2, 8192, 32, 128)),
+        "bd": (_flash_grad(block_diffusion=(8192, 4)), (2, 16384, 32, 128)),
+    }[cell]
+    texts = _mosaic_texts(fn, chip, *[(shape, bf)] * 3)
+    got = {k: hashlib.sha256(t.encode()).hexdigest()
+           for k, t in texts.items()}
+    assert got == _ACCEPTED[cell]
+
+
+def test_value_width_flash_kernels_take_256_value_lanes(chip):
+    """At the latent attention's widths a program holds a pair of heads:
+    384 lanes of q and k, 256 of v, the output and dv."""
+    bf = jnp.bfloat16
+    texts = _mosaic_texts(_flash_grad(causal=True), chip,
+                          ((1, 2048, 4, 192), bf), ((1, 2048, 4, 192), bf),
+                          ((1, 2048, 4, 128), bf))
+    assert set(texts) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    fwd = texts["flash_fwd"].split("\n")[2]         # the arguments
+    assert "memref<1x512x384xbf16" in fwd
+    assert "memref<1x2048x256xbf16" in fwd and "memref<1x512x256xbf16" in fwd
+
+
+def test_latent_attention_flash_kernels_compile_at_the_cells_shape(chip):
+    """The latent attention layer of the Kimi Linear cell
+    (kimi_linear_48b_a3b_ep32_s16k): one sequence of 16,384 positions,
+    32 heads, q and k 192 wide, v 128, causal. A program holds a pair of
+    heads, whole sequences of k and v (or q and dO) resident: Mosaic
+    takes the value width's blocks and the VMEM they ask for."""
+    qk = ((1, 16384, 32, 192), jnp.bfloat16)
+    text = _compile(_flash_grad(causal=True), chip, qk, qk,
+                    ((1, 16384, 32, 128), jnp.bfloat16))
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text
+    assert "16384,16384]" not in text
