@@ -24,7 +24,11 @@ chunk:
 
 with ``K~ = k exp(g)`` and ``Q~ = q exp(g)`` (rows). ``W = (I + A)^-1
 beta K~`` and ``U = (I + A)^-1 beta V`` (a unit lower-triangular solve in
-float32, the UT/WY transform) make ``delta = U - W S``.
+float32, the UT/WY transform) make ``delta = U - W S``. The solve is an
+explicit inverse, forward substitution inside sub-chunks and 2 x 2 block
+products above them, applied by one product (``_unit_lower_solve``): on
+a TPU v5e ``triangular_solve`` inverts the [C, C] block in a custom call
+of 86 us for a chunk of 32 heads.
 
 **Stability.** The decay is per channel, and ``A_log`` is initialised
 at up to log 16: a chunk of 64 sums its log-decay to below -100 at a
@@ -51,8 +55,8 @@ again and taking its gradient (``_kda_bwd``). Computing the intra-chunk
 work of all chunks at once, with only the state's carry in the scan,
 held 0.74 GB more at a layer's recomputed forward (PERF.md section 6,
 PR 39). Matmul operands are in the input's dtype with float32
-accumulation; the log-decay, beta, the triangular solve and the states
-are float32.
+accumulation; the log-decay, beta, the triangular solve (every product
+of it at ``Precision.HIGHEST``) and the states are float32.
 """
 
 from __future__ import annotations
@@ -75,6 +79,81 @@ __all__ = ["KimiDeltaAttention", "kda_chunked"]
 
 # a chunk's sub-chunks: their own pairwise decays are formed directly
 SUB_CHUNK = 16
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _mm(x, y):
+    """A float32 product at full precision (the chip's default is one
+    bf16 pass)."""
+    return jnp.matmul(x, y, precision=_HIGHEST)
+
+
+def _unit_lower_inverse(a):
+    """``(I + a)^-1`` for ``a`` [.., C, C] strictly lower, float32, C a
+    multiple of ``min(SUB_CHUNK, C)``.
+
+    The diagonal blocks of ``SUB_CHUNK`` rows are inverted by forward
+    substitution, a row at a time for every block of every sequence and
+    head at once. Then 2 x 2 block inversion doubles the blocks,
+
+        [T11 0; T21 T22]^-1 = [X11 0; -X22 T21 X11, X22]
+
+    for every pair of a level at once: with ``x`` the block-diagonal
+    [C, C] of the inverses so far, ``x - x T21 x`` with ``T21`` the part
+    of ``a`` between each pair's two halves, two products of [C, C]
+    matrices a level. Every factor is an inverse of a diagonal block of
+    ``I + a``, which the rule keeps bounded (Higham's block method). The
+    nilpotent product ``prod (I + (-a)^(2^i))`` is not: with correlated
+    keys the powers of ``a`` reach 1e13 to 1e17 and cancel
+    (``tests/test_kimi_linear.py``). On a TPU v5e the substitution beat
+    four more levels of products down to single rows: a layer's rule at
+    the Kimi cell's shape took 64.9 against 70.4 ms forward and
+    backward."""
+    c = a.shape[-1]
+    sub = min(SUB_CHUNK, c)
+    n = c // sub
+    blocks = jnp.stack([a[..., i * sub:(i + 1) * sub, i * sub:(i + 1) * sub]
+                        for i in range(n)], -3)          # [.., n, sub, sub]
+    x = jnp.broadcast_to(jnp.eye(sub, dtype=a.dtype), blocks.shape)
+    for t in range(1, sub):     # row t: e_t - sum_{s < t} T_ts x_s
+        x = x.at[..., t, :].add(-jnp.sum(blocks[..., t, :, None] * x, -2))
+    x = jnp.where(jnp.eye(n, dtype=bool)[:, None, :, None],
+                  x[..., None, :], 0.0).reshape(a.shape)
+    row = jnp.arange(c)[:, None]
+    col = jnp.arange(c)[None, :]
+    s = sub
+    while s < c:
+        between = (row // (2 * s) == col // (2 * s)) & (row // s != col // s)
+        x = x - _mm(x, _mm(jnp.where(between, a, 0.0), x))
+        s *= 2
+    return x
+
+
+@jax.custom_vjp
+def _unit_lower_solve(a, rhs):
+    """``(I + a)^-1 rhs`` for ``a`` [.., C, C] strictly lower and ``rhs``
+    [.., C, N], float32: the inverse (``_unit_lower_inverse``) applied by
+    one product. Its gradient reuses the inverse: ``d_rhs = X^T dx`` and
+    ``d_a = -strict_lower(d_rhs x^T)``."""
+    return _unit_lower_solve_fwd(a, rhs)[0]
+
+
+def _unit_lower_solve_fwd(a, rhs):
+    inv = _unit_lower_inverse(a)
+    x = _mm(inv, rhs)
+    return x, (inv, x)
+
+
+def _unit_lower_solve_bwd(res, dx):
+    inv, x = res
+    d_rhs = _mm(jnp.swapaxes(inv, -1, -2), dx)
+    c = inv.shape[-1]
+    strict = jnp.tril(jnp.ones((c, c), bool), -1)
+    return jnp.where(strict, -_mm(d_rhs, jnp.swapaxes(x, -1, -2)),
+                     0.0), d_rhs
+
+
+_unit_lower_solve.defvjp(_unit_lower_solve_fwd, _unit_lower_solve_bwd)
 
 
 @jax.named_scope("pt.kda_scan")     # a loop body's own name stack
@@ -133,8 +212,7 @@ def _intra(q, k, v, g, beta, scale: float):
     gh = heads_first(g)
     k_tilde = heads_first(kf) * jnp.exp(gh)
     rhs = jnp.concatenate([k_tilde, heads_first(v.astype(f32))], -1) * b_t
-    wu = jax.lax.linalg.triangular_solve(a, rhs, left_side=True,
-                                         lower=True, unit_diagonal=True)
+    wu = _unit_lower_solve(a, rhs)
     last = gh[:, :, -1:]
     k_end = heads_first(kf) * jnp.exp(last - gh)
     return ((heads_first(qf) * jnp.exp(gh)).astype(cd),
@@ -177,9 +255,12 @@ def kda_chunked(q, k, v, g, beta, scale: float, chunk: int = 64):
     work again and taking its gradient, a chunk at a time. XLA's own
     gradient held the chunks' products, decays and their cotangents at
     once: 2.7 GB for the rule at 16,384 positions, 7.85 GB for a layer's
-    backward, and the cell's step did not fit the chip."""
+    backward, and the cell's step did not fit the chip. A traced call
+    notes that its chunks' solve is the block-product inverse
+    (``pt_kda_inverse_sites``)."""
     if chunk % min(SUB_CHUNK, chunk):
         raise ValueError(f"sub-chunks of {SUB_CHUNK} do not divide {chunk}")
+    xprof.note_kda_inverse()
     return _kda(q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32),
                 float(scale), chunk)
 
@@ -277,7 +358,8 @@ class KimiDeltaAttention(Layer):
     Scopes: ``pt.kda_proj`` the projections, ``pt.kda_conv`` the three
     convs and their SiLU, ``pt.kda_scan`` the L2 norms, the decay gate,
     beta, the chunked rule and the gated RMS norm. A traced call notes
-    its chunks (``pt_kda_chunks``: sequences x chunks a sequence)."""
+    its chunks (``pt_kda_chunks``: sequences x chunks a sequence) and
+    its solve (``pt_kda_inverse_sites``)."""
 
     def __init__(self, hidden_size: int, num_heads: int, head_dim: int,
                  conv_kernel: int = 4, chunk_size: int = 64,

@@ -53,6 +53,9 @@ metrics are on, all at trace time and nothing per step:
 - ``note_kda_chunks``: each Kimi Delta Attention layer notes the
   chunks its gated delta rule walks (sequences x chunks a sequence),
   published summed as ``pt_kda_chunks``;
+- ``note_kda_inverse``: each Kimi Delta Attention layer whose chunks'
+  unit-triangular solve is the block-product inverse notes itself,
+  published as ``pt_kda_inverse_sites``;
 - ``note_remat_kept``: each named result that a layer's recomputation
   keeps (``nn.recompute_layer``'s policy) notes its bytes, published as
   ``pt_remat_kept_sites`` / ``pt_remat_kept_bytes``;
@@ -77,7 +80,7 @@ __all__ = ["ProgramCardRegistry", "cards", "enabled", "harvest",
            "flops_of", "note_kernel", "note_dropout_mask",
            "note_qkv_grad_summed", "note_ssd_scan_kernel",
            "note_bd_attention", "note_flash_tiles", "note_remat_kept",
-           "note_kda_chunks",
+           "note_kda_chunks", "note_kda_inverse",
            "kernel_notes",
            "op_scopes", "parse_op_names"]
 
@@ -254,7 +257,8 @@ def tracing(fn_name: str) -> Iterator[None]:
     outer = (getattr(_TLS, "notes", None), getattr(_TLS, "masked", None),
              getattr(_TLS, "summed", None), getattr(_TLS, "scans", None),
              getattr(_TLS, "bd_sites", None), getattr(_TLS, "kept", None),
-             getattr(_TLS, "tiles", None), getattr(_TLS, "kda", None))
+             getattr(_TLS, "tiles", None), getattr(_TLS, "kda", None),
+             getattr(_TLS, "kda_inv", None))
     _TLS.notes = notes = []
     _TLS.masked = masked = []
     _TLS.summed = summed = []
@@ -263,11 +267,13 @@ def tracing(fn_name: str) -> Iterator[None]:
     _TLS.kept = kept = []
     _TLS.tiles = tiles = []
     _TLS.kda = kda = []
+    _TLS.kda_inv = kda_inv = []
     try:
         yield
     finally:
         (_TLS.notes, _TLS.masked, _TLS.summed, _TLS.scans,
-         _TLS.bd_sites, _TLS.kept, _TLS.tiles, _TLS.kda) = outer
+         _TLS.bd_sites, _TLS.kept, _TLS.tiles, _TLS.kda,
+         _TLS.kda_inv) = outer
         if outer[0] is not None:    # an entry point traced inside another
             outer[0].extend(notes)
             outer[1].extend(masked)
@@ -277,6 +283,7 @@ def tracing(fn_name: str) -> Iterator[None]:
             outer[5].extend(kept)
             outer[6].extend(tiles)
             outer[7].extend(kda)
+            outer[8].extend(kda_inv)
         if _metrics.enabled():
             with _NOTES_LOCK:
                 _KERNEL_NOTES[fn_name] = notes
@@ -326,6 +333,11 @@ def tracing(fn_name: str) -> Iterator[None]:
                 "rule walks (layers x sequences x chunks a sequence) in "
                 "the newest trace of the entry point").set(
                     sum(kda), fn=fn_name)
+            _metrics.gauge(
+                "pt_kda_inverse_sites",
+                "Kimi Delta Attention layers whose chunks' unit-triangular "
+                "solve is the block-product inverse, in the newest trace "
+                "of the entry point").set(len(kda_inv), fn=fn_name)
             _metrics.gauge(
                 "pt_remat_kept_sites",
                 "named results that recomputed layers keep across the "
@@ -430,6 +442,15 @@ def note_kda_chunks(chunks: int) -> None:
     kda = getattr(_TLS, "kda", None)
     if kda is not None and _metrics.enabled():
         kda.append(int(chunks))
+
+
+def note_kda_inverse() -> None:
+    """Called once per traced Kimi Delta Attention layer whose chunks'
+    unit-triangular solve is the block-product inverse. A no-op unless
+    metrics are on and a tracked entry point is being traced."""
+    kda_inv = getattr(_TLS, "kda_inv", None)
+    if kda_inv is not None and _metrics.enabled():
+        kda_inv.append(1)
 
 
 @contextlib.contextmanager

@@ -6,6 +6,8 @@ factorisation of a chunk survives. Both sides compute in float32 here,
 so the only difference is the order of summation: every tolerance is
 1e-4 relative, far under what any of the planted faults does."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,6 +21,7 @@ from paddle_tpu.models import (KimiLinearConfig, KimiLinearForCausalLM,
                                routing_metrics)
 from paddle_tpu.nn.layer import functional_call
 from paddle_tpu.nn.layers import moe
+from paddle_tpu.nn.layers import kda
 from paddle_tpu.nn.layers.kda import kda_chunked
 from paddle_tpu.static import TrainStep
 
@@ -88,22 +91,45 @@ DECAYS = {"gentle": (0.019, 0.021), "a_16_dt_max": (1.52, 1.68),
           "any": (0.0, 40.0)}
 
 
-@pytest.mark.parametrize("decay", sorted(DECAYS))
-def test_the_chunked_rule_is_the_recurrence(decay):
+def unit(x):
+    return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+
+def sharing_one_direction(key, shape, mix):
+    """Unit keys ``mix`` of the way along one shared direction: a trained
+    layer's keys are correlated, and at ``mix`` 0.9 and beta near 1 a
+    chunk's ``I + A`` is where a product of powers of ``A`` cancels."""
+    shared, own = jax.random.split(key)
+    u = unit(jax.random.normal(shared, shape[-1:]))
+    z = unit(jax.random.normal(own, shape))
+    return unit(mix * u + math.sqrt(1 - mix * mix) * z)
+
+
+# the keys: at random, or 0.9 along one direction with beta near 1, at
+# gentle decays, so the chunk's A is large
+CASES = {**{name: (lohi, None) for name, lohi in DECAYS.items()},
+         "correlated": (DECAYS["gentle"], 0.9)}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_chunked_rule_is_the_recurrence(case):
     """Output and the gradients of q, k, v, the log-decay and beta, at
     several chunks of 64 and a padded tail."""
     b, length, h, dk, dv = 2, 200, 2, 16, 8
     keys = jax.random.split(jax.random.PRNGKey(3), 7)
-    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    (lo, hi), mix = CASES[case]
     q = unit(jax.random.normal(keys[0], (b, length, h, dk)))
     k = unit(jax.random.normal(keys[1], (b, length, h, dk)))
     v = jax.random.normal(keys[2], (b, length, h, dv))
-    lo, hi = DECAYS[decay]
     g = -jax.random.uniform(keys[3], (b, length, h, dk), minval=lo,
                             maxval=hi)
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], (b, length, h)))
+    if mix is not None:
+        k = sharing_one_direction(keys[6], (b, length, h, dk), mix)
+        beta = jax.random.uniform(keys[4], (b, length, h), minval=0.97,
+                                  maxval=0.999)
     cot = jax.random.normal(keys[5], (b, length, h, dv))
-    if decay != "gentle":
+    if (lo, hi) != DECAYS["gentle"]:
         assert float(jnp.max(jnp.sum(g[:, :64], axis=1))) < -100
     args = (q, k, v, g, beta)
     with jax.default_matmul_precision("highest"):
@@ -122,7 +148,6 @@ def test_the_chunked_rule_in_bf16_stays_near_the_recurrence():
     that of its operands, at the published chunk and sub-chunk."""
     b, length, h, d = 1, 256, 2, 32
     keys = jax.random.split(jax.random.PRNGKey(4), 5)
-    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
     q, k = (unit(jax.random.normal(x, (b, length, h, d))) for x in keys[:2])
     v = jax.random.normal(keys[2], (b, length, h, d))
     g = -jax.random.uniform(keys[3], (b, length, h, d), maxval=0.3)
@@ -134,6 +159,82 @@ def test_the_chunked_rule_in_bf16_stays_near_the_recurrence():
     with jax.default_matmul_precision("highest"):
         want = recurrence(q, k, v, g, beta, d ** -0.5)
     assert rel(got.astype(jnp.float32), want) < 0.03
+
+
+# -- the chunk's solve ---------------------------------------------------------
+
+def chunk_matrix(c, mix, beta, seed=0, dk=128, decay=0.02):
+    """A chunk's strictly lower ``A`` [2, 4, C, C] as the rule forms it,
+    ``A_ts = beta sum_c k_tc k_sc exp(g_tc - g_sc)``, at gentle decays;
+    ``mix`` None draws the keys at random."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    shape = (2, 4, c, dk)
+    k = (unit(jax.random.normal(keys[0], shape)) if mix is None
+         else sharing_one_direction(keys[0], shape, mix))
+    g = jnp.cumsum(-jax.random.uniform(keys[1], shape, minval=0.9 * decay,
+                                       maxval=1.1 * decay), axis=2)
+    strict = jnp.tril(jnp.ones((c, c), bool), -1)[..., None]
+    pair = jnp.exp(jnp.where(strict, g[:, :, :, None] - g[:, :, None],
+                             -jnp.inf))
+    return beta * jnp.sum(k[:, :, :, None] * k[:, :, None] * pair, -1)
+
+
+def doubling_product(a):
+    """``prod (I + (-a)^(2^i))``: exact for a nilpotent ``a`` in exact
+    arithmetic, and what the chunk solve must not be."""
+    c = a.shape[-1]
+    eye = jnp.eye(c, dtype=a.dtype)
+    x, power, n = eye - a, -a, 1
+    while 2 * n < c:
+        power = jnp.matmul(power, power, precision="highest")
+        x = jnp.matmul(x, eye + power, precision="highest")
+        n *= 2
+    return x
+
+
+# keys at random; 0.9 along one direction at beta 0.9; one direction at
+# beta 0.99
+KEYS = {"random": (None, 0.7), "correlated": (0.9, 0.9),
+        "one_direction": (1.0, 0.99)}
+INVERSES = {"block": kda._unit_lower_inverse, "doubling": doubling_product}
+
+
+@pytest.mark.parametrize("c", [16, 64])
+@pytest.mark.parametrize("keys", sorted(KEYS))
+@pytest.mark.parametrize("inverse", sorted(INVERSES))
+def test_the_chunk_inverse_is_the_inverse(inverse, keys, c):
+    """``_unit_lower_inverse`` against ``jnp.linalg.inv`` in float32,
+    within 1e-5 of the inverse's largest entry. The doubling product
+    passes on keys at random and fails on correlated ones, so the cases
+    tell the two apart."""
+    a = chunk_matrix(c, *KEYS[keys])
+    want = jnp.linalg.inv(jnp.eye(c) + a)
+    got = INVERSES[inverse](a)
+    err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+    if inverse == "doubling" and keys != "random":
+        assert err > 1e-5
+    else:
+        assert err < 1e-5
+
+
+@pytest.mark.parametrize("c", [16, 64])
+def test_the_chunk_solves_gradients_are_the_triangular_solves(c):
+    """The solve's own backward (one inverse, reused) against
+    ``jax.lax.linalg.triangular_solve``'s, at correlated keys."""
+    a = chunk_matrix(c, 0.9, 0.9, seed=1)
+    rhs = jax.random.normal(jax.random.PRNGKey(2), (2, 4, c, 24))
+    cot = jax.random.normal(jax.random.PRNGKey(3), (2, 4, c, 24))
+    with jax.default_matmul_precision("highest"):
+        got, pull = jax.vjp(kda._unit_lower_solve, a, rhs)
+        got_grads = pull(cot)
+        want, pull = jax.vjp(lambda x, y: jax.lax.linalg.triangular_solve(
+            x, y, left_side=True, lower=True, unit_diagonal=True), a, rhs)
+        want_grads = pull(cot)
+    assert rel(got, want) < 1e-5
+    strict = np.tril(np.ones((c, c), bool), -1)
+    for x, y in zip(got_grads, want_grads):
+        assert rel(x, y) < 1e-5
+    assert not np.any(np.asarray(got_grads[0])[..., ~strict])
 
 
 # -- the two mixers against the reference's ------------------------------------
@@ -306,7 +407,9 @@ def test_the_shares_add_up_to_the_uncut_expert_layer():
 
 def test_a_train_step_notes_the_chunks_of_its_kda_layers():
     """``pt_kda_chunks``: KDA layers x sequences x chunks a sequence,
-    set when the step is traced; the step trains."""
+    set when the step is traced, and ``pt_kda_inverse_sites``: the KDA
+    layers whose chunk solve is the block-product inverse; the step
+    trains."""
     from paddle_tpu import observability as obs
     obs.reset_all()
     pt.set_flags({"enable_metrics": True})
@@ -319,10 +422,13 @@ def test_a_train_step_notes_the_chunks_of_its_kda_layers():
         for _ in range(3):
             last = float(step(ids, labels=(labels,))["loss"])
         chunks = obs.gauge("pt_kda_chunks").value(fn=step._span_name)
+        inverses = obs.gauge("pt_kda_inverse_sites").value(
+            fn=step._span_name)
     finally:
         pt.set_flags({"enable_metrics": False})
         obs.reset_all()
     assert chunks == 4 * 2 * 3
+    assert inverses == 4
     assert np.isfinite(last) and last < first
 
 

@@ -315,6 +315,56 @@ def test_a_mamba2_layers_gradient_runs_the_scan_kernels_and_no_loop(chip):
     assert "reduce-window" not in text
 
 
+def _float32_dots(text, scope):
+    """``(operand_precision or None)`` of every dot and convolution
+    traced under ``scope`` whose two operands are float32."""
+    import re
+    dtypes = dict(re.findall(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[", text,
+                             re.MULTILINE))
+    found = []
+    for line in text.splitlines():
+        ops = re.search(r" (?:convolution|dot)\(%([^,]+), %([^)]+)\)", line)
+        if ops and re.search(rf'op_name="[^"]*{re.escape(scope)}', line) \
+                and all(dtypes.get(o) == "f32" for o in ops.groups()):
+            precision = re.search(r"operand_precision=\{([^}]*)\}", line)
+            found.append(precision and precision.group(1))
+    return found
+
+
+def test_a_kda_layers_gradient_solves_its_chunks_by_products(chip):
+    """One ``KimiDeltaAttention`` at the Kimi cell's widths (hidden 2304,
+    32 heads of 128, chunks of 64 over 16,384 positions) under the
+    layers' checkpoint, forward and backward: the chunks' unit-triangular
+    solve is the block-product inverse, so there is no
+    ``InvertDiagBlocksLowerTriangular`` custom call (86 us a chunk on a
+    TPU v5e, PERF.md section 5) and no ``triangular-solve``, and every dot
+    under ``pt.kda_scan`` whose operands are float32 runs at ``highest``
+    (the chip's default is one bf16 pass)."""
+    import paddle_tpu as pt
+    from paddle_tpu.nn.layer import functional_call
+    layer = pt.nn.KimiDeltaAttention(2304, 32, 128, chunk_size=64)
+    layer.to(dtype="bfloat16")
+    params = layer.param_dict()
+
+    def loss(p, u):
+        y = jax.checkpoint(lambda h: functional_call(layer, p, {}, h))(u)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip)
+         for k, v in params.items()},
+        jax.ShapeDtypeStruct((1, 16384, 2304), jnp.bfloat16, sharding=chip)
+    ).compile().as_text()
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    assert "triangular-solve" not in text
+    dots = _float32_dots(text, "pt.kda_scan")
+    # two levels of two products (blocks of 16 to 32 to 64) and the
+    # product that applies the inverse, in the forward, the recomputed
+    # forward and the backward
+    assert len(dots) >= 3 * 5
+    assert set(dots) == {"highest,highest"}
+
+
 def test_layer_norm_fwd_bwd_compiles(chip):
     from paddle_tpu.kernels.layer_norm import layer_norm_pallas
 
