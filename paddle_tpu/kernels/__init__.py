@@ -265,10 +265,25 @@ def _block_diffusion_attention(q, k, v, scale, layout: str, bd):
     return ref_impl(q, k, v, mask=mask, scale=scale)
 
 
+def _window_mask(mask, tq: int, tk: int, window: int):
+    """``mask`` (none, a keep mask or an additive one) with the keys
+    outside the sliding window of ``window`` keys hidden, bottom-right
+    aligned as the causal mask is."""
+    import jax.numpy as jnp
+    pos = jnp.arange(tq)[:, None] + (tk - tq)
+    inside = jnp.arange(tk)[None, :] > pos - window
+    if mask is None:
+        return inside
+    if mask.dtype == jnp.bool_:
+        return jnp.logical_and(mask, inside)
+    return jnp.where(inside, mask, jnp.finfo(mask.dtype).min)
+
+
 def maybe_flash_attention(q, k, v, mask=None, scale: Optional[float] = None,
                           causal: bool = False, dropout_p: float = 0.0,
                           training: bool = False, layout: str = "bhtd",
-                          block_diffusion=None):
+                          block_diffusion=None,
+                          window: Optional[int] = None):
     """q/k/v: [B, H, T, D] (``layout="bhtd"``, default) or
     [B, T, H, D] (``layout="bthd"`` — the projections' natural layout;
     the flash kernel gathers heads inside its block DMA, so the routed
@@ -296,6 +311,13 @@ def maybe_flash_attention(q, k, v, mask=None, scale: Optional[float] = None,
     anywhere; elsewhere plain XLA attention under the dense mask, for
     small sizes only. A site that takes the kernels notes itself
     (``pt_bd_attention_sites``).
+
+    ``window`` (a static int, with ``causal``) hides every key but the
+    ``window`` last up to a query's own: routed, the ``swa_flash_*``
+    kernels, which visit only the tiles inside the window (a site that
+    takes them notes itself, ``pt_swa_attention_sites``); elsewhere XLA
+    attention under the dense mask. A window that holds every key is
+    the causal mask.
     """
     from ..ops.attention import scaled_dot_product_attention as ref_impl
     import jax.numpy as jnp
@@ -307,6 +329,12 @@ def maybe_flash_attention(q, k, v, mask=None, scale: Optional[float] = None,
     bthd = layout == "bthd"
     t_axis = 1 if bthd else 2
     d = q.shape[-1]
+    if window is not None:
+        from .flash_attention import _window_of
+        if not causal:
+            raise ValueError(f"window={window} is a causal mask's: "
+                             "causal=True with it")
+        window = _window_of(window, k.shape[t_axis])
     # d%128 keeps MXU lanes full. Narrower head dims (BERT's 64) route
     # only where flash's O(T) memory is the point: training (the XLA
     # backward materializes [T,T] probs in fp32) or eval at lengths
@@ -348,7 +376,7 @@ def maybe_flash_attention(q, k, v, mask=None, scale: Optional[float] = None,
                 # give each shard its own stream (int32 wrap is fine)
                 seed = seed + shard * jnp.int32(0x3C6EF35F)
             kw = dict(seed=seed, causal=causal, scale=scale,
-                      dropout_p=drop, kv_bias=kv_bias)
+                      dropout_p=drop, kv_bias=kv_bias, window=window)
             if bthd and not bthd_supported(d, q.shape[2], v.shape[-1]):
                 # geometry the BTHD block tiling can't express (e.g.
                 # d=32, odd local head count): still flash, via the
@@ -360,8 +388,13 @@ def maybe_flash_attention(q, k, v, mask=None, scale: Optional[float] = None,
             return flash_attention(q, k, v, bthd=bthd, **kw)
 
         qkv = {0: DP, 2 if bthd else 1: MP}   # batch, heads
+        if window is not None:
+            from ..observability.xprof import note_swa_attention
+            note_swa_attention()
         return _per_shard(kernel, (q, k, v, seed, kv_bias),
                           (qkv, qkv, qkv, {}, {0: DP}), qkv)
+    if window is not None:
+        mask = _window_mask(mask, q.shape[t_axis], tk, window)
     if bthd:
         # XLA fallback wants [B, H, T, D]; the transpose pair here
         # costs what the caller-side head split used to cost

@@ -17,6 +17,11 @@ kernel blocked over keys scanning Q/dO. Scores are recomputed blockwise
 in VMEM, so the backward keeps the O(T) memory property too — the
 previous XLA einsum backward materialized the full [B, H, T, T] scores
 in fp32, which silently forfeited long-context training.
+
+Masks: none, causal, causal within a sliding window of ``window`` keys
+(``swa_flash_*``), and the block-diffusion rule (``bd_flash_*``). Each
+kernel walks only the key (or query) tiles that hold an allowed pair,
+and the tiles whose every pair is allowed without a mask.
 """
 
 from __future__ import annotations
@@ -63,17 +68,22 @@ def _block_sizes(tq: int, tk: int):
 
 def flash_fwd_work(b: int, h: int, tq: int, tk: int, d: int,
                    itemsize: int, causal: bool = False, bd=None,
-                   d_v: Optional[int] = None):
+                   d_v: Optional[int] = None,
+                   window: Optional[int] = None):
     """(FLOPs, HBM bytes) one forward call must do: the two matmuls
     QK^T at the q/k width ``d`` and PV at the value width ``d_v`` (``d``
     unless given): ``2*B*H*Tq*Tk*(d + d_v)``, half of it under a causal
     mask, ``2*B*H*(d + d_v)*(L^2 + K*L)`` under the block-diffusion mask
-    ``bd = (L, K)``: the allowed pairs, never the tiles visited); q, k,
+    ``bd = (L, K)`` and ``2*B*H*(d + d_v)*window_allowed_pairs`` under a
+    sliding window: the allowed pairs, never the tiles visited); q, k,
     v read and the output written once, plus the f32 logsumexp row."""
     d_v = d if d_v is None else d_v
     flops = 2.0 * b * h * tq * tk * (d + d_v)
     if bd is not None:
         flops = 2.0 * b * h * (d + d_v) * bd_allowed_pairs(*bd)
+    elif window is not None:
+        flops = 2.0 * b * h * (d + d_v) * window_allowed_pairs(tq, tk,
+                                                               window)
     elif causal:
         flops /= 2
     return flops, float(b * h * ((tq + tk) * d + (tk + tq) * d_v)
@@ -82,7 +92,8 @@ def flash_fwd_work(b: int, h: int, tq: int, tk: int, d: int,
 
 def flash_bwd_work(b: int, h: int, tq: int, tk: int, d: int,
                    itemsize: int, causal: bool = False,
-                   matmuls: int = 5, bd=None, d_v: Optional[int] = None):
+                   matmuls: int = 5, bd=None, d_v: Optional[int] = None,
+                   window: Optional[int] = None):
     """(FLOPs, HBM bytes) one backward call must do. The recompute
     backward runs five matmuls — S = QK^T, dQ = dS K and dK = dS^T Q at
     the q/k width ``d``, dP = dO V^T and dV = P^T dO at the value width
@@ -96,6 +107,9 @@ def flash_bwd_work(b: int, h: int, tq: int, tk: int, d: int,
     flops = 2.0 * b * h * tq * tk * width[matmuls]
     if bd is not None:      # the allowed pairs, as in flash_fwd_work
         flops = 2.0 * b * h * width[matmuls] * bd_allowed_pairs(*bd)
+    elif window is not None:
+        flops = 2.0 * b * h * width[matmuls] * window_allowed_pairs(
+            tq, tk, window)
     elif causal:
         flops /= 2
     written = {5: (tq + tk) * d + tk * d_v, 3: tq * d,
@@ -218,10 +232,10 @@ def _tile_positions(r0, block_q: int, c0, block_k: int):
 
 
 def _tile_valid(r0, block_q: int, c0, block_k: int, seq_k: int,
-                causal: bool, causal_offset: int, bd):
+                causal: bool, causal_offset: int, bd, window=None):
     """[BQ, BK] bool: the pairs of an edge tile that are allowed and
     real. The block-diffusion rule from the tile's edge vectors, the
-    tail and the causal masks from full-tile iotas."""
+    tail, the causal and the window masks from full-tile iotas."""
     if bd is not None:
         q_pos = r0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
         k_pos = c0 + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
@@ -230,6 +244,9 @@ def _tile_valid(r0, block_q: int, c0, block_k: int, seq_k: int,
     valid = k_pos < seq_k                              # tail-block mask
     if causal:
         valid = jnp.logical_and(valid, q_pos + causal_offset >= k_pos)
+    if window is not None:
+        valid = jnp.logical_and(valid, k_pos > q_pos + causal_offset
+                                - window)
     return valid
 
 
@@ -292,6 +309,31 @@ def bd_allowed_pairs(length: int, block: int) -> int:
     return length * length + block * length
 
 
+# -- the sliding window ---------------------------------------------------------
+#
+# Causal attention within a window of ``window`` keys: with the
+# bottom-right alignment ``o = seq_k - seq_q``, query ``q`` sees the keys
+# ``q + o - window < k <= q + o``, its own included. A query tile's keys
+# are then one run of tiles: a lower edge where the window starts, whole
+# tiles, and the causal edge; a key tile's queries one run likewise.
+
+def window_allowed_pairs(tq: int, tk: int, window: int) -> int:
+    """Allowed (query, key) pairs of one head and sequence under the
+    window: ``W (W + 1) / 2 + (L - W) W`` for ``tq == tk == L >= W``."""
+    import numpy as np
+    q = np.arange(tq, dtype=np.int64) + (tk - tq)
+    seen = np.minimum(q, tk - 1) - np.maximum(q - window + 1, 0) + 1
+    return int(np.sum(np.clip(seen, 0, None)))
+
+
+def _window_of(window: Optional[int], tk: int) -> Optional[int]:
+    """The window a call runs under: none where it holds every key (the
+    causal kernels then run, by their own names)."""
+    if window is None or window >= tk:
+        return None
+    return int(window)
+
+
 # -- a visited tile's kind ------------------------------------------------------
 #
 # A tile whose every (query, key) pair is allowed and real needs no mask:
@@ -318,15 +360,22 @@ def bd_tile_whole(r0, block_q: int, c0, block_k: int, seq: int,
 
 
 def tile_whole(r0, c0, block_k: int, seq_k: int, causal: bool,
-               causal_offset: int = 0):
+               causal_offset: int = 0, window: Optional[int] = None,
+               block_q: int = 0):
     """The same for the plain and the causal walks: no key of the tile
     past ``seq_k`` and, under the causal mask, its last key at or before
-    its first query plus ``causal_offset``. (A padded query row is not
-    masked by either walk: it is sliced off, and its dO is zero.)"""
+    its first query plus ``causal_offset``; under a ``window`` too, its
+    first key inside the window of the tile's last query (``r0 +
+    block_q - 1``, a padded row if the tile holds one: the stricter
+    reading). (A padded query row is not masked by any walk: it is
+    sliced off, and its dO is zero.)"""
     c1 = c0 + block_k
     if not causal:
         return c1 <= seq_k
-    return (c1 <= seq_k) & (c1 - 1 <= r0 + causal_offset)
+    whole = (c1 <= seq_k) & (c1 - 1 <= r0 + causal_offset)
+    if window is None:
+        return whole
+    return whole & (c0 > r0 + block_q - 1 + causal_offset - window)
 
 
 def _cut_run(lo, cnt, w_lo, w_hi, whole):
@@ -371,13 +420,61 @@ def _bd_subtiled(block_q: int, block_k: int, seq_k: int, bd) -> bool:
             and _SUB % bd[1] == 0)
 
 
+def _window_key_walk(r0, block_q: int, block_k: int, seq_q: int,
+                     seq_k: int, window: int):
+    """`_key_walk` under the sliding window: the keys from the first
+    query's window start to the last query's own key."""
+    offset = seq_k - seq_q
+    if offset == 0 and block_q == block_k and window % block_k == 0:
+        # square tiles, a window of whole tiles: the tile the window
+        # starts in (none for the first ones), whole tiles, the diagonal
+        n, i = window // block_k, r0 // block_k
+        return [(jnp.maximum(i - n + 1, 0), jnp.minimum(i, n - 1))], \
+            [(jnp.maximum(i - n, 0), i >= n), (i, 1)], []
+    num_k = -(-seq_k // block_k)
+    first = jnp.clip((r0 + offset - window + 1) // block_k, 0, num_k)
+    upper = jnp.clip((r0 + block_q - 1 + offset) // block_k + 1, first,
+                     num_k)
+    run, edges = _cut_run(
+        first, upper - first,
+        -(-(r0 + block_q + offset - window) // block_k),
+        jnp.minimum(r0 + offset + 1, seq_k) // block_k,
+        lambda j: tile_whole(r0, j * block_k, block_k, seq_k, True, offset,
+                             window, block_q))
+    return [run], edges, []
+
+
+def _window_query_walk(c0, block_k: int, block_q: int, num_q: int,
+                       seq_k: int, offset: int, window: int):
+    """`_query_walk` under the sliding window: the queries from the one
+    whose own key is the tile's first to the last whose window still
+    reaches its last key."""
+    if offset == 0 and block_q == block_k and window % block_q == 0:
+        n, j = window // block_q, c0 // block_k
+        return [(j + 1, jnp.clip(num_q - j - 1, 0, n - 1))], \
+            [(j, 1), (jnp.minimum(j + n, num_q - 1), j + n < num_q)], []
+    lower = jnp.clip((c0 - offset) // block_q, 0, num_q)
+    upper = jnp.clip((c0 + block_k - 2 - offset + window) // block_q + 1,
+                     lower, num_q)
+    run, edges = _cut_run(
+        lower, upper - lower,
+        -(-(c0 + block_k - 1 - offset) // block_q),
+        (c0 - offset + window) // block_q,
+        lambda i: tile_whole(i * block_q, c0, block_k, seq_k, True, offset,
+                             window, block_q))
+    return [run], edges, []
+
+
 def _key_walk(r0, block_q: int, block_k: int, seq_q: int, seq_k: int,
-              causal: bool, bd):
+              causal: bool, bd, window: Optional[int] = None):
     """The key tiles that the queries ``[r0, r0 + block_q)`` visit, by
     kind: ``(whole, edges, diagonal)``, three lists of ``(first,
     count)`` runs; ``diagonal`` is the noisy diagonal tile under the
     aligned block-diffusion walk (`_bd_aligned`), its count a bool. The
-    forward and the dq kernels walk it, and the census counts it."""
+    forward and the dq kernels walk it, and the census counts it. A
+    count may be a traced bool: a tile that is walked or not."""
+    if window is not None:
+        return _window_key_walk(r0, block_q, block_k, seq_q, seq_k, window)
     if bd is not None and _bd_aligned(block_q, block_k, seq_k, bd):
         half = bd[0] // block_q           # tiles a half
         i = r0 // block_q
@@ -415,12 +512,15 @@ def _key_walk(r0, block_q: int, block_k: int, seq_q: int, seq_k: int,
 
 
 def _query_walk(c0, block_k: int, block_q: int, num_q: int, seq_k: int,
-                causal: bool, offset: int, bd):
+                causal: bool, offset: int, bd, window: Optional[int] = None):
     """The query tiles that see the keys ``[c0, c0 + block_k)``, by kind,
     as `_key_walk` gives the key tiles: the dk/dv kernel's walk over the
     ``num_q`` tiles of the padded queries. Where ``diagonal``'s count is
     true the keys are noisy, seen by their own query tile and no other,
     and the other runs are not to be walked."""
+    if window is not None:
+        return _window_query_walk(c0, block_k, block_q, num_q, seq_k,
+                                  offset, window)
     if bd is not None and _bd_aligned(block_q, block_k, seq_k, bd):
         half = bd[0] // block_q           # tiles a half
         j = c0 // block_k
@@ -467,6 +567,10 @@ def _query_walk(c0, block_k: int, block_q: int, num_q: int, seq_k: int,
     return [run], edges, []
 
 
+def _is_flag(cnt) -> bool:
+    return getattr(cnt, "dtype", None) == jnp.bool_
+
+
 def _walk(runs, body, carry):
     """``body(tile, carry)`` over the tiles of ``runs``, in their order:
     one ``fori_loop``; none where the runs are empty by their static
@@ -474,6 +578,13 @@ def _walk(runs, body, carry):
     program half a tile's time)."""
     runs = [r for r in runs if not (isinstance(r[1], int) and r[1] == 0)]
     if not runs:
+        return carry
+    if any(_is_flag(cnt) for _, cnt in runs):
+        # a tile walked or not (the window's lower edge): a cond, no loop
+        for lo, cnt in runs:
+            carry = jax.lax.cond(cnt, functools.partial(body, lo),
+                                 lambda c: c, carry) if _is_flag(cnt) \
+                else _walk([(lo, cnt)], body, carry)
         return carry
     if all(isinstance(cnt, int) and cnt == 1 for _, cnt in runs):
         for lo, _ in runs:                      # single tiles: no loop
@@ -493,7 +604,8 @@ def _walk(runs, body, carry):
 
 @functools.lru_cache(maxsize=None)
 def flash_tile_census(tq: int, tk: int, bq: int, bk: int,
-                      causal: bool = False, bd=None):
+                      causal: bool = False, bd=None,
+                      window: Optional[int] = None):
     """``(visited, whole, diagonal)``: the key tiles that one head and
     sequence's forward call visits, those of them walked without a mask,
     and those walked as noisy diagonal sub-tiles. From static shapes, by
@@ -501,7 +613,8 @@ def flash_tile_census(tq: int, tk: int, bq: int, bk: int,
     visited = whole = diagonal = 0
     with jax.ensure_compile_time_eval():
         for r0 in range(0, tq, bq):
-            runs, edges, diag = _key_walk(r0, bq, bk, tq, tk, causal, bd)
+            runs, edges, diag = _key_walk(r0, bq, bk, tq, tk, causal, bd,
+                                          window)
             whole += sum(int(cnt) for _, cnt in runs)
             diagonal += sum(int(cnt) for _, cnt in diag)
             visited += sum(int(cnt) for _, cnt in runs + edges + diag)
@@ -542,7 +655,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bias_ref, o_ref,
                       block_k: int, seq_k: int, seq_q: int,
                       dropout_p: float, has_bias: bool, d_head: int,
                       hpb: int, n_heads: int, bd=None,
-                      d_v: Optional[int] = None):
+                      d_v: Optional[int] = None,
+                      window: Optional[int] = None):
     # refs carry hpb heads side-by-side in the minor dim ([BQ, hpb*D]):
     # hpb == 1 is the classic one-head-per-program layout; hpb == 2
     # packs head PAIRS so the [B, T, H, D] layout's d=64 slabs form a
@@ -565,7 +679,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bias_ref, o_ref,
         v2 = v_ref[0, pl.ds(j * block_k, block_k), :] \
             .astype(jnp.float32)
         valid = _tile_valid(r0, block_q, j * block_k, block_k, seq_k,
-                            causal, causal_offset, bd) if masked else None
+                            causal, causal_offset, bd, window) \
+            if masked else None
         if dropout_p > 0.0:                            # the hash's own
             q_pos, k_pos = _tile_positions(r0, block_q, j * block_k,
                                            block_k)
@@ -613,7 +728,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bias_ref, o_ref,
     # the noisy diagonal first (it makes the carry or leaves it), the
     # whole tiles without a mask, then the edges under theirs
     whole, edges, diag = _key_walk(r0, block_q, block_k, seq_q, seq_k,
-                                   causal, bd)
+                                   causal, bd, window)
     carry = (acc0, m0, l0)
     for tile, noisy in diag:
         carry = jax.lax.cond(
@@ -635,9 +750,16 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bias_ref, o_ref,
     lse_ref[0] = jnp.concatenate(lses, axis=1) if hpb > 1 else lses[0]
 
 
-# the Mosaic calls under the block-diffusion mask carry names of their
-# own, so a metric that reads ``flash_*`` goes on reading what it read
+# the Mosaic calls under the block-diffusion mask and under a sliding
+# window carry names of their own, so a metric that reads ``flash_*``
+# goes on reading what it read
 _BD_PREFIX = "bd_"
+_SWA_PREFIX = "swa_"
+
+
+def _prefix(bd, window) -> str:
+    return _BD_PREFIX if bd is not None else \
+        _SWA_PREFIX if window is not None else ""
 
 
 def _seed_arr(seed):
@@ -659,7 +781,8 @@ def _bias_arr(kv_bias, b, tk, tk_p):
 
 def _flash_forward(q, k, v, seed, scale: float, causal: bool,
                    dropout_p: float, interpret: bool = False,
-                   kv_bias=None, bthd: bool = False, bd=None):
+                   kv_bias=None, bthd: bool = False, bd=None,
+                   window: Optional[int] = None):
     """``bthd=False``: q/k/v are [B, H, T, D] (classic layout).
     ``bthd=True``: q/k/v are [B, T, H, D] — the layout attention
     projections produce naturally. The kernels are IDENTICAL in both
@@ -678,6 +801,8 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
     d_v = v.shape[-1]       # the value and output width; q's and k's d
     if bd is not None:
         _bd_check(bd, tq, tk)
+    window = _window_of(window, tk)
+    prefix = _prefix(bd, window)
     bq, bk = _block_sizes(tq, tk)
     # pad sequences to block multiples: pl.ds on a short tail CLAMPS the
     # start index (shifting rows under the validity mask), so the buffers
@@ -729,7 +854,7 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
                                causal=causal, block_k=bk, seq_k=tk,
                                seq_q=tq, dropout_p=dropout_p,
                                has_bias=has_bias, d_head=d, hpb=hpb,
-                               n_heads=h, bd=bd, d_v=d_v)
+                               n_heads=h, bd=bd, d_v=d_v, window=window)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -757,11 +882,13 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
         interpret=interpret,
         compiler_params=_grid_params(tk_p * hpb * d * k.dtype.itemsize,
                                      tk_p * hpb * d_v * v.dtype.itemsize),
-        name="flash_fwd" if bd is None else "bd_flash_fwd",
+        name="flash_fwd" if not prefix else prefix + "flash_fwd",
     )(qr, kr, vr, _seed_arr(seed), _bias_arr(kv_bias, b, tk, tk_p))
-    note_kernel(_BD_PREFIX * (bd is not None) + "flash_fwd", *flash_fwd_work(
-        b, h, tq, tk, d, q.dtype.itemsize, causal, bd=bd, d_v=d_v))
-    xprof.note_flash_tiles(*flash_tile_census(tq, tk, bq, bk, causal, bd))
+    note_kernel(prefix + "flash_fwd", *flash_fwd_work(
+        b, h, tq, tk, d, q.dtype.itemsize, causal, bd=bd, d_v=d_v,
+        window=window))
+    xprof.note_flash_tiles(*flash_tile_census(tq, tk, bq, bk, causal, bd,
+                                              window))
     # what `nn.recompute_layer` keeps of a recomputed layer: the backward
     # kernels read both, and making either again is this whole call. The
     # output is named as the kernel wrote it (kept under the caller's
@@ -779,12 +906,13 @@ def _flash_forward(q, k, v, seed, scale: float, causal: bool,
     return out[:, :tq].reshape(b, h, tq, d_v), lse_pub
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 9, 10, 11))
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None,
                     interpret: bool = False, dropout_p: float = 0.0,
                     seed=None, kv_bias=None, bthd: bool = False,
-                    block_diffusion=None):
+                    block_diffusion=None, window: Optional[int] = None):
     """Fused attention:
     dropout(softmax(QK^T * scale + kv_bias [+ causal mask])) V.
 
@@ -813,8 +941,14 @@ def flash_attention(q, k, v, causal: bool = False,
     kernels from iotas, no tile without an allowed pair visited,
     forward or backward; the Mosaic calls are named ``bd_flash_*``. Not
     with ``causal``, dropout or a key bias.
+
+    ``window``: a static int ``W``, with ``causal``: query ``q`` sees the
+    keys ``q + o - W < k <= q + o`` (``o = Tk - Tq``), ``W`` of them, its
+    own included. The kernels visit only the tiles inside the window,
+    forward and backward, and their Mosaic calls are named
+    ``swa_flash_*``; a window that holds every key is the causal mask.
     """
-    _check_mask(causal, dropout_p, kv_bias, block_diffusion)
+    _check_mask(causal, dropout_p, kv_bias, block_diffusion, window)
     if dropout_p > 0.0 and seed is None:
         raise ValueError("flash_attention: dropout_p > 0 requires a "
                          "seed (vary it per step)")
@@ -824,27 +958,33 @@ def flash_attention(q, k, v, causal: bool = False,
     # gradient runs: that is `_fwd`'s, which notes the call
     with xprof.unnoted(when=xprof.in_layer_primal()):
         out, _ = _flash_forward(q, k, v, seed, scale, causal, dropout_p,
-                                interpret, kv_bias, bthd, block_diffusion)
+                                interpret, kv_bias, bthd, block_diffusion,
+                                window)
     return out
 
 
-def _check_mask(causal, dropout_p, kv_bias, block_diffusion) -> None:
+def _check_mask(causal, dropout_p, kv_bias, block_diffusion,
+                window=None) -> None:
     if block_diffusion is not None and (causal or dropout_p > 0.0
                                         or kv_bias is not None):
         raise ValueError("flash_attention: block_diffusion is a mask of "
                          "its own: no causal, dropout_p or kv_bias")
+    if window is not None and (not causal or int(window) < 1):
+        raise ValueError(f"flash_attention: window={window} is a causal "
+                         "mask's: causal=True and at least one key")
 
 
 def _fwd(q, k, v, causal, scale, interpret, dropout_p, seed, kv_bias,
-         bthd, block_diffusion=None):
-    _check_mask(causal, dropout_p, kv_bias, block_diffusion)
+         bthd, block_diffusion=None, window=None):
+    _check_mask(causal, dropout_p, kv_bias, block_diffusion, window)
     if dropout_p > 0.0 and seed is None:
         raise ValueError("flash_attention: dropout_p > 0 requires a "
                          "seed (vary it per step)")
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     out, lse = _flash_forward(q, k, v, seed, scale, causal, dropout_p,
-                              interpret, kv_bias, bthd, block_diffusion)
+                              interpret, kv_bias, bthd, block_diffusion,
+                              window)
     return out, (q, k, v, seed, kv_bias, out, lse, scale)
 
 
@@ -889,7 +1029,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    causal: bool, block_k: int, seq_k: int, seq_q: int,
                    dropout_p: float, has_bias: bool, d_head: int,
                    hpb: int, n_heads: int, bd=None,
-                   d_v: Optional[int] = None):
+                   d_v: Optional[int] = None,
+                   window: Optional[int] = None):
     d_v = d_head if d_v is None else d_v               # v's and dO's
     q2 = q_ref[0].astype(jnp.float32) * scale          # [BQ, hpb*D]
     do2 = do_ref[0].astype(jnp.float32)                # [BQ, hpb*d_v]
@@ -906,7 +1047,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         v2 = v_ref[0, pl.ds(j * block_k, block_k), :] \
             .astype(jnp.float32)
         valid = _tile_valid(r0, block_q, j * block_k, block_k, seq_k,
-                            causal, causal_offset, bd) if masked else None
+                            causal, causal_offset, bd, window) \
+            if masked else None
         q_pos, k_pos = _tile_positions(r0, block_q, j * block_k, block_k) \
             if dropout_p > 0.0 else (None, None)       # the hash's own
         bias = bias_ref[0, :, pl.ds(j * block_k, block_k)] \
@@ -950,7 +1092,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq0 = tuple(jnp.zeros((block_q, d_head), jnp.float32)
                 for _ in range(hpb))
     whole, edges, diag = _key_walk(r0, block_q, block_k, seq_q, seq_k,
-                                   causal, bd)
+                                   causal, bd, window)
     dqs = dq0
     own = diagonal if bd is not None and _bd_subtiled(
         block_q, block_k, seq_k, bd) else functools.partial(body, masked=True)
@@ -969,7 +1111,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     causal: bool, block_q: int, seq_k: int, seq_q: int,
                     dropout_p: float, has_bias: bool, d_head: int,
                     hpb: int, n_heads: int, bd=None,
-                    d_v: Optional[int] = None):
+                    d_v: Optional[int] = None,
+                    window: Optional[int] = None):
     d_v = d_head if d_v is None else d_v               # v's, dO's, dv's
     # Padded-q correctness: dO and delta are zero-padded, so a padded
     # query row contributes p^T@dO = 0 to dv and p*(0-0) = 0 to dk —
@@ -992,7 +1135,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         lse2 = lse_ref[0, pl.ds(i * block_q, block_q), :]  # [BQ, hpb]
         delta2 = delta_ref[0, pl.ds(i * block_q, block_q), :]
         valid = _tile_valid(i * block_q, block_q, c0, block_k, seq_k,
-                            causal, causal_offset, bd) if masked else None
+                            causal, causal_offset, bd, window) \
+            if masked else None
         q_pos, k_pos = _tile_positions(i * block_q, block_q, c0, block_k) \
             if dropout_p > 0.0 else (None, None)       # the hash's own
         new_dk, new_dv = [], []
@@ -1046,7 +1190,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     zeros_v = zeros if d_v == d_head else tuple(
         jnp.zeros((block_k, d_v), jnp.float32) for _ in range(hpb))
     whole, edges, diag = _query_walk(c0, block_k, block_q, num_q, seq_k,
-                                     causal, causal_offset, bd)
+                                     causal, causal_offset, bd, window)
 
     def seen_by_others():
         carry = _walk(whole, functools.partial(body, masked=False),
@@ -1072,7 +1216,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       scale: float, causal: bool, seq_k: int,
                       seq_q: int, dropout_p: float, has_bias: bool,
                       d_head: int, hpb: int, n_heads: int, bd=None,
-                      d_v: Optional[int] = None):
+                      d_v: Optional[int] = None,
+                      window: Optional[int] = None):
     """Single-block backward: when BOTH padded sequences fit one tile
     (tq_p == bq and tk_p == bk — e.g. BERT's T=512 with 512-tiles),
     the dq and dkv kernels' scans each degenerate to one iteration
@@ -1094,7 +1239,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     # mask is asked for
     valid = None if bd is None and not causal and seq_k == block_k else \
         _tile_valid(0, block_q, 0, block_k, seq_k, causal, seq_k - seq_q,
-                    bd)
+                    bd, window)
     q_pos, k_pos = _tile_positions(0, block_q, 0, block_k) \
         if dropout_p > 0.0 else (None, None)           # the hash's own
     dqs, dks, dvs = [], [], []
@@ -1126,7 +1271,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
                     causal: bool, dropout_p: float,
                     interpret: bool = False, dlse=None, kv_bias=None,
-                    bthd: bool = False, bd=None):
+                    bthd: bool = False, bd=None,
+                    window: Optional[int] = None):
     if bthd:
         b, tq, h, d = q.shape
         tk = k.shape[1]
@@ -1134,6 +1280,7 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
         b, h, tq, d = q.shape
         tk = k.shape[2]
     d_v = v.shape[-1]       # v's, dO's and dv's width; q's and k's d
+    window = _window_of(window, tk)
     bq, bk = _block_sizes(tq, tk)
     tq_p = pl.cdiv(tq, bq) * bq
     tk_p = pl.cdiv(tk, bk) * bk
@@ -1215,8 +1362,8 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
     shape = (b, h, tq, tk, d, q.dtype.itemsize)     # for the work notes
     kernel_kw = dict(scale=scale, causal=causal, seq_k=tk, seq_q=tq,
                      dropout_p=dropout_p, has_bias=has_bias, d_head=d,
-                     hpb=hpb, n_heads=h, bd=bd, d_v=d_v)
-    prefix = _BD_PREFIX * (bd is not None)
+                     hpb=hpb, n_heads=h, bd=bd, d_v=d_v, window=window)
+    prefix = _prefix(bd, window)
     row_spec = pl.BlockSpec((1, bq, hpb), lambda g_, i: (g_, i, 0),
                             memory_space=pltpu.VMEM)
     rowfull_spec = pl.BlockSpec((1, tq_p, hpb),
@@ -1249,10 +1396,11 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
             out_shape=[dq_struct, dk_struct, dv_struct],
             interpret=interpret,
             compiler_params=_GRID_PARALLEL,
-            name="flash_bwd" if bd is None else "bd_flash_bwd",
+            name="flash_bwd" if not prefix else prefix + "flash_bwd",
         )(qr, kr, vr, dor, lse_r, delta, seed_a, bias_a)
         note_kernel(prefix + "flash_bwd",
-                    *flash_bwd_work(*shape, causal, bd=bd, d_v=d_v))
+                    *flash_bwd_work(*shape, causal, bd=bd, d_v=d_v,
+                                    window=window))
         return _from_flat(bthd, (b, h, tq, tk, d, d_v), dq, dk, dv)
     # one program's resident whole-sequence operands, for _grid_params
     k_bytes = tk_p * hpb * d * k.dtype.itemsize
@@ -1278,10 +1426,11 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
         out_shape=dq_struct,
         interpret=interpret,
         compiler_params=_grid_params(k_bytes, v_bytes),
-        name="flash_bwd_dq" if bd is None else "bd_flash_bwd_dq",
+        name="flash_bwd_dq" if not prefix else prefix + "flash_bwd_dq",
     )(qr, kr, vr, dor, lse_r, delta, seed_a, bias_a)
     note_kernel(prefix + "flash_bwd_dq",
-                *flash_bwd_work(*shape, causal, matmuls=3, bd=bd, d_v=d_v))
+                *flash_bwd_work(*shape, causal, matmuls=3, bd=bd, d_v=d_v,
+                                window=window))
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=bq, **kernel_kw),
@@ -1310,10 +1459,11 @@ def _flash_backward(q, k, v, seed, out, lse, g, scale: float,
         interpret=interpret,
         compiler_params=_grid_params(q_bytes, do_bytes, _row_bytes(tq_p),
                                      _row_bytes(tq_p)),
-        name="flash_bwd_dkv" if bd is None else "bd_flash_bwd_dkv",
+        name="flash_bwd_dkv" if not prefix else prefix + "flash_bwd_dkv",
     )(qr, kr, vr, dor, lse_r, delta, seed_a, bias_a)
     note_kernel(prefix + "flash_bwd_dkv",
-                *flash_bwd_work(*shape, causal, matmuls=4, bd=bd, d_v=d_v))
+                *flash_bwd_work(*shape, causal, matmuls=4, bd=bd, d_v=d_v,
+                                window=window))
     return _from_flat(bthd, (b, h, tq, tk, d, d_v), dq, dk, dv)
 
 
@@ -1331,14 +1481,14 @@ def _from_flat(bthd: bool, dims, dq, dk, dv):
 
 
 def _bwd(causal, scale_arg, interpret, dropout_p, bthd, block_diffusion,
-         res, g):
+         window, res, g):
     import numpy as np
 
     q, k, v, seed, kv_bias, out, lse, scale = res
     dq, dk, dv = _flash_backward(q, k, v, seed, out, lse, g, scale,
                                  causal, dropout_p, interpret,
                                  kv_bias=kv_bias, bthd=bthd,
-                                 bd=block_diffusion)
+                                 bd=block_diffusion, window=window)
     # seed is integer-valued: its cotangent is the symbolic-zero float0
     dseed = None if seed is None else \
         np.zeros(jnp.shape(jnp.asarray(seed)), jax.dtypes.float0)

@@ -28,3 +28,5 @@ from .sdar_moe import (BlockDiffusionOutput, SdarMoeConfig,  # noqa: F401
                        SdarMoeForCausalLM, block_diffusion_loss,
                        block_diffusion_metrics)
 from .kimi_linear import KimiLinearConfig, KimiLinearForCausalLM  # noqa
+from .smallthinker import (SmallThinkerConfig,  # noqa: F401
+                           SmallThinkerForCausalLM)

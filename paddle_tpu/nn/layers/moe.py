@@ -169,15 +169,19 @@ class DroplessMoE(Layer):
     number of windows that hold a pair, not the pairs (PERF.md section
     6, PR 29, has the price and why it is paid).
 
-    Two options, each off by default (the layer then traces to what it
+    Options, each off by default (the layer then traces to what it
     always did). ``score_func="softmax"`` scores by ``softmax(W_r x)``
     over all the experts in sigmoid's place (selection, bias, the
     division and the scale as above). ``gated=True`` makes an expert
-    three matrices, ``W_down (silu(W_gate x) * W_up x)``: ``w_in`` is
+    three matrices, ``W_down (act(W_gate x) * W_up x)``: ``w_in`` is
     then ``[held, d_model, 2 d_expert]``, gate columns first, up columns
     after, one operand so that a window's first product is one grouped
     matmul as it is without the gate (``w_out`` is ``W_down``); the
-    shared expert, where there is one, is gated alike.
+    shared expert, where there is one, is gated alike. ``gate_act`` is
+    that gate's ``act``: ``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU).
+    ``forward(x, router_input)`` routes on ``router_input`` (a tensor of
+    ``x``'s shape: a layer whose router reads another tensor than its
+    experts do) and sends ``x`` to the experts chosen.
 
     Returns ``(out, stats)``; ``stats`` holds the scalars
     ``pairs_held`` (pairs on held experts in this call),
@@ -206,11 +210,14 @@ class DroplessMoE(Layer):
                  routed_scaling_factor: float = 1.0,
                  norm_topk_prob: bool = True, weight_attr=None,
                  out_weight_attr=None, score_func: str = "sigmoid",
-                 gated: bool = False) -> None:
+                 gated: bool = False, gate_act: str = "silu") -> None:
         super().__init__()
         if score_func not in ("sigmoid", "softmax"):
             raise ValueError(f"score_func {score_func!r}")
+        if gate_act not in ("silu", "relu"):
+            raise ValueError(f"gate_act {gate_act!r}")
         self.score_func, self.gated = score_func, gated
+        self.gate_act = gate_act
         held = num_experts if experts_held is None else experts_held
         if not (0 <= expert_offset and expert_offset + held <= num_experts
                 and 0 < top_k <= num_experts):
@@ -245,6 +252,8 @@ class DroplessMoE(Layer):
         if not self.gated:
             return jnp.square(jax.nn.relu(h))
         gate, up = jnp.split(h, 2, axis=-1)
+        if self.gate_act == "relu":
+            return jax.nn.relu(gate) * up
         return jax.nn.silu(gate) * up
 
     def route(self, tokens):
@@ -348,7 +357,7 @@ class DroplessMoE(Layer):
         return routed((tokens, weights, self.w_in, self.w_out), order,
                       ends, jnp.asarray(windows, jnp.int32))
 
-    def forward(self, x):
+    def forward(self, x, router_input=None):
         tokens = x.reshape(-1, x.shape[-1])
         n, held = tokens.shape[0], self.experts_held
         total = n * self.top_k
@@ -356,7 +365,9 @@ class DroplessMoE(Layer):
                             // (self.num_experts * _ROW_TILE)) * _ROW_TILE)
         windows = -(-total // rows)
         with jax.named_scope("pt.moe_route"):
-            chosen, weights = self.route(tokens)
+            chosen, weights = self.route(
+                tokens if router_input is None
+                else router_input.reshape(tokens.shape))
             local = chosen - self.expert_offset
             # absent experts sort last, into a group no matmul visits
             key = jnp.where((local >= 0) & (local < held), local,

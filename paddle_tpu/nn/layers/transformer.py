@@ -169,7 +169,7 @@ class GroupedQueryAttention(Layer):
     kernel sees plain multi-head operands in their native [B, T, H, D]
     layout; the gradient of the repeat sums a group's heads.
 
-    Three options, each off by default (the layer then traces to what it
+    Four options, each off by default (the layer then traces to what it
     always did): ``qk_norm_eps`` puts an RMS norm with a gain of its own
     over every q head and every k head (``q_norm`` / ``k_norm``);
     ``rope_theta`` turns q and k by rotary positions, from the
@@ -177,7 +177,9 @@ class GroupedQueryAttention(Layer):
     input may share one; ``arange(T)`` without them); ``block_diffusion``
     is the block length ``K`` of the block-diffusion training mask in
     ``causal``'s place: the input is a noised copy of ``T / 2`` tokens
-    followed by the clean copy (``kernels.flash_attention``). The norm
+    followed by the clean copy (``kernels.flash_attention``);
+    ``sliding_window`` is ``W`` of the causal mask within a window: a
+    position sees the ``W`` last positions, its own included. The norm
     and the turn are traced under the scope ``pt.attn_qk``.
 
     Where the flash kernel runs, its output and row statistics carry the
@@ -190,7 +192,8 @@ class GroupedQueryAttention(Layer):
                  weight_attr=None, out_weight_attr=None,
                  qk_norm_eps: Optional[float] = None,
                  rope_theta: Optional[float] = None,
-                 block_diffusion: Optional[int] = None) -> None:
+                 block_diffusion: Optional[int] = None,
+                 sliding_window: Optional[int] = None) -> None:
         super().__init__()
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_kv_heads} KV heads do not divide "
@@ -198,6 +201,10 @@ class GroupedQueryAttention(Layer):
         if block_diffusion is not None and causal:
             raise ValueError("block_diffusion is a mask of its own: "
                              "causal=False with it")
+        if sliding_window is not None and not causal:
+            raise ValueError("sliding_window is a causal mask's: "
+                             "causal=True with it")
+        self.sliding_window = sliding_window
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
         self.head_dim, self.causal = head_dim, causal
         self.rope_theta, self.block_diffusion = rope_theta, block_diffusion
@@ -245,7 +252,8 @@ class GroupedQueryAttention(Layer):
             q, jnp.repeat(k, rep, axis=2),
             jnp.repeat(kv(self.v_proj), rep, axis=2), causal=self.causal,
             scale=self.head_dim ** -0.5, training=self.training,
-            layout="bthd", block_diffusion=mask)
+            layout="bthd", block_diffusion=mask,
+            window=self.sliding_window)
         return self.o_proj(out.reshape(b, t, -1))
 
 

@@ -44,9 +44,13 @@ metrics are on, all at trace time and nothing per step:
 - ``note_bd_attention``: each attention layer under the block-diffusion
   mask whose products run the ``bd_flash_*`` kernels notes itself,
   published as ``pt_bd_attention_sites``;
+- ``note_swa_attention``: each attention layer under a sliding window
+  whose products run the ``swa_flash_*`` kernels notes itself,
+  published as ``pt_swa_attention_sites``;
 - ``note_flash_tiles``: each flash attention forward call site notes
-  the key tiles one head and sequence of it visits, those of them it
-  walks without a mask and those it walks as noisy diagonal sub-tiles
+  the key tiles one head and sequence of it visits (window sites too),
+  those of them it walks without a mask and those it walks as noisy
+  diagonal sub-tiles
   (``kernels.flash_attention.flash_tile_census``), published summed as
   ``pt_flash_tiles_visited`` / ``pt_flash_tiles_whole`` /
   ``pt_flash_tiles_diagonal``;
@@ -79,7 +83,8 @@ from . import metrics as _metrics
 __all__ = ["ProgramCardRegistry", "cards", "enabled", "harvest",
            "flops_of", "note_kernel", "note_dropout_mask",
            "note_qkv_grad_summed", "note_ssd_scan_kernel",
-           "note_bd_attention", "note_flash_tiles", "note_remat_kept",
+           "note_bd_attention", "note_swa_attention", "note_flash_tiles",
+           "note_remat_kept",
            "note_kda_chunks", "note_kda_inverse",
            "kernel_notes",
            "op_scopes", "parse_op_names"]
@@ -258,7 +263,8 @@ def tracing(fn_name: str) -> Iterator[None]:
              getattr(_TLS, "summed", None), getattr(_TLS, "scans", None),
              getattr(_TLS, "bd_sites", None), getattr(_TLS, "kept", None),
              getattr(_TLS, "tiles", None), getattr(_TLS, "kda", None),
-             getattr(_TLS, "kda_inv", None))
+             getattr(_TLS, "kda_inv", None),
+             getattr(_TLS, "swa_sites", None))
     _TLS.notes = notes = []
     _TLS.masked = masked = []
     _TLS.summed = summed = []
@@ -268,12 +274,13 @@ def tracing(fn_name: str) -> Iterator[None]:
     _TLS.tiles = tiles = []
     _TLS.kda = kda = []
     _TLS.kda_inv = kda_inv = []
+    _TLS.swa_sites = swa_sites = []
     try:
         yield
     finally:
         (_TLS.notes, _TLS.masked, _TLS.summed, _TLS.scans,
          _TLS.bd_sites, _TLS.kept, _TLS.tiles, _TLS.kda,
-         _TLS.kda_inv) = outer
+         _TLS.kda_inv, _TLS.swa_sites) = outer
         if outer[0] is not None:    # an entry point traced inside another
             outer[0].extend(notes)
             outer[1].extend(masked)
@@ -284,6 +291,7 @@ def tracing(fn_name: str) -> Iterator[None]:
             outer[6].extend(tiles)
             outer[7].extend(kda)
             outer[8].extend(kda_inv)
+            outer[9].extend(swa_sites)
         if _metrics.enabled():
             with _NOTES_LOCK:
                 _KERNEL_NOTES[fn_name] = notes
@@ -311,6 +319,11 @@ def tracing(fn_name: str) -> Iterator[None]:
                 "attention layers under the block-diffusion mask whose "
                 "products run the bd_flash kernels, in the newest trace "
                 "of the entry point").set(len(bd_sites), fn=fn_name)
+            _metrics.gauge(
+                "pt_swa_attention_sites",
+                "attention layers under a sliding window whose products "
+                "run the swa_flash kernels, in the newest trace of the "
+                "entry point").set(len(swa_sites), fn=fn_name)
             _metrics.gauge(
                 "pt_flash_tiles_visited",
                 "key tiles that the flash attention forward call sites "
@@ -411,6 +424,16 @@ def note_bd_attention() -> None:
     ``bd_flash_*`` kernels. A no-op unless metrics are on and a tracked
     entry point is being traced."""
     sites = getattr(_TLS, "bd_sites", None)
+    if sites is not None and _metrics.enabled():
+        sites.append(1)
+
+
+def note_swa_attention() -> None:
+    """Called once per traced attention layer whose sliding window the
+    seam (``kernels.maybe_flash_attention``) sends to the
+    ``swa_flash_*`` kernels. A no-op unless metrics are on and a tracked
+    entry point is being traced."""
+    sites = getattr(_TLS, "swa_sites", None)
     if sites is not None and _metrics.enabled():
         sites.append(1)
 

@@ -192,4 +192,5 @@ def test_the_roofline_share_is_data_for_the_reader_that_is_there():
     # the cells whose experts run the kernels
     assert entry[0]["workloads"] == ["nemotron3_nano_ep16_s8k",
                                      "sdar_30b_a3b_ep8_s8k",
-                                     "kimi_linear_48b_a3b_ep32_s16k"]
+                                     "kimi_linear_48b_a3b_ep32_s16k",
+                                     "smallthinker_21b_a3b_ep4_s16k"]

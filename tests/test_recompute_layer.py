@@ -99,11 +99,12 @@ def case(request, monkeypatch):
     monkeypatch.setattr(moe, "_ROW_TILE", 8)
 
     def seam(q, k, v, scale=None, causal=False, training=False,
-             layout="bthd", block_diffusion=None):
+             layout="bthd", block_diffusion=None, window=None):
         assert layout == "bthd"
         out = fa.flash_attention(
             *(jnp.moveaxis(x, 2, 1) for x in (q, k, v)), causal=causal,
-            scale=scale, interpret=True, block_diffusion=block_diffusion)
+            scale=scale, interpret=True, block_diffusion=block_diffusion,
+            window=window)
         return jnp.moveaxis(out, 1, 2)
 
     monkeypatch.setattr(kernels, "maybe_flash_attention", seam)

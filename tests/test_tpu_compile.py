@@ -636,3 +636,132 @@ def test_latent_attention_flash_kernels_compile_at_the_cells_shape(chip):
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert kernel in text
     assert "16384,16384]" not in text
+
+
+# The kernels' other walks (a tail, fewer queries than keys, one tile,
+# blocks that straddle tiles) as the tree before the window lowered them,
+# digested as `_ACCEPTED` is: the sliding window leaves every kernel
+# without it as it was.
+_UNWINDOWED = {
+    "plain_tail": {
+        "flash_fwd": "1e0d9bd0e17238501b7f98453ddc990c"
+                     "0ca88e8ffb8f6cc333e3ba3c39ee3104",
+        "flash_bwd_dq": "88cb37023ea349e5244cb26202d342c1"
+                        "35e95288a8f1f3296f91a321b68fdb48",
+        "flash_bwd_dkv": "7065cc51331274105de1d6a217bc4999"
+                         "4b5fe34956ebdbbba83e6a8edf5dcc8f"},
+    "causal_offset": {
+        "flash_fwd": "f1fce6723dd8f19aa8fa0b3f46c4831f"
+                     "33714dc0a9707fc73c7ab7030a1237a6",
+        "flash_bwd_dq": "3bfb09478319d6735f5e1e4a0c311053"
+                        "b7974ae7da030c5fa20196eaab04dc7a",
+        "flash_bwd_dkv": "3f02d92a8634673360215e1d24af66f8"
+                         "4a277e5632f14cfa52cb70f9ecbf1154"},
+    "causal_tail": {
+        "flash_fwd": "ea00970123ca934eafd09085555a0907"
+                     "89693027af42a5c1b909dbe9f0c0d8d2",
+        "flash_bwd_dq": "de8b6625778d1522e2cf9e37f0a62ce1"
+                        "81375c29da0e15a58c79d5b59f3ccd5a",
+        "flash_bwd_dkv": "282af9581cfa830c0baaa6ac2f54306f"
+                         "c6720ac5e067abdcf96e82e4f22636ae"},
+    "bd_unaligned": {
+        "bd_flash_fwd": "8e9d31c5ae9d82340e3f159b9ccee613"
+                        "d63437b74812f4bcc3b4030b9f4f46d2",
+        "bd_flash_bwd_dq": "1f3d032d7ee4f490e8a9ad8fd4619b32"
+                           "19bdda773ffa457a3740c05151fd4016",
+        "bd_flash_bwd_dkv": "708c8219f73a9b1a9a32bbf70ab316b0"
+                            "afb79d3a94505e2e6840e939e91e0f62"},
+    "causal_one_tile": {
+        "flash_fwd": "ca65400a57d430644cf13963ca40baa5"
+                     "11cc4c8c038f5927d678ccb58f8c2de5",
+        "flash_bwd": "d1dd29cf75646b692961276c2aacd9c6"
+                     "f6ca993b3dd43ad860025eca427b91dd"},
+}
+
+
+@pytest.mark.parametrize("case", sorted(_UNWINDOWED))
+def test_kernels_without_a_window_are_unchanged(chip, case):
+    import hashlib
+
+    bf = jnp.bfloat16
+    fn, shapes = {
+        "plain_tail": (_flash_grad(), [(1, 1000, 4, 128)] * 3),
+        "causal_offset": (_flash_grad(causal=True), [
+            (1, 1024, 4, 128), (1, 2048, 4, 128), (1, 2048, 4, 128)]),
+        "causal_tail": (_flash_grad(causal=True), [(1, 1800, 4, 128)] * 3),
+        "bd_unaligned": (_flash_grad(block_diffusion=(1000, 8)),
+                         [(1, 2000, 4, 128)] * 3),
+        "causal_one_tile": (_flash_grad(causal=True),
+                            [(2, 384, 4, 128)] * 3),
+    }[case]
+    texts = _mosaic_texts(fn, chip, *[(s, bf) for s in shapes])
+    got = {k: hashlib.sha256(t.encode()).hexdigest()
+           for k, t in texts.items()}
+    assert got == _UNWINDOWED[case]
+
+
+def test_window_kernels_compile_at_the_cells_shape(chip):
+    """The SmallThinker cell's window layers (smallthinker_21b_a3b_ep4_
+    s16k): one sequence of 16,384 positions, 28 heads of 128, windows of
+    4096 keys: the ``swa_flash`` kernels, one call each, and no [L, L]
+    array."""
+    from paddle_tpu.kernels.flash_attention import flash_attention
+
+    @jax.named_scope("pt.attn")     # as a model calls it: the Mosaic
+    def loss(q, k, v):              # calls are named after the kernels
+        out = flash_attention(q, k, v, causal=True, bthd=True, window=4096)
+        return jnp.sum(out.astype(jnp.float32))
+
+    qkv = ((1, 16384, 28, 128), jnp.bfloat16)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), chip, qkv, qkv,
+                    qkv)
+    for kernel in ("swa_flash_fwd", "swa_flash_bwd_dq", "swa_flash_bwd_dkv"):
+        assert len(_custom_calls(text, kernel)) == 1, kernel
+    assert not _custom_calls(text, "flash_fwd")
+    assert "16384,16384]" not in text
+
+
+def test_a_window_layers_gradient_runs_its_kernels_once(chip, monkeypatch):
+    """One window layer of the SmallThinker cell at its widths (rotary
+    positions, the window of 4096, a softmax router that reads the
+    layer's input before attention over 16 held ReGLU experts),
+    recomputed in its backward pass as the model runs it, steered onto
+    the kernels' side of the seams: the ``swa_flash`` kernels and the
+    grouped matmuls compile, the forward kernel runs once (its result is
+    kept), and no [L, L] array is in the optimized program."""
+    import json
+
+    from paddle_tpu import kernels, nn
+    from paddle_tpu.models.smallthinker import (SmallThinkerBlock,
+                                                SmallThinkerConfig)
+    from paddle_tpu.nn.layer import functional_call
+    monkeypatch.setattr(kernels, "_on_tpu", lambda: True)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "smallthinker_21b_a3b.json")) as f:
+        cfg = json.load(f)["model"]
+    block = SmallThinkerBlock(SmallThinkerConfig(**cfg), 1)
+    block.to(dtype="bfloat16")
+    block.train()
+    params, buffers = block.param_dict(), block.buffer_dict()
+
+    def loss(p, x):
+        def layer(h):
+            y, stats = functional_call(block, p, buffers, h)
+            return y, stats["pairs_held"]
+        y, held = nn.recompute_layer(layer)(x)
+        return jnp.sum(y.astype(jnp.float32)), held
+
+    abstract = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=chip), params)
+    x = jax.ShapeDtypeStruct((1, 16384, cfg["hidden_size"]), jnp.bfloat16,
+                             sharding=chip)
+    compiled = jax.jit(jax.grad(loss, (0, 1), has_aux=True)).lower(
+        abstract, x).compile()
+    text = compiled.as_text()
+    for kernel in ("swa_flash_fwd", "swa_flash_bwd_dq", "swa_flash_bwd_dkv"):
+        assert len(_custom_calls(text, kernel)) == 1, kernel
+    assert _custom_calls(text, "moe_gmm") and _custom_calls(text, "moe_tgmm")
+    assert "ragged-dot" not in text
+    assert "16384,16384]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
